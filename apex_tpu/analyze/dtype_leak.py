@@ -33,6 +33,7 @@ from typing import Any, Iterator, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jex_core
 
 __all__ = ["DtypeLeakError", "DtypeLeakReport", "assert_no_dtype_leaks",
            "dtype_leak_report", "resolve_policy_dtype"]
@@ -75,9 +76,9 @@ def _subjaxprs(eqn) -> Iterator[Any]:
     for val in eqn.params.values():
         vals = val if isinstance(val, (list, tuple)) else (val,)
         for v in vals:
-            if isinstance(v, jax.core.ClosedJaxpr):
+            if isinstance(v, jex_core.ClosedJaxpr):
                 yield v.jaxpr
-            elif isinstance(v, jax.core.Jaxpr):
+            elif isinstance(v, jex_core.Jaxpr):
                 yield v
 
 
@@ -165,7 +166,7 @@ def dtype_leak_report(fn, *args, policy, **kwargs) -> DtypeLeakReport:
     report dtype leaks against the declared ``policy`` (see
     :func:`resolve_policy_dtype`)."""
     policy_dt = resolve_policy_dtype(policy)
-    if isinstance(fn, jax.core.ClosedJaxpr):
+    if isinstance(fn, jex_core.ClosedJaxpr):
         closed = fn
     else:
         closed = jax.make_jaxpr(lambda *a: fn(*a, **kwargs))(*args)

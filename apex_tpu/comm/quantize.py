@@ -38,13 +38,8 @@ import jax.numpy as jnp
 from apex_tpu.ops._pallas_util import compiled_backend as _compiled_backend
 from apex_tpu.ops._pallas_util import sds as _sds
 
-try:  # keep import-failure graceful (CPU-only envs), like ops/layer_norm.py
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 QMAX = 127.0  # symmetric int8 code range; -128 is never emitted
 QMAX4 = 7.0   # symmetric int4 code range; -8 is never emitted
@@ -169,8 +164,6 @@ _ROWS_PER_STEP = 32
 
 
 def _pallas_ok(n: int, block_size: int, allow_interpret: bool) -> bool:
-    if not _HAS_PALLAS:
-        return False
     if block_size % 128 != 0:
         return False
     rows = n // block_size

@@ -44,7 +44,7 @@ Tier 2 (the serving side — request-level attribution, not step averages):
   budgets → :class:`SloTracker` goodput/violation accounting over rolling
   windows;
 * :mod:`~apex_tpu.monitor.regress` — :func:`compare_records` baseline
-  diffing of bench records (the ``tpu_watch.sh`` stage-10 gate);
+  diffing of bench records;
 * :mod:`~apex_tpu.monitor.view` — ``python -m apex_tpu.monitor.view``
   latency/SLO summary CLI over any monitor JSONL file.
 

@@ -1,9 +1,7 @@
 """Baseline comparison for bench records — flag metric regressions.
 
-The banked-artifact discipline (``BENCH_r0*.json``, ``SERVE_TPU.json``,
-``tpu_watch.sh`` promotion rules) gives every bench a durable last-good
-record; this module closes the loop by DIFFING a fresh record against the
-banked one so a perf regression fails loudly at bench time instead of
+A bench's last good record is a file; this module closes the loop by
+DIFFING a fresh record against that one so a perf regression fails loudly at bench time instead of
 surfacing rounds later in a human's spreadsheet:
 
 * :func:`load_record` — reads a record file in any of the repo's shapes:
@@ -18,8 +16,8 @@ surfacing rounds later in a human's spreadsheet:
   direction. Returns a JSON-serializable report.
 * CLI: ``python -m apex_tpu.monitor.regress BASELINE NEW [--tol 0.1]`` —
   table to stderr, one ``json_record`` line to stdout, exit 1 on
-  regression (the ``tpu_watch.sh`` stage-10 gate; CPU-rehearsal records
-  are refused by the caller before this ever runs).
+  regression (CPU-rehearsal records are refused by the caller before
+  this ever runs).
 """
 
 from __future__ import annotations

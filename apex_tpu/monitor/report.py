@@ -151,9 +151,13 @@ def step_report(
     """
     from apex_tpu.pyprof import measured_op_table
 
+    if peak_flops is None:
+        from apex_tpu.utils.platform import device_peaks
+
+        peak_flops = device_peaks().bf16_flops_per_s
     measured = measured_op_table(
-        fn, *args, steps=steps, depth=depth,
-        peak_flops=peak_flops or 1e12, **kwargs)
+        fn, *args, steps=steps, depth=depth, peak_flops=peak_flops,
+        **kwargs)
     stats = hlo_stats(measured["compiled"], default_group_size)
 
     # wall clock, NOT the attributed-row sum: a partial trace join would
@@ -178,9 +182,7 @@ def step_report(
         "rows": measured["rows"],
         "unattributed": measured["unattributed"],
     }
-    if peak_flops:
-        out["mfu"] = round(flops / (step_s * peak_flops), 4) if step_s \
-            else 0.0
+    out["mfu"] = round(flops / (step_s * peak_flops), 4) if step_s else 0.0
     if analytic_flops_per_step is not None and stats["hlo_flops"]:
         out["hlo_over_analytic"] = round(
             stats["hlo_flops"] / analytic_flops_per_step, 4)

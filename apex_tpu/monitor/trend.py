@@ -33,9 +33,8 @@ over the whole series:
   [--stage S]`` banks a record into the history;
   ``python -m apex_tpu.monitor.trend check HISTORY [--window W]
   [--threshold Z] [--min-records N]`` prints the verdict table to stderr,
-  one ``json_record`` line to stdout, and exits 1 on drift — the
-  tpu_watch stages run both next to (never instead of) the pairwise
-  regress gate. A history shorter than ``--min-records`` passes
+  one ``json_record`` line to stdout, and exits 1 on drift — it runs
+  next to (never instead of) the pairwise regress gate. A history shorter than ``--min-records`` passes
   trivially: the gate arms itself as evidence accumulates.
 """
 

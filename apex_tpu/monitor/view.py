@@ -4,7 +4,7 @@ The one-command read of a serve telemetry log (step records, lifecycle
 events and gauges share one JSONL file — ``view`` partitions by the
 ``kind`` field). Human table to **stderr**, one machine-readable
 ``json_record`` line to **stdout** — the bench.py pipe convention, so
-``tpu_watch.sh`` and humans read the same invocation.
+scripts and humans read the same invocation.
 
 Per-request latencies are reconstructed from the lifecycle events
 (``submitted → admitted → first_token → retired``); pass SLO budgets

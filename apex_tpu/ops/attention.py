@@ -29,13 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Finite stand-in for -inf: keeps exp() exact zero without nan from (-inf) - (-inf).
 NEG_INF = -1e30
@@ -296,6 +291,7 @@ def _fa_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret,
         inputs.append(bias)
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(bh, nq, nk),
         in_specs=in_specs,
         out_specs=[
@@ -531,6 +527,7 @@ def _fa_bwd(q3, k3, v3, o3, lse, do3, scale, causal, block_q, block_k,
         dq_inputs.append(bias)
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_bwd_dq",
         grid=(bh, nq, nk),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -569,6 +566,7 @@ def _fa_bwd(q3, k3, v3, o3, lse, do3, scale, causal, block_q, block_k,
         dkv_inputs.append(bias)
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_bwd_dkv",
         grid=(bh, nk, nq),
         in_specs=dkv_specs,
         out_specs=[
@@ -606,6 +604,7 @@ def _fa_bwd(q3, k3, v3, o3, lse, do3, scale, causal, block_q, block_k,
 
     db = pl.pallas_call(
         dbias_kernel,
+        name="flash_bwd_dbias",
         # batch innermost ("arbitrary"): the (h, q, k) tile accumulates
         # its batch sum in scratch and writes once at the last batch item
         grid=(num_heads, nq, nk, nb),
@@ -733,8 +732,6 @@ def _pick_block(seq: int, want: int) -> Optional[int]:
 
 
 def _pallas_ok(sq, sk, d, causal, allow_interpret):
-    if not _HAS_PALLAS:
-        return False
     if _pick_block(sq, 128) is None or _pick_block(sk, 128) is None:
         return False
     if d % 8 != 0:

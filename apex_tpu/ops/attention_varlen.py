@@ -35,13 +35,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax import lax
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops._pallas_util import sds as _sds
 from apex_tpu.ops._pallas_util import compiled_backend as _compiled_backend
@@ -551,7 +546,7 @@ def flash_attention_varlen(
         scale = 1.0 / math.sqrt(d)
     bq = _pick_block(sq, block_q)
     bk = _pick_kv_block(sk, block_k)
-    if (_HAS_PALLAS and d % 8 == 0 and (bq is None or bk is None)
+    if (d % 8 == 0 and (bq is None or bk is None)
             and (use_pallas or (use_pallas is None and _compiled_backend()))):
         # seq lengths with no legal block (e.g. sk = 2056: 8-aligned but
         # not 128-divisible and past the one-block VMEM cap) would
@@ -575,8 +570,7 @@ def flash_attention_varlen(
         # pq == pk == 0: the seq is already aligned and the block pick
         # still failed (a block hint < 8 on an aligned seq) — padding
         # cannot fix that; fall through to the error/fallback below
-    fits = (_HAS_PALLAS and bq is not None and bk is not None
-            and d % 8 == 0)
+    fits = bq is not None and bk is not None and d % 8 == 0
     if use_pallas is None:
         use_pallas = fits and _compiled_backend()
     elif use_pallas and not fits:

@@ -15,8 +15,9 @@ into ONE kernel per leaf:
   (or feeds ``-lr·u`` to optax) — the one op deliberately left outside,
   since LAMB must scale ``u`` by the trust ratio first and FusedAdam's
   optax contract returns updates, not params.
-* :func:`fused_lamb_tail` — the same kernel with two extra ``(1, 1)``
-  outputs accumulated across the sequential grid: the LOCAL sq-sums
+* :func:`fused_lamb_tail` — the same kernel with two extra ``(8, 128)``
+  per-lane partial-sum tiles accumulated across the sequential grid (folded
+  by the wrapper): the LOCAL sq-sums
   ``Σp²`` and ``Σu²`` that LAMB's trust ratio needs (the Pallas analogue
   of the reference's two-stage ``multi_tensor_l2norm``); the caller
   psums them over the dp axis and applies ``p - lr·trust·u``.
@@ -44,15 +45,11 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.ops._pallas_util import compiled_backend as _compiled_backend
+from apex_tpu.ops._pallas_util import mosaic_placeable as _mosaic_placeable
 from apex_tpu.ops._pallas_util import sds as _sds
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
 _TILE = 8 * _LANES        # fp32 min tile: small leaves pad to a multiple
@@ -115,21 +112,20 @@ def _tail_kernel(c_ref, g_ref, m_ref, v_ref, p_ref, *refs,
     m_out[:] = m_new
     v_out[:] = v_new
     if with_norms:
-        # sequential-grid accumulation into one (1, 1) block (the
-        # layer_norm backward's partial-grad idiom); zero padding adds 0
+        # sequential-grid accumulation into one resident (8, 128) tile per
+        # norm: per-lane partial sums, added as whole vregs (Mosaic cannot
+        # store a scalar to VMEM); the caller folds the tile. Zero padding
+        # adds 0.
         @pl.when(pl.program_id(0) == 0)
         def _init():
-            wsq_ref[0, 0] = 0.0
-            usq_ref[0, 0] = 0.0
+            wsq_ref[:] = jnp.zeros_like(wsq_ref)
+            usq_ref[:] = jnp.zeros_like(usq_ref)
 
-        wsq_ref[0, 0] += jnp.sum(p * p)
-        usq_ref[0, 0] += jnp.sum(u * u)
+        def lane_sums(x):
+            return jnp.sum(x.reshape(-1, 8, _LANES), axis=0)
 
-
-def _pallas_ok(allow_interpret: bool) -> bool:
-    if not _HAS_PALLAS:
-        return False
-    return allow_interpret or _compiled_backend()
+        wsq_ref[:] += lane_sums(p * p)
+        usq_ref[:] += lane_sums(u * u)
 
 
 def _tail_pallas(g, m, v, p, c1, c2, *, betas, eps, weight_decay,
@@ -155,10 +151,11 @@ def _tail_pallas(g, m, v, p, c1, c2, *, betas, eps, weight_decay,
     out_specs = [row_spec, row_spec, row_spec]
     out_shape = [_sds((rows, _LANES), jnp.float32, g, m, v, p)] * 3
     if with_norms:
-        out_specs += [pl.BlockSpec((1, 1), lambda i: (0, 0))] * 2
-        out_shape += [_sds((1, 1), jnp.float32, g, m, v, p)] * 2
+        out_specs += [pl.BlockSpec((8, _LANES), lambda i: (0, 0))] * 2
+        out_shape += [_sds((8, _LANES), jnp.float32, g, m, v, p)] * 2
     out = pl.pallas_call(
         kernel,
+        name="lamb_tail" if with_norms else "adam_tail",
         grid=(rows // block,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # (1, 2) c1/c2
@@ -166,6 +163,9 @@ def _tail_pallas(g, m, v, p, c1, c2, *, betas, eps, weight_decay,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
+        # the norm tiles accumulate across the grid: it must run in order
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(c, *mats)
 
@@ -174,7 +174,7 @@ def _tail_pallas(g, m, v, p, c1, c2, *, betas, eps, weight_decay,
 
     u, m_new, v_new = (unpad(a) for a in out[:3])
     if with_norms:
-        return u, m_new, v_new, out[3][0, 0], out[4][0, 0]
+        return u, m_new, v_new, jnp.sum(out[3]), jnp.sum(out[4])
     return u, m_new, v_new
 
 
@@ -188,9 +188,7 @@ def fused_adam_tail(g, m, v, p, c1, c2, *, betas, eps,
     fp32 in the input shape. Returns ``(u, m', v')`` — apply with
     ``p - lr·u``."""
     if use_pallas is None:
-        use_pallas = _pallas_ok(allow_interpret=False)
-    elif use_pallas and not _pallas_ok(allow_interpret=True):
-        raise ValueError("pallas fused_adam_tail needs pallas importable")
+        use_pallas = _compiled_backend()
     if not use_pallas:
         if interpret is not None:
             raise ValueError("interpret= only applies to the Pallas path")
@@ -212,9 +210,7 @@ def fused_lamb_tail(g, m, v, p, c1, c2, *, betas, eps,
     sq-sums accumulated in-kernel (LOCAL — psum them over dp, then
     ``p - lr·trust·u``)."""
     if use_pallas is None:
-        use_pallas = _pallas_ok(allow_interpret=False)
-    elif use_pallas and not _pallas_ok(allow_interpret=True):
-        raise ValueError("pallas fused_lamb_tail needs pallas importable")
+        use_pallas = _compiled_backend()
     if not use_pallas:
         if interpret is not None:
             raise ValueError("interpret= only applies to the Pallas path")
@@ -230,17 +226,18 @@ def fused_lamb_tail(g, m, v, p, c1, c2, *, betas, eps,
 
 def resolve_fused(mode: str, what: str = "fused_update") -> bool:
     """``"auto" | "on" | "off"`` -> whether to run the fused kernels.
-    ``auto`` picks them only where they are a win — a compiled Mosaic
-    backend; off-TPU the interpreter just re-expands the kernel body into
-    the same XLA ops, saving no dispatch (``"on"`` forces exactly that,
-    which is how the parity tests run)."""
+    ``auto`` picks them only where they are a win AND can be placed — a
+    compiled Mosaic backend, traced inside a ``shard_map`` body or on a
+    one-device host (an optimizer stepping sharded leaves under plain
+    ``jit`` would hand the partitioner a kernel it cannot split); off-TPU
+    the interpreter just re-expands the kernel body into the same XLA ops,
+    saving no dispatch (``"on"`` forces exactly that, which is how the
+    parity tests run). Call at trace time, where the step is traced."""
     if mode == "off":
         return False
     if mode == "on":
-        if not _HAS_PALLAS:
-            raise ValueError(f"{what}='on' but pallas is not importable")
         return True
     if mode == "auto":
-        return _HAS_PALLAS and _compiled_backend()
+        return _compiled_backend() and _mosaic_placeable()
     raise ValueError(
         f"{what} must be 'auto', 'on' or 'off', got {mode!r}")

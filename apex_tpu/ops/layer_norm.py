@@ -27,16 +27,12 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.ops._pallas_util import sds as _sds
 from apex_tpu.ops._pallas_util import compiled_backend as _compiled_backend
+from apex_tpu.ops._pallas_util import pvary_like as _pvary_like
+from apex_tpu.ops._pallas_util import sds as _sds
 
-try:  # Pallas is part of jax, but keep import-failure graceful (CPU-only envs)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 # ---------------------------------------------------------------------------
@@ -161,8 +157,6 @@ def _pallas_ok(rows: int, hidden: int, allow_interpret: bool) -> bool:
     """Shape/platform gate. By default the Pallas path is only *selected* on
     real TPU; off-TPU it runs through the (slow) Pallas interpreter and is
     therefore opt-in via use_pallas=True (tests do this)."""
-    if not _HAS_PALLAS:
-        return False
     if _pick_block_rows(rows, hidden) is None:
         return False
     if hidden % 128 != 0:
@@ -190,6 +184,7 @@ def _ln_fwd(x2d, w, b, eps):
     kernel = functools.partial(_ln_fwd_kernel, eps=eps, hidden=hidden)
     y, mean, rstd = pl.pallas_call(
         kernel,
+        name="layer_norm_fwd",
         grid=(rows // block,),
         in_specs=[
             pl.BlockSpec((block, hidden), lambda i: (i, 0)),
@@ -223,6 +218,7 @@ def _layer_norm_affine_bwd(eps, res, dy):
     kernel = functools.partial(_ln_bwd_kernel, hidden=hidden)
     dx, dw, db = pl.pallas_call(
         kernel,
+        name="layer_norm_bwd",
         grid=(rows // block,),
         in_specs=[
             pl.BlockSpec((block, hidden), lambda i: (i, 0)),
@@ -261,6 +257,7 @@ def _rms_fwd(x2d, w, eps):
     kernel = functools.partial(_rms_fwd_kernel, eps=eps, hidden=hidden)
     y, rstd = pl.pallas_call(
         kernel,
+        name="rms_norm_fwd",
         grid=(rows // block,),
         in_specs=[
             pl.BlockSpec((block, hidden), lambda i: (i, 0)),
@@ -291,6 +288,7 @@ def _rms_norm_affine_bwd(eps, res, dy):
     kernel = functools.partial(_rms_bwd_kernel, hidden=hidden)
     dx, dw = pl.pallas_call(
         kernel,
+        name="rms_norm_bwd",
         grid=(rows // block,),
         in_specs=[
             pl.BlockSpec((block, hidden), lambda i: (i, 0)),
@@ -344,7 +342,11 @@ def layer_norm(
     if not use_pallas or weight is None or bias is None:
         return layer_norm_reference(x, weight, bias, eps)
     x2d = x.reshape(rows, hidden)
-    return _layer_norm_affine(x2d, weight, bias, eps).reshape(x.shape)
+    # replicated affine params under a data-sharded batch: the custom_vjp
+    # hides their linearity from shard_map, so the dw/db reduction over the
+    # data axes has to be made explicit (``pvary_like``)
+    return _layer_norm_affine(x2d, _pvary_like(weight, x2d),
+                              _pvary_like(bias, x2d), eps).reshape(x.shape)
 
 
 def rms_norm(
@@ -367,6 +369,7 @@ def rms_norm(
     if not use_pallas or weight is None:
         return rms_norm_reference(x, weight, eps)
     x2d = x.reshape(rows, hidden)
-    return _rms_norm_affine(x2d, weight, eps).reshape(x.shape)
+    return _rms_norm_affine(x2d, _pvary_like(weight, x2d),
+                            eps).reshape(x.shape)
 
 

@@ -37,13 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops._pallas_util import sds as _sds
 from apex_tpu.ops._pallas_util import compiled_backend as _compiled_backend
@@ -88,6 +83,14 @@ def lm_head_loss_reference(x2, w, targets, axis_name: Optional[str] = None):
 # vocab block is masked with a column iota (V need not divide block_v).
 
 
+# Scoped-VMEM ceiling handed to Mosaic for the three kernels. The compiler
+# reports the dx kernel's tile set at 14.99 MiB for (block_n 1024, block_v
+# 512, hidden 768) and 17.99 MiB once the vocab shard is ragged (tp=2:
+# 25152 % 512 != 0 adds the masking temporaries) — over XLA's 16 MiB default
+# scoped limit, far inside the 128 MiB a v5e core has.
+_VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+
+
 def _col_ids(v_i, block_n, block_v):
     return v_i * block_v + lax.broadcasted_iota(
         jnp.int32, (block_n, block_v), 1)
@@ -114,6 +117,11 @@ def _fwd_kernel(t_ref, x_ref, w_ref, lse_ref, pred_ref, m_scr, l_scr, p_scr,
         s = jnp.where(col >= v_total, NEG_INF, s)
     t = t_ref[...]  # (block_n, 1) int32, local ids (may be out of range)
     hit = col == t
+    if v_total % block_v:
+        # another vocab shard's target can carry a local id that lands on
+        # one of the ragged last block's PADDED columns; its score there is
+        # the NEG_INF mask, not a logit — it must not be picked
+        hit = hit & (col < v_total)
     p_scr[:, :1] += jnp.sum(jnp.where(hit, s, 0.0), axis=1, keepdims=True)
     m_prev = m_scr[:, :1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -197,6 +205,7 @@ def _run_fwd(x2, w, t_local, block_n, block_v, interpret):
                                nv=nv, v_total=v)
     lse, pred = pl.pallas_call(
         kernel,
+        name="lm_head_fwd",
         grid=(nn, nv),
         in_specs=[
             pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
@@ -217,7 +226,8 @@ def _run_fwd(x2, w, t_local, block_n, block_v, interpret):
             pltpu.VMEM((block_n, 128), jnp.float32),
         ],
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(t_local[:, None], x2, w)
     return lse[:, 0], pred[:, 0]
@@ -244,6 +254,7 @@ def _run_bwd(x2, w, t_local, lse, g, block_n, block_v, interpret):
     dx = pl.pallas_call(
         functools.partial(_dx_kernel, block_n=block_n, block_v=block_v,
                           nv=nv, v_total=v),
+        name="lm_head_bwd_dx",
         grid=(nn, nv),
         in_specs=[
             pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
@@ -256,13 +267,15 @@ def _run_bwd(x2, w, t_local, lse, g, block_n, block_v, interpret):
         out_shape=_sds((n, h), x2.dtype, x2, w, t_local, g),
         scratch_shapes=[pltpu.VMEM((block_n, h), jnp.float32)],
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(t2, g2, lse2, x2, w)
 
     dw = pl.pallas_call(
         functools.partial(_dw_kernel, block_n=bn_dw, block_v=bv_dw,
                           nn=nn_dw, v_total=v),
+        name="lm_head_bwd_dw",
         grid=(nv_dw, nn_dw),
         in_specs=[
             pl.BlockSpec((bn_dw, 1), lambda j, i: (i, 0)),
@@ -275,7 +288,8 @@ def _run_bwd(x2, w, t_local, lse, g, block_n, block_v, interpret):
         out_shape=_sds((v, h), w.dtype, x2, w, t_local, g),
         scratch_shapes=[pltpu.VMEM((bv_dw, h), jnp.float32)],
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(t2, g2, lse2, x2, w)
     return dx, dw
@@ -391,8 +405,6 @@ def pallas_fits(n: int, h: int, block_n: int = DEFAULT_BLOCK_N) -> bool:
     unfused alternative (e.g. logits+CE) should check this before choosing
     the fused path, because the shape fallback below is a dense fp32
     reference, not a tuned kernel."""
-    if not _HAS_PALLAS:
-        return False
     return _resolve_block_n(n, block_n) is not None and h % 128 == 0
 
 
@@ -418,13 +430,13 @@ def lm_head_loss(
     t1 = targets.reshape(-1)
     n = x2.shape[0]
     bn = _resolve_block_n(n, block_n)
-    fits = _HAS_PALLAS and bn is not None and h % 128 == 0
+    fits = bn is not None and h % 128 == 0
     if use_pallas is None:
         use_pallas = fits and _compiled_backend()
     elif use_pallas and not fits:
         raise ValueError(
-            f"pallas lm_head_loss needs pallas available, a row block "
-            f"dividing rows ({n}), and hidden ({h}) divisible by 128")
+            f"pallas lm_head_loss needs a row block dividing rows ({n}) "
+            f"and hidden ({h}) divisible by 128")
     if bn is None:
         bn = n  # dense impl ignores the block size
     if use_pallas:

@@ -67,7 +67,9 @@ def FusedAdam(
     b1, b2 = betas
 
     def init(params):
-        zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
+        # zeros_like keeps each leaf's sharding: moments live where their
+        # parameter does, not on the default device
+        zeros = lambda p: jnp.zeros_like(p, dtype=jnp.float32)
         return FusedAdamState(
             count=jnp.zeros((), jnp.int32),
             mu=tree_map(zeros, params),

@@ -21,6 +21,7 @@ from typing import Optional, Sequence, Tuple
 import numpy as np
 
 import jax
+from jax import lax
 from jax.sharding import Mesh
 
 # Canonical axis names, outermost → innermost.
@@ -36,13 +37,8 @@ def axis_size(axis_name, mesh: Optional[Mesh] = None) -> int:
 
     Two calling conventions share this door:
 
-    * ``axis_size(name)`` — the bound size from inside a mesh program.
-      ``lax.axis_size`` on graft jax; on stock 0.4.37 that spelling does
-      not exist, so ``jax.core.axis_frame(name)`` reads the traced axis
-      env instead. Modules on the serve-plan path resolve the world size
-      through here so a ``ParallelismPlan``-sharded engine runs on either
-      toolchain (the same compatibility contract as the shard_map
-      ``check_vma``/``check_rep`` shim in ``serve.sharded``).
+    * ``axis_size(name)`` — the bound size from inside a mesh program
+      (``lax.axis_size``).
     * ``axis_size(mesh, name)`` — static lookup outside any trace,
       ``mesh.shape[name]``.
     """
@@ -50,11 +46,7 @@ def axis_size(axis_name, mesh: Optional[Mesh] = None) -> int:
         return axis_name.shape[mesh]
     if mesh is not None:
         return mesh.shape[axis_name]
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return jax.core.axis_frame(axis_name)
+    return lax.axis_size(axis_name)
 
 
 def build_mesh(

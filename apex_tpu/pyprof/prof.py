@@ -156,8 +156,8 @@ def op_table(
     fn: Callable,
     *args: Any,
     depth: int = 2,
-    peak_flops: float = 197e12,
-    hbm_bandwidth: float = 819e9,
+    peak_flops: Optional[float] = None,
+    hbm_bandwidth: Optional[float] = None,
     **kwargs: Any,
 ) -> List[Dict[str, Any]]:
     """Per-op roofline attribution of a jittable function.
@@ -166,9 +166,20 @@ def op_table(
     (fusions counted whole, their inner dots attributed to them):
     ``{scope, op, flops, bytes, est_time_s, bound}``, aggregated up to
     ``depth`` segments of the ``named_scope`` path and sorted by estimated
-    time. ``peak_flops`` / ``hbm_bandwidth`` default to TPU v5e spec; pass
+    time. ``peak_flops`` / ``hbm_bandwidth`` default to the published peaks
+    of the device JAX reports (``utils.platform.DEVICE_PEAKS``); pass
     measured numbers for a calibrated roofline.
     """
+    if peak_flops is None or hbm_bandwidth is None:
+        # an unknown device kind (the CPU included) raises: the roofline is
+        # never taken against an assumed chip
+        from apex_tpu.utils.platform import device_peaks
+
+        peaks = device_peaks()
+        if peak_flops is None:
+            peak_flops = peaks.bf16_flops_per_s
+        if hbm_bandwidth is None:
+            hbm_bandwidth = peaks.hbm_bytes_per_s
     lowered = jax.jit(fn).lower(*args, **kwargs)
     hlo = lowered.compile().as_text()
     comps, entry = _parse_hlo(hlo)
@@ -206,8 +217,8 @@ def op_table(
 
     out = list(rows.values())
     for r in out:
-        t_c = r["flops"] / peak_flops if peak_flops else 0.0
-        t_m = r["bytes"] / hbm_bandwidth if hbm_bandwidth else 0.0
+        t_c = r["flops"] / peak_flops
+        t_m = r["bytes"] / hbm_bandwidth
         r["est_time_s"] = max(t_c, t_m)
         r["bound"] = "compute" if t_c >= t_m else "memory"
     out.sort(key=lambda r: -r["est_time_s"])
@@ -241,7 +252,8 @@ def format_table(rows: List[Dict[str, Any]], top: int = 25) -> str:
 
 
 def report(fn: Callable, *args: Any, depth: int = 2, top: int = 25,
-           peak_flops: float = 197e12, hbm_bandwidth: float = 819e9,
+           peak_flops: Optional[float] = None,
+           hbm_bandwidth: Optional[float] = None,
            **kwargs: Any) -> str:
     """One-command per-op report for a jittable step (printed + returned)."""
     table = format_table(

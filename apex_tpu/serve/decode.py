@@ -63,13 +63,8 @@ from apex_tpu.ops.layer_norm import layer_norm
 from apex_tpu.parallel.mesh import axis_size as _axis_size
 from apex_tpu.serve.kv_cache import KVCacheConfig, gather_kv, paged_write
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 Pytree = Any
 
@@ -140,36 +135,37 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *refs,
 
     @pl.when(j * block_size < ctx)
     def _compute():
-        q = q_ref[0]                       # (H, D)
+        q = q_ref[0]                       # (H, 1, D)
         k = k_ref[:, 0]                    # (H, bs, D) | packed (H, bs, D/2)
         v = v_ref[:, 0]
         if quantized and kv_bits == 4:
             k = _nibble_dequant(k, ks_ref[:, 0], kv_group)
             v = _nibble_dequant(v, vs_ref[:, 0], kv_group)
         elif quantized:
-            k = k.astype(jnp.float32) * ks_ref[:, 0][..., None]
-            v = v.astype(jnp.float32) * vs_ref[:, 0][..., None]
+            k = k.astype(jnp.float32) * ks_ref[:, 0, 0][..., None]
+            v = v.astype(jnp.float32) * vs_ref[:, 0, 0][..., None]
+        dt = jnp.promote_types(q.dtype, k.dtype)  # Mosaic: one dot dtype
         s = lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale  # (H, bs)
+            q.astype(dt), k.astype(dt), (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale  # (H, 1, bs)
         kpos = j * block_size + lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
+            jnp.int32, s.shape, 2)
         s = jnp.where(kpos >= ctx, NEG_INF, s)
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_prev = m_scr[:, :, :1]
+        l_prev = l_scr[:, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        l_new = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        l_new = corr * l_prev + jnp.sum(p, axis=2, keepdims=True)
         acc_scr[:] = acc_scr[:] * corr + lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)          # (H, 1, D)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
     @pl.when(j == nb - 1)
     def _finish():
-        l = l_scr[:, :1]
+        l = l_scr[:, :, :1]
         safe_l = jnp.where(l == 0.0, 1.0, l)  # ctx==0 slot: emit zeros
         o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
 
@@ -188,17 +184,17 @@ def _paged_pallas(q, cache_layer, cfg: KVCacheConfig, block_tables,
         jl = jnp.maximum(ln[i] - 1, 0) // bs
         return (0, bt[i * nb + jnp.minimum(j, jl)], 0, 0)
 
-    def blk_index_s(i, j, bt, ln):
-        jl = jnp.maximum(ln[i] - 1, 0) // bs
-        return (0, bt[i * nb + jnp.minimum(j, jl)], 0)
-
+    # the single query row keeps a unit q dim all the way through: Mosaic's
+    # batched matmul needs a free (non-contracting) dim on both operands,
+    # and a (H, D) <-> (H, 1, D) shape cast inside the kernel does not lower
+    row_spec = pl.BlockSpec((1, h, 1, d), lambda i, j, bt, ln: (i, 0, 0, 0))
     dk = d // 2 if cfg.quantized and cfg.bits == 4 else d
     in_specs = [
-        pl.BlockSpec((1, h, d), lambda i, j, bt, ln: (i, 0, 0)),
+        row_spec,
         pl.BlockSpec((h, 1, bs, dk), blk_index),
         pl.BlockSpec((h, 1, bs, dk), blk_index),
     ]
-    inputs = [q, cache_layer["k"], cache_layer["v"]]
+    inputs = [q[:, :, None, :], cache_layer["k"], cache_layer["v"]]
     if cfg.quantized and cfg.bits == 4:
         # group scales carry a trailing head_dim/group dim — same 4-d
         # rank as the packed code pools, same block walk
@@ -207,9 +203,10 @@ def _paged_pallas(q, cache_layer, cfg: KVCacheConfig, block_tables,
                      pl.BlockSpec((h, 1, bs, gdim), blk_index)]
         inputs += [cache_layer["k_scale"], cache_layer["v_scale"]]
     elif cfg.quantized:
-        in_specs += [pl.BlockSpec((h, 1, bs), blk_index_s),
-                     pl.BlockSpec((h, 1, bs), blk_index_s)]
-        inputs += [cache_layer["k_scale"], cache_layer["v_scale"]]
+        in_specs += [pl.BlockSpec((h, 1, 1, bs), blk_index),
+                     pl.BlockSpec((h, 1, 1, bs), blk_index)]
+        inputs += [_scale_rows(cache_layer["k_scale"]),
+                   _scale_rows(cache_layer["v_scale"])]
     kernel = functools.partial(
         _paged_kernel, scale=scale, block_size=bs, nb=nb,
         quantized=cfg.quantized, kv_bits=cfg.bits if cfg.quantized else 8,
@@ -218,46 +215,65 @@ def _paged_pallas(q, cache_layer, cfg: KVCacheConfig, block_tables,
         num_scalar_prefetch=2,
         grid=(n, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, d), lambda i, j, bt, ln: (i, 0, 0)),
+        out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((h, 128), jnp.float32),
-            pltpu.VMEM((h, 128), jnp.float32),
-            pltpu.VMEM((h, d), jnp.float32),
+            pltpu.VMEM((h, 1, 128), jnp.float32),
+            pltpu.VMEM((h, 1, 128), jnp.float32),
+            pltpu.VMEM((h, 1, d), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
+        name="paged_attention",
         grid_spec=grid_spec,
-        out_shape=_sds((n, h, d), q.dtype, q),
+        out_shape=_sds((n, h, 1, d), q.dtype, q),
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(bt_flat, lens, *inputs)
+    )(bt_flat, lens, *inputs)[:, :, 0, :]
 
 
-def _pallas_ok(head_dim: int, allow_interpret: bool) -> bool:
-    if not _HAS_PALLAS or head_dim % 8 != 0:
-        return False
-    return allow_interpret or _compiled_backend()
+def _scale_rows(scale_pool):
+    """int8 scale pool (H, B, bs) -> (H, B, 1, bs): a free bitcast that
+    makes one pool block's scales a tile-legal BlockSpec (a (H, 1, bs)
+    block of the 3-d pool has second-to-last dim 1, neither 8-divisible
+    nor the full dim — the varlen ``_seg_wide`` class of bug)."""
+    return scale_pool[:, :, None, :]
 
 
-# head_dims whose silent kernel->reference fallback was already logged
-# (warn ONCE per shape: a 10x slower serve run must be diagnosable from
-# the log, not only from the bench line)
+def paged_kernel_refusal(cfg: KVCacheConfig,
+                         compiled: bool) -> Optional[str]:
+    """Why the Pallas gather-attend kernel cannot serve this pool —
+    ``None`` when it can. ``compiled``: whether the kernel would go through
+    Mosaic (the interpreter takes everything but a misaligned head_dim)."""
+    if cfg.head_dim % 8 != 0:
+        return (f"head_dim {cfg.head_dim} % 8 != 0 (sublane alignment; pad "
+                f"head_dim to a multiple of 8 to get the kernel)")
+    if compiled and cfg.quantized and cfg.bits == 4:
+        return ("int4 pools: the in-kernel nibble unpack interleaves lanes "
+                "through a shape cast Mosaic refuses (infer-vector-layout: "
+                "unsupported shape cast, tpu.reshape vector<HxbsxD/2xi8> -> "
+                "vector<HxbsxD/2x1xi8>); the compiled kernel serves fp and "
+                "int8 pools")
+    return None
+
+
+# refusals already logged (warn ONCE per reason: a 10x slower serve run
+# must be diagnosable from the log, not only from the bench line)
 _FALLBACK_WARNED: set = set()
 
 
-def _warn_reference_fallback(head_dim: int) -> None:
-    if head_dim in _FALLBACK_WARNED:
+def _warn_reference_fallback(reason: str) -> None:
+    if reason in _FALLBACK_WARNED:
         return
-    _FALLBACK_WARNED.add(head_dim)
+    _FALLBACK_WARNED.add(reason)
     from apex_tpu._logging import get_logger
 
     get_logger("apex_tpu.serve").warning(
-        "paged_attention: head_dim %d %% 8 != 0 — falling back to the "
-        "pure-JAX gather+reference path on a compiled TPU backend "
-        "(expect a much slower decode step; pad head_dim to a multiple "
-        "of 8 to get the Pallas kernel)", head_dim)
+        "paged_attention: falling back to the pure-JAX gather+reference "
+        "path on a compiled TPU backend (expect a much slower decode "
+        "step) — %s",
+        reason)
 
 
 def paged_attention(q, cache_layer, cfg: KVCacheConfig, block_tables,
@@ -265,20 +281,17 @@ def paged_attention(q, cache_layer, cfg: KVCacheConfig, block_tables,
                     use_pallas: Optional[bool] = None,
                     interpret: Optional[bool] = None):
     """Dispatching front door: Pallas gather-attend on compiled TPU
-    backends (head_dim % 8), the gather+reference path elsewhere — the
-    ``flash_attention`` gating pattern. Same signature/result as
-    :func:`paged_attention_reference`."""
+    backends unless :func:`paged_kernel_refusal` names a reason, the
+    gather+reference path elsewhere — the ``flash_attention`` gating
+    pattern. Same signature/result as :func:`paged_attention_reference`."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    compiled = _compiled_backend()
     if use_pallas is None:
-        use_pallas = _pallas_ok(q.shape[-1], allow_interpret=False)
-        if (not use_pallas and _HAS_PALLAS and _compiled_backend()
-                and q.shape[-1] % 8 != 0):
-            _warn_reference_fallback(q.shape[-1])
-    elif use_pallas and not _pallas_ok(q.shape[-1], allow_interpret=True):
-        raise ValueError(
-            f"pallas paged_attention needs head_dim % 8 == 0 "
-            f"(got {q.shape[-1]}) and pallas available")
+        reason = paged_kernel_refusal(cfg, compiled)
+        use_pallas = compiled and reason is None
+        if compiled and reason is not None:
+            _warn_reference_fallback(reason)
     if not use_pallas:
         if interpret is not None:
             raise ValueError(
@@ -287,7 +300,10 @@ def paged_attention(q, cache_layer, cfg: KVCacheConfig, block_tables,
         return paged_attention_reference(q, cache_layer, cfg, block_tables,
                                          ctx_lens, scale=scale)
     if interpret is None:
-        interpret = not _compiled_backend()
+        interpret = not compiled
+    reason = paged_kernel_refusal(cfg, compiled=not interpret)
+    if reason is not None:
+        raise ValueError(f"pallas paged_attention refused: {reason}")
     return _paged_pallas(q, cache_layer, cfg, block_tables, ctx_lens,
                          scale, interpret)
 

@@ -565,12 +565,13 @@ class InferenceEngine:
         regression."""
         if self._megakernel:
             return "fused"
-        from apex_tpu.serve.decode import _pallas_ok
+        from apex_tpu.ops._pallas_util import compiled_backend
+        from apex_tpu.serve.decode import paged_kernel_refusal
 
         use_pallas = self._use_pallas
         if use_pallas is None:
-            use_pallas = _pallas_ok(self.cfg.head_dim,
-                                    allow_interpret=False)
+            use_pallas = compiled_backend() and paged_kernel_refusal(
+                self.kv_cfg, compiled=True) is None
         return "pallas" if use_pallas else "reference"
 
     @property

@@ -69,13 +69,8 @@ from apex_tpu.ops._pallas_util import sds as _sds
 from apex_tpu.ops.attention import NEG_INF
 from apex_tpu.serve.kv_cache import KVCacheConfig, paged_write
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 Pytree = Any
 
@@ -85,8 +80,13 @@ from apex_tpu.comm.quantize import QMAX as _QMAX  # the codec's code range:
 # VMEM budget for the fused block's live set: the CURRENT weight tiles
 # (double-buffered while their phase streams), the resident bias/norm
 # vectors, one pool block per pool (double-buffered walk) and the fp32
-# activation scratch. Well under the ~16 MB/core so Mosaic keeps
-# headroom for its own spills.
+# activation scratch — a MODEL, kept under XLA's 16 MiB default scoped-VMEM
+# limit on v5e (of 128 MiB per core) so Mosaic keeps headroom for its own
+# spills. It is an upper bound: for the GPT-2-124M bf16 layer at tiles
+# (1, 1, 4) it counts 9.6 MB where the compiler allocates 2.50 MiB of
+# scoped VMEM inside the 12-layer decode program (jax 0.9.0, v5e AOT
+# compile; fp32 weights at (6, 1, 8) compile too) — XLA stages the weight
+# operands itself — so no ``vmem_limit_bytes`` is needed.
 _VMEM_BUDGET_BYTES = 10 * 1024 * 1024
 _LANE = 128
 
@@ -219,8 +219,6 @@ def megakernel_refusal(cfg, kv_cfg: KVCacheConfig,
     when it is supported. Budget refusals report the MEASURED bytes
     (finest-tiling live set vs the budget) so operators see how far
     over a config is, not a bare no."""
-    if not _HAS_PALLAS:
-        return "pallas is not importable"
     if cfg.num_experts:
         return ("MoE layers (num_experts > 0) — the fused block assumes "
                 "a dense FFN (ROADMAP item 5a)")
@@ -236,6 +234,15 @@ def megakernel_refusal(cfg, kv_cfg: KVCacheConfig,
     if not allow_interpret and not compiled:
         return ("no compiled Mosaic backend (interpret mode simulates "
                 "the kernel — it saves no dispatch)")
+    if compiled:
+        # the fused block walks the pool with the paged kernel's own
+        # dequant: what Mosaic refuses there (int4's nibble unpack) it
+        # refuses here
+        from apex_tpu.serve.decode import paged_kernel_refusal
+
+        reason = paged_kernel_refusal(kv_cfg, compiled=True)
+        if reason is not None:
+            return reason
     tiles = default_tiles(cfg, kv_cfg, q=q, compiled=compiled)
     if tiles is None:
         finest = _finest_tiles(cfg, compiled)
@@ -409,33 +416,39 @@ def _fused_block_kernel(bt_ref, len_ref, x_ref, ln1w_ref, ln1b_ref,
 
     @pl.when(j == a_end - 1)
     def _emit_qkv():
-        # per-head interleaved unpack (the standalone_gpt packing), one
-        # fed row at a time: row-major (1, 3h) -> (H, 3, D)
-        for w in range(q_rows):
-            hq = qkv_scr[w:w + 1, :].reshape(heads, 3, head_dim)
-            qh, kh, vh = hq[:, 0], hq[:, 1], hq[:, 2]         # (H, D) f32
-            q_scr[w] = qh
-            # the EMITTED values (model dtype) are what paged_write
-            # consumes — the in-register fold must round-trip through
-            # that cast first, or a bf16 model's codec scales/codes
-            # diverge from the pool's
-            kq = kh.astype(ko_ref.dtype)
-            vq = vh.astype(vo_ref.dtype)
-            ko_ref[0, w] = kq
-            vo_ref[0, w] = vq
-            # what the pool hands back for this row: the codec
+        # per-head interleaved unpack (the standalone_gpt packing) as
+        # STATIC lane slices of the (q, 3h) row, one head at a time: a
+        # (1, 3h) -> (H, 3, D) shape cast does not lower in Mosaic. Heads
+        # land on a LEADING scratch dim with a unit row dim, so the pool
+        # walk's head-batched matmul gets its free dimension.
+        def from_pool(x):
+            # what the pool hands back for an emitted row: the codec
             # round-trip (int8/int4 cache) or the pool-dtype cast
             if quantized and kv_bits == 4:
-                kc_scr[w] = _codec_roundtrip4(kq.astype(jnp.float32),
-                                              kv_group)
-                vc_scr[w] = _codec_roundtrip4(vq.astype(jnp.float32),
-                                              kv_group)
-            elif quantized:
-                kc_scr[w] = _codec_roundtrip(kq.astype(jnp.float32))
-                vc_scr[w] = _codec_roundtrip(vq.astype(jnp.float32))
-            else:
-                kc_scr[w] = kq.astype(pool_dtype).astype(jnp.float32)
-                vc_scr[w] = vq.astype(pool_dtype).astype(jnp.float32)
+                return _codec_roundtrip4(x.astype(jnp.float32), kv_group)
+            if quantized:
+                return _codec_roundtrip(x.astype(jnp.float32))
+            return x.astype(pool_dtype).astype(jnp.float32)
+
+        for w in range(q_rows):
+            for hh in range(heads):
+                qh, kh, vh = (
+                    qkv_scr[w:w + 1, (3 * hh + part) * head_dim:
+                            (3 * hh + part + 1) * head_dim]   # (1, D) f32
+                    for part in range(3))
+                # q rounds through the model dtype like the per-op path's
+                # projection output (exact no-op for an fp32 model)
+                q_scr[w, hh] = qh.astype(x_ref.dtype).astype(jnp.float32)
+                # the EMITTED values (model dtype) are what paged_write
+                # consumes — the in-register fold must round-trip through
+                # that cast first, or a bf16 model's codec scales/codes
+                # diverge from the pool's
+                kq = kh.astype(ko_ref.dtype)
+                vq = vh.astype(vo_ref.dtype)
+                ko_ref[0, w, hh] = kq
+                vo_ref[0, w, hh] = vq
+                kc_scr[w, hh] = from_pool(kq)
+                vc_scr[w, hh] = from_pool(vq)
 
     @pl.when((j >= a_end) & (j < b_end)
              & ((j - a_end) * block_size < ctx))
@@ -448,27 +461,30 @@ def _fused_block_kernel(bt_ref, len_ref, x_ref, ln1w_ref, ln1b_ref,
             k = _nibble_dequant(k, ks_ref[:, 0], kv_group)
             v = _nibble_dequant(v, vs_ref[:, 0], kv_group)
         elif quantized:
-            k = k.astype(jnp.float32) * ks_ref[:, 0][..., None]
-            v = v.astype(jnp.float32) * vs_ref[:, 0][..., None]
+            k = k.astype(jnp.float32) * ks_ref[:, 0, 0][..., None]
+            v = v.astype(jnp.float32) * vs_ref[:, 0, 0][..., None]
         for w in range(q_rows):
-            qw = q_scr[w]                                     # (H, D)
+            # Mosaic wants one dtype per dot: q joins K's (the pool dtype,
+            # or fp32 after the dequant) — exact, q_scr holds model-dtype
+            # values
+            qw = q_scr[w].astype(k.dtype)                     # (H, 1, D)
             s = lax.dot_general(
-                qw, k, (((1,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32) * scale   # (H, bs)
+                qw, k, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32) * scale   # (H, 1, bs)
             kpos = ((j - a_end) * block_size
-                    + lax.broadcasted_iota(jnp.int32, s.shape, 1))
+                    + lax.broadcasted_iota(jnp.int32, s.shape, 2))
             s = jnp.where(kpos >= ctx, NEG_INF, s)
-            m_prev = m_scr[w][:, :1]
-            l_prev = l_scr[w][:, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            m_prev = m_scr[w][:, :, :1]
+            l_prev = l_scr[w][:, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
             corr = jnp.exp(m_prev - m_new)
             p = jnp.exp(s - m_new)
-            l_new = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            l_new = corr * l_prev + jnp.sum(p, axis=2, keepdims=True)
             acc_scr[w] = acc_scr[w] * corr + lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)
-            m_scr[w] = jnp.broadcast_to(m_new, (heads, _LANE))
-            l_scr[w] = jnp.broadcast_to(l_new, (heads, _LANE))
+                p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)           # (H, 1, D)
+            m_scr[w] = jnp.broadcast_to(m_new, (heads, 1, _LANE))
+            l_scr[w] = jnp.broadcast_to(l_new, (heads, 1, _LANE))
 
     @pl.when(j == b_end - 1)
     def _fold_window():
@@ -478,15 +494,15 @@ def _fused_block_kernel(bt_ref, len_ref, x_ref, ln1w_ref, ln1b_ref,
         # attends fed rows 0..w (causal within the window); the diagonal
         # is always allowed, so even ctx == 0 slots stay finite.
         for w in range(q_rows):
-            qw = q_scr[w]
-            m_prev = m_scr[w][:, :1]
-            l_prev = l_scr[w][:, :1]
+            qw = q_scr[w]                                     # (H, 1, D)
+            m_prev = m_scr[w][:, :, :1]
+            l_prev = l_scr[w][:, :, :1]
             acc = acc_scr[w]
             for t in range(w + 1):
                 kc = kc_scr[t]
                 vc = vc_scr[t]
-                s_cur = jnp.sum(qw * kc, axis=1,
-                                keepdims=True) * scale        # (H, 1)
+                s_cur = jnp.sum(qw * kc, axis=2,
+                                keepdims=True) * scale        # (H, 1, 1)
                 m_new = jnp.maximum(m_prev, s_cur)
                 corr = jnp.exp(m_prev - m_new)
                 p = jnp.exp(s_cur - m_new)
@@ -494,7 +510,11 @@ def _fused_block_kernel(bt_ref, len_ref, x_ref, ln1w_ref, ln1b_ref,
                 acc = acc * corr + p * vc
                 m_prev, l_prev = m_new, l_new
             ctx_vec = acc / l_prev                     # l >= p(diag) > 0
-            ctx_scr[w:w + 1, :] = ctx_vec.reshape(1, hd)
+            # heads back onto lanes for the out-proj GEMM, one static
+            # lane slice per head (a (H, D) -> (1, hd) cast does not lower)
+            for hh in range(heads):
+                ctx_scr[w:w + 1,
+                        hh * head_dim:(hh + 1) * head_dim] = ctx_vec[hh]
 
     # phase C: out-proj column tiles -> the fp32 residual x1
     for t in range(to):
@@ -591,10 +611,6 @@ def _fused_block(x, layer_params, cache_layer, cfg,
         jl = jnp.maximum(ln[i] - 1, 0) // bs
         return (0, bt[i * nb + jnp.clip(j - a_end, 0, jl)], 0, 0)
 
-    def blk_index_s(i, j, bt, ln):
-        jl = jnp.maximum(ln[i] - 1, 0) // bs
-        return (0, bt[i * nb + jnp.clip(j - a_end, 0, jl)], 0)
-
     dk = d // 2 if kv_cfg.quantized and kv_cfg.bits == 4 else d
     in_specs = [
         pl.BlockSpec((1, q, h), row3),             # x
@@ -630,9 +646,12 @@ def _fused_block(x, layer_params, cache_layer, cfg,
                      pl.BlockSpec((heads, 1, bs, gdim), blk_index)]
         inputs += [cache_layer["k_scale"], cache_layer["v_scale"]]
     elif kv_cfg.quantized:
-        in_specs += [pl.BlockSpec((heads, 1, bs), blk_index_s),
-                     pl.BlockSpec((heads, 1, bs), blk_index_s)]
-        inputs += [cache_layer["k_scale"], cache_layer["v_scale"]]
+        from apex_tpu.serve.decode import _scale_rows
+
+        in_specs += [pl.BlockSpec((heads, 1, 1, bs), blk_index),
+                     pl.BlockSpec((heads, 1, 1, bs), blk_index)]
+        inputs += [_scale_rows(cache_layer["k_scale"]),
+                   _scale_rows(cache_layer["v_scale"])]
     kernel = functools.partial(
         _fused_block_kernel, scale=att_scale, block_size=bs, nb=nb,
         heads=heads, head_dim=d, q_rows=q, tiles=tiles,
@@ -645,20 +664,22 @@ def _fused_block(x, layer_params, cache_layer, cfg,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, q, h), row3),
-            pl.BlockSpec((1, q, heads, d),
-                         lambda i, j, bt, ln: (i, 0, 0, 0)),
-            pl.BlockSpec((1, q, heads, d),
-                         lambda i, j, bt, ln: (i, 0, 0, 0)),
+            pl.BlockSpec((1, q, heads, 1, d),
+                         lambda i, j, bt, ln: (i, 0, 0, 0, 0)),
+            pl.BlockSpec((1, q, heads, 1, d),
+                         lambda i, j, bt, ln: (i, 0, 0, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((q, h), jnp.float32),          # h1 (LN1 rows)
             pltpu.VMEM((q, 3 * h), jnp.float32),      # qkv accumulator
-            pltpu.VMEM((q, heads, d), jnp.float32),   # q rows
-            pltpu.VMEM((q, heads, d), jnp.float32),   # fed-row K
-            pltpu.VMEM((q, heads, d), jnp.float32),   # fed-row V
-            pltpu.VMEM((q, heads, _LANE), jnp.float32),  # softmax m
-            pltpu.VMEM((q, heads, _LANE), jnp.float32),  # softmax l
-            pltpu.VMEM((q, heads, d), jnp.float32),   # softmax acc
+            # per-head rows keep a unit dim: heads lead (untiled), so the
+            # head-batched matmuls read (H, 1, D) without a shape cast
+            pltpu.VMEM((q, heads, 1, d), jnp.float32),   # q rows
+            pltpu.VMEM((q, heads, 1, d), jnp.float32),   # fed-row K
+            pltpu.VMEM((q, heads, 1, d), jnp.float32),   # fed-row V
+            pltpu.VMEM((q, heads, 1, _LANE), jnp.float32),  # softmax m
+            pltpu.VMEM((q, heads, 1, _LANE), jnp.float32),  # softmax l
+            pltpu.VMEM((q, heads, 1, d), jnp.float32),   # softmax acc
             pltpu.VMEM((q, heads * d), jnp.float32),  # attended ctx rows
             pltpu.VMEM((q, h), jnp.float32),          # residual x1
             pltpu.VMEM((q, h), jnp.float32),          # h2 (LN2 rows)
@@ -667,17 +688,18 @@ def _fused_block(x, layer_params, cache_layer, cfg,
     )
     x_new, k_new, v_new = pl.pallas_call(
         kernel,
+        name="fused_decode_block",
         grid_spec=grid_spec,
         out_shape=[
             _sds((n, q, h), x.dtype, x),
-            _sds((n, q, heads, d), x.dtype, x),
-            _sds((n, q, heads, d), x.dtype, x),
+            _sds((n, q, heads, 1, d), x.dtype, x),
+            _sds((n, q, heads, 1, d), x.dtype, x),
         ],
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(bt_flat, lens, *inputs)
-    return x_new, k_new, v_new
+    return x_new, k_new[:, :, :, 0], v_new[:, :, :, 0]
 
 
 def fused_layer_decode(x, layer_params, cache_layer, cfg,

@@ -83,19 +83,6 @@ from apex_tpu.transformer.testing.standalone_gpt import gpt_param_specs
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
-def shard_map(fn, *, mesh, in_specs, out_specs, check_vma=False):
-    """The 0.4.37 shard_map shim (the PR-9/12 test idiom, packaged):
-    graft jax exposes ``jax.shard_map(check_vma=)``; stock 0.4.37 has
-    ``jax.experimental.shard_map.shard_map(check_rep=)`` — same replication
-    semantics, older spelling. One call site, both toolchains."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
 Pytree = Any
 
 __all__ = [
@@ -178,7 +165,7 @@ def tp_transform(cfg, mesh) -> Callable[[Callable], Callable]:
     cache_spec = P(None, TP_AXIS)
 
     def wrap(fn):
-        return shard_map(
+        return jax.shard_map(
             fn, mesh=mesh,
             in_specs=_in_specs_for(fn, param_spec, cache_spec),
             out_specs=_out_specs_for(fn.__name__, cache_spec),
@@ -259,7 +246,7 @@ def _build_fsdp_engine(params, cfg, serve_cfg, plan, mesh, devices,
             k: jax.vmap(lambda row: slice_leaf(row, axis, multiple=mult))(v)
             for k, v in ls.items()}
 
-    shard_prog = jax.jit(shard_map(
+    shard_prog = jax.jit(jax.shard_map(
         _shard_layers, mesh=mesh, in_specs=(P(),),
         out_specs=P(None, axis), check_vma=False))
     shards = shard_prog(layers)
@@ -276,7 +263,7 @@ def _build_fsdp_engine(params, cfg, serve_cfg, plan, mesh, devices,
     param_spec = {"embed": P(), "head": P(), "layers": P(None, axis)}
 
     def wrap(fn):
-        return shard_map(
+        return jax.shard_map(
             fn, mesh=mesh,
             in_specs=_in_specs_for(fn, param_spec, P()),
             out_specs=_out_specs_for(fn.__name__, P()),
@@ -297,7 +284,7 @@ def _build_fsdp_engine(params, cfg, serve_cfg, plan, mesh, devices,
         return {k: jax.vmap(lambda s: fsdp.gather_leaf(s, metas[k]))(v)
                 for k, v in ls.items()}
 
-    gather_prog = jax.jit(shard_map(
+    gather_prog = jax.jit(jax.shard_map(
         _gather_all, mesh=mesh, in_specs=(P(None, axis),),
         out_specs=P(), check_vma=False))
     measured: Dict[str, float] = {}

@@ -31,9 +31,7 @@ from typing import Any, Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from apex_tpu.transformer.pipeline_parallel.schedules._compat import (
-    shard_map,
-)
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.monitor.trace import span

@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from apex_tpu.ops._pallas_util import pvary_like  # noqa: F401 (re-export)
 from apex_tpu.parallel.mesh import TP_AXIS
 from apex_tpu.parallel.mesh import axis_size as _axis_size
 from apex_tpu.transformer.tensor_parallel.utils import divide
@@ -71,25 +72,6 @@ def _split(x, axis_name: str):
     chunk = divide(x.shape[-1], world)
     rank = lax.axis_index(axis_name)
     return lax.dynamic_slice_in_dim(x, rank * chunk, chunk, axis=x.ndim - 1)
-
-
-def pvary_like(w, ref):
-    """Mark ``w`` varying over every mesh axis ``ref`` varies on (identity
-    value-wise; transpose = psum over those axes). Required before feeding a
-    replicated parameter together with sharded activations into a
-    ``custom_vjp`` op: the opaque vjp rule hides the linearity, so
-    shard_map's automatic invariant-input reduction cannot fire — this makes
-    the reduction explicit at the pvary transpose, over exactly the axes the
-    cotangent (which inherits the activations' vma) will carry."""
-    try:
-        want = set(jax.typeof(ref).vma)
-        have = set(jax.typeof(w).vma)
-    except (AttributeError, TypeError):
-        return w
-    missing = tuple(sorted(want - have))
-    if missing:
-        w = lax.pcast(w, missing, to="varying")
-    return w
 
 
 def copy_to_tensor_model_parallel_region(x, axis_name: str = TP_AXIS):

@@ -567,12 +567,6 @@ def test_regress_gates_analyzer_record():
 # flagship acceptance: the REAL paths, tier-1
 
 
-MESH_OK = hasattr(jax, "shard_map") and hasattr(jax.lax, "axis_size")
-needs_mesh = pytest.mark.skipif(
-    not MESH_OK,
-    reason="mesh programs need jax.shard_map/lax.axis_size (graft jax)")
-
-
 def test_flagship_gpt_train_step_donation_and_recompile():
     """Acceptance (stock-safe): a GPT train step over the flagship layer
     stack (the serve ``gpt_prefill`` forward, tp-optional — the same
@@ -610,9 +604,8 @@ def test_flagship_gpt_train_step_donation_and_recompile():
         assert g.growth() == {"train_step": 1}
 
 
-@needs_mesh
 def test_flagship_gpt_mesh_loss_step_donation_and_recompile():
-    """Acceptance (graft jax): the REAL flagship step — ``gpt_loss``
+    """Acceptance: the REAL flagship step — ``gpt_loss``
     under ``shard_map`` — donated params aliased, one compilation."""
     from jax.sharding import PartitionSpec as P
 

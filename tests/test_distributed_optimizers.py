@@ -177,11 +177,6 @@ def test_dist_adam_e5m2_allgather():
         assert np.any(a != b), "compression should actually round something"
 
 
-MESH_OK = hasattr(jax, "shard_map") and hasattr(jax.lax, "axis_size")
-
-
-@pytest.mark.skipif(not MESH_OK,
-                    reason="needs graft jax (jax.shard_map + lax.axis_size)")
 @pytest.mark.parametrize("cls_name", ["adam", "lamb"])
 def test_zero_fused_update_matches_unfused(cls_name):
     """fused_update='on' (the ops/fused_update.py Pallas tail) produces

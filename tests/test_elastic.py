@@ -65,10 +65,6 @@ from apex_tpu.resilience.reshard import (
 )
 from apex_tpu.resilience.supervisor import RESTART_NAME
 
-MESH_OK = hasattr(jax, "shard_map") and hasattr(jax.lax, "axis_size")
-mesh_only = pytest.mark.skipif(
-    not MESH_OK,
-    reason="mesh programs need jax.shard_map/lax.axis_size (graft jax)")
 
 DEGREES = (1, 2, 4, 8)
 _N, _MULT = 13, 2  # odd logical size + alignment: padding differs per dp
@@ -591,7 +587,6 @@ def test_grad_checksum_sums_inexact_leaves_only():
     assert float(grad_checksum({"i": jnp.int32(3)})) == 0.0
 
 
-@mesh_only
 def test_sdc_check_is_rank_uniform_under_shard_map():
     mesh = build_mesh(tp=1, pp=1, sp=1)  # dp=8
     sent = SDCSentinel()

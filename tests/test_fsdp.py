@@ -6,7 +6,7 @@ shapes / nonsense compositions at CONSTRUCTION; (2) the modeled
 DDP leg of the DDP+ZeRO-1 baseline at dp=2 on the GPT example — exactly
 2.0× — and ≥1.8× vs the ZeRO-1 leg from dp=4 up; the replicated-params
 term is what FSDP deletes, so the ZeRO-1 ratio grows with dp); (3)
-mesh-gated (graft-only, shard_map-shim-validated like PR 8's rows):
+on the 8-device virtual mesh:
 FSDP == DDP+FusedAdam loss-curve parity over ≥5 GPT steps at dp=2
 (measured BITWISE on the sim; asserted to 1e-5), the int8 weight-gather
 codec within codec tolerance, a mid-run checkpoint save/restore
@@ -42,11 +42,6 @@ from apex_tpu.fsdp import (
 )
 from apex_tpu.parallel import ParallelismPlan
 from apex_tpu.parallel.mesh import build_mesh
-
-MESH_OK = hasattr(jax, "shard_map") and hasattr(jax.lax, "axis_size")
-mesh_only = pytest.mark.skipif(
-    not MESH_OK,
-    reason="mesh programs need jax.shard_map/lax.axis_size (graft jax)")
 
 
 # ---------------------------------------------------------------------------
@@ -425,7 +420,6 @@ def _state_specs(params):
     return FSDPAdamState(count=P(), master=shard, mu=shard, nu=shard)
 
 
-@mesh_only
 @pytest.mark.parametrize("bidirectional", [False, True])
 def test_matmul_param_gather_matches_monolithic(bidirectional):
     """Forward BITWISE vs x @ all_gather(w) (the gathered dim is
@@ -541,7 +535,6 @@ def _ddp_gpt_losses(steps=6, lr=2e-3):
     return losses
 
 
-@mesh_only
 def test_fsdp_matches_ddp_loss_curve():
     """ACCEPTANCE: FSDP == DDP+FusedAdam over ≥5 GPT steps at dp=2.
     The shared Adam tail + exact gather/reduce-scatter make the curves
@@ -554,7 +547,6 @@ def test_fsdp_matches_ddp_loss_curve():
     np.testing.assert_allclose(fsdp, base, atol=1e-5)
 
 
-@mesh_only
 def test_fsdp_int8_weight_gather_within_codec_tolerance():
     """int8 param-gather wire: the curve tracks the exact one within
     codec tolerance (measured ~1e-3 max divergence; 0.02 is margin) —
@@ -567,7 +559,6 @@ def test_fsdp_int8_weight_gather_within_codec_tolerance():
         "the codec should actually round something"
 
 
-@mesh_only
 def test_fsdp_int8_grad_reduce_within_tolerance():
     base = _ddp_gpt_losses()
     int8 = _fsdp_gpt_losses(
@@ -575,7 +566,6 @@ def test_fsdp_int8_grad_reduce_within_tolerance():
     np.testing.assert_allclose(int8, base, atol=0.05)
 
 
-@mesh_only
 def test_fsdp_int4_weight_gather_within_codec_tolerance():
     """The sub-8-bit FSDP wire: nibble-packed int4 param gathers (half
     the int8 gather bytes again) keep the curve within the ±7-code
@@ -592,7 +582,6 @@ def test_fsdp_int4_weight_gather_within_codec_tolerance():
     assert int4[-1] < int4[0] - 0.4, int4  # training still progresses
 
 
-@mesh_only
 def test_fsdp_int4_grad_reduce_within_tolerance():
     base = _ddp_gpt_losses()
     int4 = _fsdp_gpt_losses(
@@ -602,7 +591,6 @@ def test_fsdp_int4_grad_reduce_within_tolerance():
     assert int4[-1] < int4[0] - 0.4, int4
 
 
-@mesh_only
 def test_fsdp_checkpoint_midrun_rejoins_exactly(tmp_path):
     """Mid-run save → zeroed state → restore: the continued curve is
     IDENTICAL to the uninterrupted run (shard-exact manifest path)."""
@@ -611,7 +599,6 @@ def test_fsdp_checkpoint_midrun_rejoins_exactly(tmp_path):
     np.testing.assert_array_equal(plain, rejoined)
 
 
-@mesh_only
 def test_fsdp_adam_matches_fused_adam_singleleaf():
     """The shard optimizer is FusedAdam given the same grads (the ZeRO-1
     parity contract, now for the stage-3 optimizer)."""
@@ -659,7 +646,6 @@ def test_fsdp_adam_matches_fused_adam_singleleaf():
                                    atol=1e-6, err_msg=k)
 
 
-@mesh_only
 def test_fsdp_step_records_metrics():
     from apex_tpu.monitor import Metrics
 
@@ -691,7 +677,6 @@ def test_fsdp_step_records_metrics():
     assert d["comm_wire_bytes"] > d["param_gather_bytes"]
 
 
-@mesh_only
 def test_flagship_tp_fsdp_gather_ring_proven_hidden():
     """ACCEPTANCE: the compiled tp/fsdp program's forward weight-gather
     rings are ≥0.5 hidden, proven from the HLO (the PR-4 flagship
@@ -733,7 +718,6 @@ def test_flagship_tp_fsdp_gather_ring_proven_hidden():
     assert rep.hidden_fraction >= 0.5, rep
 
 
-@mesh_only
 def test_plan_drives_fsdp_end_to_end():
     """The ParallelismPlan IS the wiring: preset('fsdp') -> mesh,
     engine, optimizer; one train step runs and shrinks the loss."""

@@ -93,13 +93,19 @@ def test_vocab_parallel_fused_matches_dense():
     np.testing.assert_allclose(dwf, dwd, rtol=1e-4, atol=1e-5)
 
 
-def test_dense_impl_matches_pallas_interpret_unsharded():
-    """The dense local impl and the kernel impl are interchangeable."""
+@pytest.mark.parametrize("t_max", [37, 48])
+def test_dense_impl_matches_pallas_interpret_unsharded(t_max):
+    """The dense local impl and the kernel impl are interchangeable —
+    also for targets that belong to ANOTHER vocab shard (``t_max`` 48: local
+    ids 37..47 land on the ragged last block's padded columns, whose masked
+    score must not be picked; on the chip at tp=2 this made the loss 1e28)."""
     n, v, h, bn, bv = 16, 37, 128, 8, 16
     ks = jax.random.split(jax.random.PRNGKey(4), 3)
     x2 = jax.random.normal(ks[0], (n, h), jnp.float32) * 0.5
     w = jax.random.normal(ks[1], (v, h), jnp.float32) * 0.1
-    t = jax.random.randint(ks[2], (n,), 0, v)
+    t = jax.random.randint(ks[2], (n,), 0, t_max)
+    if t_max > v:
+        t = t.at[:4].set(jnp.arange(v, v + 4))  # surely some out of shard
 
     def f(impl):
         def loss(x2, w):

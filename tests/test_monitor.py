@@ -3,9 +3,8 @@ wiring, JSONL schema round-trip + append-after-crash, span visibility in
 HLO/trace layer paths, and the compile-accounting gate (monitoring must add
 ZERO recompilations; DDP-reported bytes must agree with comm.accounting).
 
-Mesh-free tests are stock-jax/CPU-safe; mesh programs (shard_map + the GPT
-fixture) run on the graft jax toolchain and skip cleanly elsewhere; the
-profiler-trace tests are marked slow.
+Mesh programs (shard_map + the GPT fixture) run on the conftest's virtual
+mesh; the profiler-trace tests are marked slow.
 
 Treedef note exercised throughout: a Metrics carried THROUGH a step must be
 pre-seeded with every name the step records (names are treedef aux data, so
@@ -37,11 +36,6 @@ from apex_tpu.monitor import (
     span_function,
     train_metrics,
 )
-
-MESH_OK = hasattr(jax, "shard_map") and hasattr(jax.lax, "axis_size")
-needs_mesh = pytest.mark.skipif(
-    not MESH_OK,
-    reason="mesh programs need jax.shard_map/lax.axis_size (graft jax)")
 
 
 # compilation count of a jitted callable (None if this jax can't say) —
@@ -289,7 +283,8 @@ def test_span_names_visible_in_hlo_op_table():
         with span("opt"):
             return jnp.sum(h * h)
 
-    rows = op_table(f, jnp.ones((64, 32)), jnp.ones((32, 16)))
+    rows = op_table(f, jnp.ones((64, 32)), jnp.ones((32, 16)),
+                    peak_flops=1e12, hbm_bandwidth=1e11)
     # jax version differences add jit(...) wrapper components; the span
     # names must appear as path components either way
     comps = {c for r in rows for c in r["scope"].split("/")}
@@ -305,7 +300,8 @@ def test_span_function_decorator():
         return x @ w
 
     rows = op_table(lambda x, w: jnp.sum(layer(x, w)),
-                    jnp.ones((16, 8)), jnp.ones((8, 8)))
+                    jnp.ones((16, 8)), jnp.ones((8, 8)),
+                    peak_flops=1e12, hbm_bandwidth=1e11)
     assert any("layer0" in r["scope"].split("/") for r in rows)
 
 
@@ -512,7 +508,6 @@ def _gpt_bits():
     return cfg, gpt_loss, params, tok
 
 
-@needs_mesh
 @pytest.mark.parametrize("policy", ["none", "int8"])
 def test_ddp_reported_bytes_match_accounting(policy):
     """DDP's in-metrics per-bucket bytes must agree with what
@@ -557,7 +552,6 @@ def test_ddp_reported_bytes_match_accounting(policy):
         assert d["comm_compression_ratio"] == pytest.approx(1.0)
 
 
-@needs_mesh
 def test_instrumented_gpt_step_compiles_once_and_sinks_jsonl(tmp_path):
     """The acceptance criterion: 5 monitored GPT steps produce a JSONL
     where every record carries step/loss/grad-norm/loss-scale/overflow/
@@ -649,7 +643,6 @@ def test_instrumented_gpt_step_compiles_once_and_sinks_jsonl(tmp_path):
             assert r["comm_wire_bytes"] == pytest.approx(priced, rel=1e-3)
 
 
-@needs_mesh
 def test_zero_adam_metrics_shard_norms():
     from jax.sharding import PartitionSpec as P
 

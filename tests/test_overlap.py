@@ -22,21 +22,15 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-MESH_OK = hasattr(jax, "shard_map") and hasattr(jax.lax, "axis_size")
-pytestmark = pytest.mark.skipif(
-    not MESH_OK,
-    reason="mesh programs need jax.shard_map/lax.axis_size (graft jax)")
-
-if MESH_OK:
-    from apex_tpu.comm import (
-        CompressionConfig,
-        all_gather_matmul,
-        collective_report,
-        matmul_all_reduce,
-        matmul_reduce_scatter,
-    )
-    from apex_tpu.parallel import DistributedDataParallel
-    from apex_tpu.parallel.mesh import build_mesh
+from apex_tpu.comm import (
+    CompressionConfig,
+    all_gather_matmul,
+    collective_report,
+    matmul_all_reduce,
+    matmul_reduce_scatter,
+)
+from apex_tpu.parallel import DistributedDataParallel
+from apex_tpu.parallel.mesh import build_mesh
 
 B, S, H, N = 2, 64, 32, 48
 

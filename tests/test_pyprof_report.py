@@ -6,6 +6,10 @@ import jax.numpy as jnp
 
 from apex_tpu.pyprof import annotate, format_table, op_table
 
+# the test box is a CPU, which has no entry in the peaks table: the roofline
+# constants are the caller's (here the v5e figures, as plain numbers)
+PEAKS = dict(peak_flops=197e12, hbm_bandwidth=819e9)
+
 
 def _f(x, w1, w2):
     with annotate("layer1"):
@@ -18,7 +22,7 @@ def test_op_table_attributes_dots_to_scopes_with_exact_flops():
     x = jnp.ones((256, 512), jnp.bfloat16)
     w1 = jnp.ones((512, 512), jnp.bfloat16)
     w2 = jnp.ones((512, 128), jnp.bfloat16)
-    rows = op_table(_f, x, w1, w2)
+    rows = op_table(_f, x, w1, w2, **PEAKS)
     scopes = {r["scope"] for r in rows}
     assert any(s.startswith("layer1") for s in scopes)
     assert any(s.startswith("layer2") for s in scopes)
@@ -35,7 +39,7 @@ def test_op_table_attributes_dots_to_scopes_with_exact_flops():
 def test_format_table_renders():
     x = jnp.ones((64, 128), jnp.float32)
     w = jnp.ones((128, 128), jnp.float32)
-    rows = op_table(lambda x, w: jnp.sum(x @ w), x, w)
+    rows = op_table(lambda x, w: jnp.sum(x @ w), x, w, **PEAKS)
     text = format_table(rows, top=5)
     assert "GFLOP" in text and "TOTAL est" in text
 
@@ -53,7 +57,7 @@ def test_op_table_on_train_step_with_grad():
     w = {"a": jnp.ones((128, 256), jnp.float32),
          "b": jnp.ones((256, 64), jnp.float32)}
     x = jnp.ones((32, 128), jnp.float32)
-    rows = op_table(step, w, x)
+    rows = op_table(step, w, x, **PEAKS)
     assert sum(r["flops"] for r in rows) > 0
     # backward dots exist: total flops ~3x forward dot flops
     fwd = 2 * 32 * 128 * 256 + 2 * 32 * 256 * 64
@@ -73,7 +77,8 @@ def test_measured_op_table_joins_trace_and_hlo():
     x = jnp.ones((256, 256), jnp.float32)
     w1 = jnp.ones((256, 512), jnp.float32)
     w2 = jnp.ones((512, 256), jnp.float32)
-    res = measured_op_table(step, x, w1, w2, steps=3)
+    res = measured_op_table(step, x, w1, w2, steps=3,
+                            peak_flops=PEAKS["peak_flops"])
     rows = res["rows"]
     assert rows, "no measured rows joined"
     dot = [r for r in rows if r["op"] == "dot"]

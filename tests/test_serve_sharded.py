@@ -22,10 +22,8 @@ Gates, per residency strategy (``tp`` / ``pp`` / ``fsdp``):
   ``fsdp.accounting.hbm_serve_bytes`` prices each strategy under a chip
   budget.
 
-All mesh rows run under the 0.4.37 shard_map shim (``sharded.shard_map``
-dispatches graft ``jax.shard_map`` / stock ``jax.experimental``) on the
-conftest's 8 virtual devices — the same validation idiom as the PR-9/12
-mesh suites.
+All mesh rows run on the conftest's 8 virtual devices — the same
+validation idiom as the PR-9/12 mesh suites.
 """
 
 import dataclasses
@@ -57,7 +55,7 @@ MESH_OK = jax.device_count() >= 8
 mesh_only = pytest.mark.skipif(
     not MESH_OK,
     reason="plan-sharded engines need >= 8 devices (conftest forces 8 "
-           "virtual CPU devices; the shard_map shim covers stock 0.4.37)")
+           "virtual CPU devices)")
 
 CFG = GPTConfig(vocab_size=64, max_seq=64, hidden=32, num_layers=4,
                 num_heads=4, dtype=jnp.float32, fused_loss=False)

@@ -24,8 +24,6 @@ import pytest
 
 from apex_tpu.ops._pallas_util import force_compiled
 
-MESH_OK = hasattr(jax, "shard_map") and hasattr(jax.lax, "axis_size")
-
 
 def _lower_tpu(f, *args):
     return jax.jit(f).trace(*args).lower(lowering_platforms=("tpu",))
@@ -220,8 +218,6 @@ def _ring_loss(op_body, in_specs, x, w):
     return loss
 
 
-@pytest.mark.skipif(not MESH_OK,
-                    reason="mesh programs need jax.shard_map (graft jax)")
 def test_all_gather_matmul_ring_lowers_for_tpu():
     """AOT TPU lowering of the decomposed all-gather-matmul ring, fwd+bwd
     (the varlen lesson: what only ever EXECUTES on the CPU sim skips every
@@ -241,8 +237,6 @@ def test_all_gather_matmul_ring_lowers_for_tpu():
         _lower_tpu(jax.grad(loss, argnums=(0, 1)), x, w)
 
 
-@pytest.mark.skipif(not MESH_OK,
-                    reason="mesh programs need jax.shard_map (graft jax)")
 def test_matmul_reduce_scatter_ring_lowers_for_tpu():
     """AOT TPU lowering of the shifting-accumulator reduce-scatter ring
     (and its fused dx/dw backward ring), fwd+bwd."""
@@ -289,17 +283,6 @@ def test_lm_head_loss():
         _lower_tpu(jax.grad(loss, argnums=(0, 1)), x, w)
 
 
-_PALLAS_PARAMS_OK = False
-try:  # the kernel entry points need the graft-era Pallas compiler params
-    from jax.experimental.pallas import tpu as _pltpu
-
-    _PALLAS_PARAMS_OK = hasattr(_pltpu, "CompilerParams")
-except Exception:
-    pass
-
-
-@pytest.mark.skipif(not _PALLAS_PARAMS_OK,
-                    reason="pltpu.CompilerParams needs graft-era pallas")
 @pytest.mark.parametrize("quantized", [False, True])
 def test_paged_attention_kernel_lowers_for_tpu(quantized):
     """AOT TPU lowering of the serve gather-attend kernel: scalar-prefetch
@@ -324,8 +307,6 @@ def test_paged_attention_kernel_lowers_for_tpu(quantized):
         _lower_tpu(f, q, cl, bt, lens)
 
 
-@pytest.mark.skipif(not _PALLAS_PARAMS_OK,
-                    reason="pltpu.CompilerParams needs graft-era pallas")
 @pytest.mark.parametrize("quantized", [False, True])
 def test_fused_layer_decode_kernel_lowers_for_tpu(quantized):
     """AOT TPU lowering of the megakernel fused layer block: resident
@@ -400,8 +381,6 @@ def _mega_layer_fixture(quantized):
     return cfg, kv, lp, cl
 
 
-@pytest.mark.skipif(not _PALLAS_PARAMS_OK,
-                    reason="pltpu.CompilerParams needs graft-era pallas")
 @pytest.mark.parametrize("quantized", [False, True])
 def test_fused_layer_decode_tiled_kernel_lowers_for_tpu(quantized):
     """AOT TPU lowering of the WEIGHT-STREAMING fused layer: multi-tile
@@ -426,8 +405,6 @@ def test_fused_layer_decode_tiled_kernel_lowers_for_tpu(quantized):
         _lower_tpu(fn, x, lp, cl, bt, lens)
 
 
-@pytest.mark.skipif(not _PALLAS_PARAMS_OK,
-                    reason="pltpu.CompilerParams needs graft-era pallas")
 @pytest.mark.parametrize("quantized", [False, True])
 def test_fused_layer_verify_kernel_lowers_for_tpu(quantized):
     """AOT TPU lowering of the fused VERIFY layer (q_len = k+1 = 3 rows
@@ -449,8 +426,6 @@ def test_fused_layer_verify_kernel_lowers_for_tpu(quantized):
         _lower_tpu(fn, x, lp, cl, bt, start_ctx)
 
 
-@pytest.mark.skipif(not _PALLAS_PARAMS_OK,
-                    reason="pltpu.CompilerParams needs graft-era pallas")
 @pytest.mark.parametrize("with_norms", [False, True])
 def test_fused_update_tail_lowers_for_tpu(with_norms):
     """AOT TPU lowering of the fused Adam/LAMB update-tail kernel: the
@@ -469,3 +444,62 @@ def test_fused_update_tail_lowers_for_tpu(with_norms):
 
     with force_compiled():
         _lower_tpu(fn, g, c)
+
+
+# ---------------------------------------------------------------------------
+# Composed rows: the engine's own compiled programs at the GPT-2-124M serve
+# shape, beside benchmarks/preflight_lowering.py's train rows. Each is
+# lowered (the Pallas->Mosaic MLIR checks, with a minimum Mosaic-call count
+# so a dispatch site that fell back to the reference fails) and then
+# COMPILED for a v5e topology description — XLA:TPU plus Mosaic's own
+# compiler, where VMEM overflows and unsupported vector ops surface. The
+# serve path stopped lowering unseen once (PRs 8-20, jax 0.9.0); this is
+# what sees it.
+
+
+@pytest.fixture(scope="module")
+def flagship_serve():
+    """GPT-2-124M widths, two layers (the layer body is scanned: depth does
+    not change what lowers), zero weights."""
+    from apex_tpu.transformer.testing import GPTConfig, init_gpt_params
+
+    cfg = GPTConfig(vocab_size=50304, max_seq=1024, hidden=768,
+                    num_layers=2, num_heads=12, dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(
+        lambda: init_gpt_params(jax.random.PRNGKey(0), cfg))
+    return cfg, jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+
+
+@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+@pytest.mark.parametrize("megakernel,kernel", [("auto", "fused"),
+                                               ("off", "pallas")])
+def test_engine_programs_compile_for_tpu(flagship_serve, megakernel, kernel,
+                                         kv_quant):
+    from apex_tpu.ops._pallas_util import compile_for_tpu
+    from apex_tpu.serve import InferenceEngine, ServeConfig
+
+    cfg, params = flagship_serve
+    with force_compiled():  # "auto" resolves as it does on the chip
+        eng = InferenceEngine(params, cfg, ServeConfig(
+            megakernel=megakernel, kv_quant=kv_quant, spec_k=2))
+        assert eng.decode_kernel == kernel
+    n, mb = eng.serve_cfg.num_slots, eng._blocks_per_slot
+    ints = lambda *shape: jnp.zeros(shape, jnp.int32)
+    keys = jnp.zeros((n, 2), jnp.uint32)
+    active = jnp.zeros((n,), bool)
+    programs = {
+        # (args after params/cache, minimum Mosaic calls: the paged kernel
+        # or fused block in the layer scan; chunk_prefill also runs Pallas
+        # LayerNorm at its 32 rows)
+        "chunk_prefill": ((ints(eng.serve_cfg.prefill_chunk), jnp.int32(0),
+                           jnp.int32(1), ints(mb), keys[0]), 2),
+        "decode": ((ints(n), ints(n), active, ints(n, mb), keys), 1),
+        "verify": ((ints(n, 3), ints(n), ints(n), active, ints(n, mb),
+                    keys), 1),
+    }
+    for name, (args, min_calls) in programs.items():
+        lowered, compiled = compile_for_tpu(
+            eng.programs()[name], eng.params, eng.cache, *args)
+        calls = lowered.as_text().count("tpu_custom_call")
+        assert calls >= min_calls, (name, calls)
+        assert compiled is not None
