@@ -1,0 +1,24 @@
+"""The whole train step's share of the chip's bf16 peak: tokens of the steps
+that lie wholly inside the traced window, over the span from the first such
+step's start to the last one's end on the device's clock (idle time between
+steps counts; the profiler's own start and stop do not), x (6N + 6·L·h·s) over
+the peak. Recomputation is not credited."""
+import counts
+import xplane
+
+
+def read(facts, trace):
+    if facts.get("peaks") is None:     # no chip, no share of a peak
+        return None
+    if trace is None or facts.get("kind") != "train":
+        return None
+    shares = []
+    for dev in trace.devices.values():
+        runs = xplane.whole_runs(xplane.module_runs(dev, "jit_train_step"))
+        if not runs:
+            continue
+        span_s = (max(s + d for _, s, d in runs) - min(s for _, s, _ in runs)) / 1e9
+        tokens = len(runs) * (facts["rows"] // facts["chips"]) * facts["seq"]
+        flops = tokens * counts.train_flops_per_token(facts["model"], facts["seq"])
+        shares.append(flops / span_s / facts["peaks"].bf16_flops_per_s)
+    return 100.0 * sum(shares) / len(shares) if shares else None
