@@ -18,8 +18,11 @@ the step:
   (shard norms).
 * :mod:`~apex_tpu.monitor.trace` — :func:`span` named ranges
   (``jax.named_scope`` + host ``TraceAnnotation``: one marker, visible in
-  the trace viewer and as pyprof layer paths) and :func:`step_annotation`
-  step grouping. The pipeline schedules emit ``pp_stage`` /
+  the trace viewer and in the compiled HLO's metadata), :func:`split_scope`
+  (an instruction's ``op_name`` → phase and model scope) and the program
+  registry behind :func:`scope_table` (which scope each instruction of a
+  compiled program belongs to). Its docstring lists the scope and host
+  span names. The pipeline schedules emit ``pp_stage`` /
   ``pp_ring_shift`` spans for bubble attribution.
 * :mod:`~apex_tpu.monitor.sink` — :class:`JsonlSink`, the process-0-gated,
   versioned, buffered, crash-safe JSONL writer; :func:`json_record` is the
@@ -91,12 +94,7 @@ Tier 4 (performance forensics — why, who pays, and since when):
   rolled up under a declarative :class:`CostModel` with
   ``cost_per_token``/``cost_per_request`` surfaced in stats, per-worker
   cost rates advertised on the membership heartbeat, and loud
-  cardinality-bounded overflow accounting;
-* :mod:`~apex_tpu.monitor.trend` — append-only per-stage history of
-  banked watcher records (provenance-stamped via
-  :func:`sink.set_provenance`) with robust median+MAD / Theil–Sen
-  drift detection; ``python -m apex_tpu.monitor.trend check`` exits 1
-  on drift — the longitudinal gate next to the pairwise regress gate.
+  cardinality-bounded overflow accounting.
 """
 
 from apex_tpu.monitor.alerts import (  # noqa: F401
@@ -174,25 +172,22 @@ from apex_tpu.monitor.slo import (  # noqa: F401
 )
 from apex_tpu.monitor.trace import (  # noqa: F401
     PHASES,
+    register_program,
+    scope_table,
     span,
     span_function,
-    step_annotation,
+    split_scope,
 )
 
 
 def __getattr__(name):
-    # regress and trend double as `python -m apex_tpu.monitor.<mod>`;
-    # importing them eagerly here would make runpy warn about the
-    # pre-imported module every CLI run, so their package-level names
-    # resolve lazily
+    # regress doubles as `python -m apex_tpu.monitor.regress`; importing
+    # it eagerly here would make runpy warn about the pre-imported module
+    # every CLI run, so its package-level names resolve lazily
     if name in ("compare_records", "load_record"):
         from apex_tpu.monitor import regress
 
         return getattr(regress, name)
-    if name in ("append_history", "detect_trends", "load_history"):
-        from apex_tpu.monitor import trend
-
-        return getattr(trend, name)
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 __all__ = [
@@ -220,16 +215,13 @@ __all__ = [
     "SloSpec",
     "SloTracker",
     "accumulate_hist",
-    "append_history",
     "attribute_requests",
     "attribution_summary",
     "chrome_trace",
     "collect_provenance",
     "compare_records",
     "dedupe_events",
-    "detect_trends",
     "explain_regression",
-    "load_history",
     "merge_snapshots",
     "modeled_request_flops",
     "format_step_report",
@@ -245,13 +237,15 @@ __all__ = [
     "phase_breakdown",
     "pipeline_bubble_fraction",
     "read_jsonl",
+    "register_program",
     "request_spans",
     "rotated_segments",
+    "scope_table",
     "set_provenance",
     "span",
     "stitch_traces",
     "span_function",
-    "step_annotation",
+    "split_scope",
     "step_report",
     "train_metrics",
     "write_chrome_trace",

@@ -15,7 +15,7 @@ previously joined ad hoc by every consumer):
 
 :func:`step_report` runs a jittable step under the profiler and returns one
 flat dict (step time, MFU, wire bytes + modeled ICI bandwidth, per-phase
-time via :func:`phase_breakdown` over ``monitor.span`` names, trace
+time via :func:`phase_breakdown` over ``monitor.trace.split_scope``, trace
 coverage) ready for :func:`apex_tpu.monitor.sink.json_record`.
 :func:`hlo_stats` / :func:`mfu_check` are the compile-only (no-trace)
 subset for hosts that cannot run the profiler.
@@ -23,13 +23,13 @@ subset for hosts that cannot run the profiler.
 
 from __future__ import annotations
 
-import re
 from typing import Any, Callable, Dict, Optional
 
 import jax
 
 from apex_tpu.analyze.hlo import as_text
 from apex_tpu.comm.accounting import collective_report
+from apex_tpu.monitor.trace import split_scope
 
 
 def gpt_analytic_flops_per_token(n_params: int, num_layers: int,
@@ -88,39 +88,18 @@ def mfu_check(fn: Callable, *args: Any, analytic_flops: float,
     return out
 
 
-# AD/vectorization wrappers XLA's op paths accumulate around user scope
-# names; peeled so e.g. transpose(jvp(fwd)) rolls up to the fwd phase
-_WRAPPER_RE = re.compile(
-    r"^(?:jvp|transpose|vmap|pmap|remat|checkpoint|custom_jvp|custom_vjp)"
-    r"\((.*)\)$")
-
-
-def _phase_of(scope: str) -> str:
-    for part in scope.split("/"):
-        if not part or (part.startswith("jit(") and part.endswith(")")):
-            continue  # nested jit boundaries are plumbing, not phases
-        while True:
-            m = _WRAPPER_RE.match(part)
-            if not m:
-                break
-            part = m.group(1)
-        if part:
-            return part
-    return "<no-scope>"
-
-
 def phase_breakdown(measured: Dict[str, Any]) -> Dict[str, float]:
-    """ms/step per top-level span name, from a ``measured_op_table`` result.
-    Scope paths come from ``monitor.span`` / ``jax.named_scope``; the first
-    component that is a USER name is the phase (``fwd``/``bwd``/``comm``/
-    ``opt`` or any name), with ``jit(...)`` boundaries skipped and
-    ``jvp(...)``/``transpose(...)``-style AD wrappers peeled — a span
-    traced under ``jax.grad`` (the pipeline ``pp_stage`` spans, a span
-    inside the loss) still rolls its forward-replay AND transpose time up
-    to the span's own name. Unscoped ops land in ``<no-scope>``."""
+    """ms/step per phase, from a ``measured_op_table`` result. The phase is
+    :func:`apex_tpu.monitor.trace.split_scope`'s: ``fwd``, ``recompute``
+    (the forward replayed under ``jax.checkpoint``) or ``bwd`` where
+    differentiation wraps the operation, so the three passes of one scope
+    stay apart; else the first user scope (``opt``, ``comm``, ``pp_stage``
+    or any name). Unscoped ops land in ``<no-scope>``."""
     phases: Dict[str, float] = {}
     for r in measured["rows"]:
-        phase = _phase_of(r["scope"])
+        # a row's scope is a path cut to ``depth`` components, with no
+        # primitive at its end: give split_scope one to drop
+        phase = split_scope(r["scope"] + "/_")[0] or "<no-scope>"
         phases[phase] = phases.get(phase, 0.0) + r["time_ms"]
     return dict(sorted(phases.items(), key=lambda kv: -kv[1]))
 

@@ -6,52 +6,91 @@ paths — host-side markers a profiler joins with kernel launches.
 TPU design: one :func:`span` plants BOTH kinds of marker at once:
 
 * ``jax.named_scope`` — attaches the name to every op traced inside, so it
-  rides the compiled HLO's op metadata and shows up as the layer path in
-  ``apex_tpu.pyprof.op_table`` / ``measured_op_table`` (and the XLA trace
-  viewer's per-op details). This is the marker that survives jit.
-* ``jax.profiler.TraceAnnotation`` — a host-side range for eager/dispatch
-  work, so un-jitted phases (data loading, checkpoint writes) show in the
-  trace viewer's host rows too.
+  rides the compiled HLO's op metadata (``op_name``). It costs nothing at
+  run time and does not enter the compile-cache key. This is the marker
+  that survives jit.
+* ``jax.profiler.TraceAnnotation`` — a host-side range on the profiler's
+  clock, for eager and dispatch work. With no profiler session open it is
+  a disabled annotation.
 
-Canonical phase names are :data:`PHASES`
-(``fwd``/``bwd``/``comm``/``opt``/``ckpt`` — the last is the host-side
-checkpoint phase the resilience layer traces under) — using them makes
-``monitor.report.phase_breakdown`` attribute step time per phase with no
-configuration — but any string works.
+No flag, environment variable or config field switches either of them.
 
-:func:`step_annotation` wraps ``jax.profiler.StepTraceAnnotation`` so the
-trace viewer groups device activity by train step (the MLPerf-style
-step-time lane); use it host-side around each step call.
+**The operator's contract: the names.** Readers (``perfbench/scopes.py``
+and its metrics, ``PERF.md`` §3 and §5) match these letter for letter.
+
+Device scopes of the train step (``transformer/testing/standalone_gpt.py``,
+``bench.train_step_fn``), as :func:`split_scope` returns them::
+
+    embed                       token + position embedding (+ dropout)
+    layer                       the scanned layer body, and inside it
+    layer/ln1  layer/ln2        the two LayerNorms
+    layer/attn/qkv              QKV projection and the split into heads
+    layer/attn/core             the flash call and any layout change for it
+    layer/attn/out              merge of heads and output projection
+    layer/mlp/fc  layer/mlp/act  layer/mlp/proj
+    layer/residual              the two residual adds (and hidden dropout)
+    final_ln                    the LayerNorm after the stack
+    lm_head_loss                LM head + cross entropy, fused or not
+    opt                         FusedAdam's update and ``p + u``
+    scan_carry                  the scan's own slice of the stacked
+                                parameters and write of the stacked
+                                gradients, once a layer (no user scope)
+
+each under one phase: ``fwd``, ``recompute`` (the forward replayed under
+``jax.checkpoint``), ``bwd``, or the first user scope where no
+differentiation wraps the operation (``opt``, ``comm``, ...).
+
+Device scopes of the serving programs (``serve/decode.py``): ``embed``,
+``layer`` with ``ln1``, ``attn/qkv``, ``kv_write``, ``kv_read`` (the paged
+attention call), ``attn/out``, ``ln2``, ``mlp/fc``, ``mlp/act``,
+``mlp/proj``, ``residual``; then ``final_ln`` and ``lm_head``.
+
+Host spans (``TraceAnnotation``; the narrowest one that covers an idle gap
+names it): the engine's ``prefill`` (one chunk's dispatch, and the fence on
+a prompt's last chunk), ``prefill.admit`` (``_try_admit``), ``decode`` with
+``decode.dispatch`` (the call of the decode or verify program) and
+``decode.fence`` (``np.asarray(toks)``) inside it, and ``decode.retire``
+(per-slot bookkeeping to the end of ``step()``); ``comm``, ``fwd_bwd``,
+``pp_stage`` / ``pp_ring_shift``, ``ckpt``, ``transfer`` and ``scrape``
+elsewhere in the package.
+
+**Which scope a compiled instruction belongs to.** A program registers a
+thunk under the name its module has in a device trace
+(:func:`register_program`); a reader asks :func:`scope_table` for
+``{instruction name: {"op_name", "opcode", "moves_only"}}`` of the program
+compiled at given shapes. Nothing is lowered or compiled until a reader
+asks.
 """
 
 from __future__ import annotations
 
 import contextlib
 import functools
-from typing import Callable, Iterator, Optional
+import re
+from typing import Callable, Dict, Iterator, Optional, Tuple
 
 import jax
 
-# canonical train-step phases; monitor.report.phase_breakdown groups by the
-# leading scope component, so spans named from this set roll up cleanly.
-# "ckpt" is the host-side checkpoint phase (resilience.CheckpointManager's
-# device_get + serialization) — it appears in trace-viewer host rows, not
-# in the compiled step. "prefill"/"decode" are the serving phases the
-# apex_tpu.serve engine traces its two jitted programs under; "transfer"
-# is the disaggregated cluster's KV-block handoff between hosts
-# (serve.cluster — pack/ship/unpack around the SimTransport or ICI hop).
-# "scrape" is the fleet-observability tier's host-side phase: the
-# FleetScraper pulling worker snapshots on the cluster clock (its cost
-# is itself measured — scrape_ms — and gated by bench_observe.py).
-PHASES = ("fwd", "bwd", "comm", "opt", "ckpt", "prefill", "decode",
-          "transfer", "scrape")
+# canonical phases. "ckpt" is the host-side checkpoint phase
+# (resilience.CheckpointManager's device_get + serialization) — it appears
+# in trace-viewer host rows, not in the compiled step. "prefill"/"decode"
+# are the serving phases the apex_tpu.serve engine traces its two jitted
+# programs under; "transfer" is the disaggregated cluster's KV-block
+# handoff between hosts (serve.cluster — pack/ship/unpack around the
+# SimTransport or ICI hop). "scrape" is the fleet-observability tier's
+# host-side phase: the FleetScraper pulling worker snapshots on the
+# cluster clock (its cost is itself measured — scrape_ms — and gated by
+# bench_observe.py). "recompute" is the forward replayed in the backward
+# pass (split_scope tells it from "fwd" and "bwd").
+PHASES = ("fwd", "recompute", "bwd", "comm", "opt", "ckpt", "prefill",
+          "decode", "transfer", "scrape")
 
 
 @contextlib.contextmanager
 def span(name: str) -> Iterator[None]:
-    """Named range: in-graph (``named_scope`` → HLO op metadata → pyprof
-    layer paths) and host-side (``TraceAnnotation`` → trace-viewer host
-    row). Nesting composes into ``outer/inner`` scope paths."""
+    """Named range: in-graph (``named_scope`` → HLO op metadata → the scope
+    table) and host-side (``TraceAnnotation`` → trace-viewer host row).
+    Nesting composes into ``outer/inner`` scope paths."""
     with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
         yield
 
@@ -71,12 +110,94 @@ def span_function(fn: Callable = None, *, name: Optional[str] = None):
     return wrapped
 
 
-def step_annotation(step: int, name: str = "train_step"):
-    """Host-side step marker (``jax.profiler.StepTraceAnnotation``): device
-    activity dispatched inside is grouped under step ``step`` in the trace
-    viewer. Use around the step CALL (not inside the jitted body)::
+# -- from an instruction's op_name to (phase, scope) -------------------------
 
-        with monitor.step_annotation(i):
-            state = train_step(state, batch)
+_JIT = re.compile(r"\b(?:jit|pjit|xla_call)\([^()]*\)")
+_WRAPPER = re.compile(
+    r"\b(?:jvp|transpose|vmap|pmap|remat|checkpoint|custom_jvp|custom_vjp)\(")
+# path components JAX writes itself: control flow, calls and the transforms'
+# own sub-computations. What is left of a path is the user's.
+_PLUMBING = frozenset((
+    "while", "body", "cond", "body_fun", "cond_fun", "closed_call",
+    "core_call", "checkpoint", "rematted_computation", "remat", "remat2",
+    "shard_map", "pjit", "custom_vjp_call", "custom_vjp_call_jaxpr",
+    "custom_jvp_call", "custom_lin", "branch_0_fun", "branch_1_fun"))
+
+
+def split_scope(op_name: str) -> Tuple[str, str]:
+    """``(phase, scope)`` of a compiled instruction's ``op_name``.
+
+    ``phase`` is ``recompute`` if the path holds ``rematted_computation``,
+    else ``bwd`` if it holds ``transpose(``, else ``fwd`` if it holds
+    ``jvp(``, else the first user scope (``opt``, ``comm``, ...; ``""``
+    where there is none). ``scope`` is the user's part of the path
+    (``layer/attn/qkv``): without ``jit(...)``, the wrappers of
+    differentiation, JAX's own components (``while/body``,
+    ``closed_call``, ``checkpoint``, ...) and the primitive at the end. An
+    operation directly under ``while/body`` with no user scope is the
+    loop's own: the scan slicing its stacked inputs
+    (``dynamic_slice``, ``squeeze``), writing its stacked outputs
+    (``dynamic_update_slice``) and counting: ``scan_carry``.
     """
-    return jax.profiler.StepTraceAnnotation(name, step_num=step)
+    path = op_name.split(";", 1)[0]     # a fused instruction may list several
+    flat = _WRAPPER.sub("", _JIT.sub("", path)).replace(")", "")
+    parts = [p for p in flat.split("/") if p][:-1]    # less the primitive
+    user = []
+    for p in parts:
+        # the transposed body repeats the scope the scan's equation carried
+        if p not in _PLUMBING and (not user or user[-1] != p):
+            user.append(p)
+    scope = "/".join(user)
+    if not scope and parts[-2:] == ["while", "body"]:
+        scope = "scan_carry"
+    if "rematted_computation" in path:
+        phase = "recompute"
+    elif "transpose(" in path:
+        phase = "bwd"
+    elif "jvp(" in path:
+        phase = "fwd"
+    else:
+        phase = user[0] if user else ""
+    return phase, scope
+
+
+# -- which scope each compiled instruction belongs to -------------------------
+
+_PROGRAMS: Dict[str, Callable] = {}
+
+
+def register_program(name: str, lower: Callable) -> None:
+    """Register ``lower(**shape) -> jax.stages.Lowered`` under the name the
+    program's module has in a device trace (``jit_train_step``). The thunk
+    closes over the jitted function and what its shapes and shardings are
+    made from, and over no array; nothing is lowered here. A later
+    registration under the same name replaces the earlier one."""
+    _PROGRAMS[name] = lower
+
+
+def scope_table(name: str, **shape) -> Optional[Dict[str, Dict]]:
+    """``{instruction name: {"op_name", "opcode", "moves_only"}}`` of the
+    program registered as ``name``, compiled at ``shape`` (what its thunk
+    takes: ``rows`` and ``seq`` for the train step); ``None`` where no such
+    program is registered.
+
+    The persistent compile cache's key leaves metadata out, so a cache hit
+    may hand back an executable compiled from a tree with other scope names
+    (or none), and its text then shows *that* tree's metadata. This one
+    compile therefore runs with the metadata in the key. The instruction
+    names do not depend on metadata: a reader still proves, by name and
+    opcode, that the table is of the executable it traced.
+    """
+    from apex_tpu.pyprof.prof import instruction_scopes
+
+    lower = _PROGRAMS.get(name)
+    if lower is None:
+        return None
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    try:
+        compiled = lower(**shape).compile()
+    finally:
+        jax.config.update(flag, before)
+    return instruction_scopes(compiled.as_text())

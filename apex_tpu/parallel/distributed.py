@@ -48,7 +48,7 @@ from apex_tpu.comm.collectives import (
     fold_seed,
 )
 from apex_tpu.comm.error_feedback import init_error_feedback
-from apex_tpu.parallel.mesh import DP_AXIS
+from apex_tpu.parallel.mesh import DP_AXIS, vma_tracked
 
 
 def _flatten_buckets(leaves: List[jnp.ndarray], message_size: int):
@@ -161,7 +161,10 @@ class DistributedDataParallel:
         :meth:`average_gradients`. Differentiate w.r.t.
         ``ddp.replicate(params)`` and the gradients come back per-replica,
         exactly like the reference's per-process ``.grad`` buffers, ready for
-        the explicit allreduce."""
+        the explicit allreduce. Under ``check_vma=False`` nothing is
+        tracked and nothing auto-inserted, so the params pass through."""
+        if not vma_tracked(self.axis):
+            return params
         return jax.tree_util.tree_map(
             lambda p: lax.pcast(p, self.axis, to="varying"), params
         )

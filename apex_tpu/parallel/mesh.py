@@ -49,6 +49,14 @@ def axis_size(axis_name, mesh: Optional[Mesh] = None) -> int:
     return lax.axis_size(axis_name)
 
 
+def vma_tracked(axis_name: str) -> bool:
+    """False inside ``shard_map(check_vma=False)``: every value's ``vma`` is
+    then empty (``axis_index`` included, which always varies when tracked),
+    and a ``pcast`` there cannot be transposed (its transpose is a psum over
+    an axis the cotangent does not vary on)."""
+    return axis_name in jax.typeof(lax.axis_index(axis_name)).vma
+
+
 def build_mesh(
     tp: int = 1,
     pp: int = 1,

@@ -19,6 +19,9 @@ parts collapse to thin, robust wrappers:
 * :func:`report` / :func:`op_table` — per-op/per-layer attribution from the
   compiled HLO: every fused instruction with its ``named_scope`` layer path,
   FLOPs, bytes, and roofline time estimate (the ``parse``+``prof`` report).
+* :func:`instruction_scopes` — which scope path (``op_name``) each compiled
+  instruction carries, whether it only moves data, and its operands: what
+  ``monitor.trace.scope_table`` hands a reader of a device trace.
 * :func:`measured_report` / :func:`measured_op_table` — the MEASURED
   analogue: runs the step under ``jax.profiler``, parses the trace, and
   joins per-instruction measured time with the HLO flops/bytes (the
@@ -35,6 +38,7 @@ from apex_tpu.pyprof.profiler import (  # noqa: F401
 )
 from apex_tpu.pyprof.prof import (  # noqa: F401
     format_table,
+    instruction_scopes,
     op_table,
     report,
 )
@@ -48,4 +52,4 @@ from apex_tpu.pyprof.parse import (  # noqa: F401
 __all__ = ["annotate", "annotate_function", "trace", "cost_analysis",
            "summary", "op_table", "format_table", "report",
            "measured_op_table", "format_measured_table", "measured_report",
-           "load_trace_events"]
+           "load_trace_events", "instruction_scopes"]

@@ -39,6 +39,7 @@ from apex_tpu.pyprof.prof import (
     _dot_flops,
     _nbytes,
     _parse_hlo,
+    _scopes_of,
 )
 
 
@@ -173,18 +174,18 @@ def measured_op_table(
     # events per iteration. Container ops (while/call/conditional) are
     # excluded from rows: their spans COVER their bodies' spans and would
     # double-count the attributed total.
-    container_ops = {"while", "call", "conditional"}
+    containers = {n for n, rec in _scopes_of(comps).items()
+                  if rec["container"]}
     all_instrs = {i.name: i for instrs in comps.values() for i in instrs}
     instr_by_name = {
         n: i for n, i in all_instrs.items()
-        if i.op not in _SKIP_OPS and i.op not in container_ops
+        if i.op not in _SKIP_OPS and n not in containers
     }
     # container spans COVER their bodies' spans: drop them from the
     # denominator and the unattributed list, or coverage could never
     # approach 100% on loop-dominated (scan-over-layers) programs
-    for n, i in all_instrs.items():
-        if i.op in container_ops and n in dur_us:
-            total_us -= dur_us.pop(n)[0]
+    for n in containers & set(dur_us):
+        total_us -= dur_us.pop(n)[0]
 
     rows: List[Dict[str, Any]] = []
     matched_us = 0.0

@@ -31,10 +31,8 @@ _DTYPE_BYTES = {
     "c128": 16, "token": 0, "opaque": 0,
 }
 
-_INSTR_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*"
-    r"(?P<type>\([^)]*\)|\S+)\s+"  # tuple types contain spaces
-    r"(?P<op>[\w\-]+)\(")
+_INSTR_HEAD_RE = re.compile(r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*")
+_OPCODE_RE = re.compile(r"\s*(?P<op>[\w\-]+)\(")
 _SHAPE_RE = re.compile(r"(?P<dt>\w+)\[(?P<dims>[\d,]*)\]")
 _META_RE = re.compile(r'metadata=\{[^}]*op_name="(?P<op_name>[^"]*)"')
 _CALLS_RE = re.compile(r"(?:calls|to_apply)=%?(?P<callee>[\w.\-]+)")
@@ -68,13 +66,55 @@ class _Instr:
     operands: List[str] = field(default_factory=list)
 
 
+def _type_end(line: str, start: int) -> int:
+    """Index just past the result type that starts at ``start``. A tuple
+    type is matched by depth: a TPU layout carries parentheses of its own
+    (``{1,0:T(8,128)(2,1)}``), inside tuples too."""
+    if line[start] != "(":
+        end = start
+        while end < len(line) and not line[end].isspace():
+            end += 1
+        return end
+    depth = 0
+    for i in range(start, len(line)):
+        if line[i] == "(":
+            depth += 1
+        elif line[i] == ")":
+            depth -= 1
+            if depth == 0:
+                return i + 1
+    return len(line)
+
+
+def _parse_instr(line: str) -> Optional[_Instr]:
+    head = _INSTR_HEAD_RE.match(line)
+    if not head or head.end() >= len(line):
+        return None
+    t_end = _type_end(line, head.end())
+    op = _OPCODE_RE.match(line, t_end)
+    if not op:
+        return None
+    ins = _Instr(head.group("name"), op.group("op"),
+                 line[head.end():t_end], line)
+    meta = _META_RE.search(line)
+    if meta:
+        ins.op_name = meta.group("op_name")
+    calls = _CALLS_RE.search(line)
+    if calls:
+        ins.callee = calls.group("callee")
+    # operand names: %foo references inside the opcode's parentheses
+    close = _type_end(line, op.end() - 1)
+    ins.operands = re.findall(r"%([\w.\-]+)", line[op.end():close])
+    return ins
+
+
 def _parse_hlo(hlo_text: str) -> Tuple[Dict[str, List[_Instr]], str]:
     """-> ({computation_name: [instrs]}, entry_computation_name)."""
     comps: Dict[str, List[_Instr]] = {}
     entry = ""
     cur: Optional[str] = None
     for line in hlo_text.splitlines():
-        header = re.match(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\([^)]*\)\s*->", line)
+        header = re.match(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->", line)
         if header and not line.lstrip().startswith("ROOT"):
             cur = header.group(2)
             comps[cur] = []
@@ -83,21 +123,81 @@ def _parse_hlo(hlo_text: str) -> Tuple[Dict[str, List[_Instr]], str]:
             continue
         if cur is None:
             continue
-        m = _INSTR_RE.match(line)
-        if not m:
-            continue
-        ins = _Instr(m.group("name"), m.group("op"), m.group("type"), line)
-        meta = _META_RE.search(line)
-        if meta:
-            ins.op_name = meta.group("op_name")
-        calls = _CALLS_RE.search(line)
-        if calls:
-            ins.callee = calls.group("callee")
-        # operand names: %foo references after the opcode's '('
-        rest = line[m.end():]
-        ins.operands = re.findall(r"%([\w.\-]+)", rest)
-        comps[cur].append(ins)
+        ins = _parse_instr(line)
+        if ins is not None:
+            comps[cur].append(ins)
     return comps, entry
+
+
+# -- which scope each instruction belongs to ----------------------------------
+
+_MOVE_OPS = frozenset((
+    "copy", "transpose", "reshape", "bitcast", "slice", "dynamic-slice",
+    "dynamic-update-slice", "concatenate", "pad", "broadcast"))
+_ASYNC_HALF_RE = re.compile(r"-(?:start|done)$")    # copy-start, slice-done
+_MOVE_FILLERS = frozenset((
+    "parameter", "tuple", "get-tuple-element", "constant"))
+_CONTAINER_OPS = frozenset(("while", "call", "conditional"))
+_SCALAR_INDEX_RE = re.compile(r"^(?:s|u)(?:8|16|32|64)\[\]|^pred\[\]")
+
+
+def _moves_only(ins: _Instr, comps: Dict[str, List[_Instr]],
+                seen: Optional[set] = None) -> bool:
+    """True where the instruction moves data and computes nothing: one of
+    the layout and slicing opcodes, or a fusion whose fused computation
+    holds nothing but those (and parameters, tuples, constants and index
+    arithmetic on scalars)."""
+    if _ASYNC_HALF_RE.sub("", ins.op) in _MOVE_OPS:
+        return True
+    if ins.op != "fusion" or ins.callee not in comps:
+        return False
+    seen = seen if seen is not None else set()
+    if ins.callee in seen:
+        return False
+    seen.add(ins.callee)
+    moves = False
+    for inner in comps[ins.callee]:
+        if inner.op in _MOVE_FILLERS or _SCALAR_INDEX_RE.match(inner.type_str):
+            continue
+        if not _moves_only(inner, comps, seen):
+            return False
+        moves = True
+    return moves
+
+
+def instruction_scopes(hlo_text: str) -> Dict[str, Dict[str, Any]]:
+    """``{instruction name: {"op_name", "opcode", "moves_only", "container",
+    "operands"}}`` of a compiled module's text, for every instruction of
+    every computation that is not itself fused into another (instruction
+    names are unique in a module; a device trace names its events by them).
+
+    ``op_name`` is the instruction's ``metadata`` path, where the scopes a
+    program planted live (``monitor.trace.split_scope`` reads it).
+    ``moves_only`` marks data movement (see :func:`_moves_only`).
+    ``container`` marks ``while`` / ``call`` / ``conditional``, whose events
+    span their bodies' events: a reader leaves them out or takes self
+    time. ``operands`` lets a reader lend an unnamed instruction its
+    neighbour's scope.
+    """
+    return _scopes_of(_parse_hlo(hlo_text)[0])
+
+
+def _scopes_of(comps: Dict[str, List[_Instr]]) -> Dict[str, Dict[str, Any]]:
+    fused = {i.callee for instrs in comps.values() for i in instrs
+             if i.op == "fusion" and i.callee}
+    table: Dict[str, Dict[str, Any]] = {}
+    for comp, instrs in comps.items():
+        if comp in fused:
+            continue
+        for ins in instrs:
+            table[ins.name] = {
+                "op_name": ins.op_name,
+                "opcode": ins.op,
+                "moves_only": _moves_only(ins, comps),
+                "container": ins.op in _CONTAINER_OPS,
+                "operands": ins.operands,
+            }
+    return table
 
 
 def _dot_flops(ins: _Instr, shapes: Dict[str, str]) -> float:

@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from apex_tpu.monitor.trace import span
 from apex_tpu.ops._pallas_util import compiled_backend as _compiled_backend
 from apex_tpu.ops._pallas_util import sds as _sds
 from apex_tpu.ops.attention import NEG_INF, attention_reference, flash_attention
@@ -369,16 +370,19 @@ def serve_logits(params, x, cfg, tp_axis: Optional[str] = None):
     global argmax/top-k, so TP-sharded logits are all-gathered here —
     unlike training, where the fused loss never materializes them)."""
     head = params["head"]
-    x = layer_norm(x, head["ln_w"], head["ln_b"], use_pallas=cfg.ln_pallas)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("...h,vh->...v", x,
-                            params["embed"]["tok"].astype(x.dtype))
-    else:
-        logits = jnp.dot(x, head["lm"].astype(x.dtype))
-    if tp_axis is not None:
-        logits = lax.all_gather(logits, tp_axis, axis=logits.ndim - 1,
-                                tiled=True)
-    return logits.astype(jnp.float32)
+    with span("final_ln"):
+        x = layer_norm(x, head["ln_w"], head["ln_b"],
+                       use_pallas=cfg.ln_pallas)
+    with span("lm_head"):
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("...h,vh->...v", x,
+                                params["embed"]["tok"].astype(x.dtype))
+        else:
+            logits = jnp.dot(x, head["lm"].astype(x.dtype))
+        if tp_axis is not None:
+            logits = lax.all_gather(logits, tp_axis, axis=logits.ndim - 1,
+                                    tiled=True)
+        return logits.astype(jnp.float32)
 
 
 def _split_qkv(qkv, heads_local: int, head_dim: int):
@@ -560,39 +564,55 @@ def paged_layer_stack(x, layers, start_lens, n_valid, active, cache,
             lp, cl, ad = xs
         if gather_layer is not None:
             lp = gather_layer(lp)
-        h1 = layer_norm(x, lp["ln1_w"], lp["ln1_b"],
-                        use_pallas=cfg.ln_pallas)
-        qkv = _col(h1, lp["qkv_kernel"], lp["qkv_bias"], tp_axis)
-        if ad is not None:
-            qkv = qkv + lora_delta(h1, ad["qkv_a"], ad["qkv_b"],
+        with span("layer"):
+            return layer(x, lp, cl, ad)
+
+    def layer(x, lp, cl, ad):
+        with span("ln1"):
+            h1 = layer_norm(x, lp["ln1_w"], lp["ln1_b"],
+                            use_pallas=cfg.ln_pallas)
+        with span("attn/qkv"):
+            qkv = _col(h1, lp["qkv_kernel"], lp["qkv_bias"], tp_axis)
+            if ad is not None:
+                qkv = qkv + lora_delta(h1, ad["qkv_a"], ad["qkv_b"],
+                                       adapter_ids)
+            qh, k, v = _split_qkv(qkv, heads_local, cfg.head_dim)  # (n,q,H,D)
+        with span("kv_write"):
+            k_flat = k.reshape(n * q, heads_local, cfg.head_dim)
+            v_flat = v.reshape(n * q, heads_local, cfg.head_dim)
+            cl = paged_write(cl, kv_cfg, k_flat.transpose(1, 0, 2),
+                             v_flat.transpose(1, 0, 2), bt_rows, pos_flat,
+                             valid_flat)
+        with span("kv_read"):
+            ctx = paged_attention(
+                qh.reshape(n * q, heads_local, cfg.head_dim), cl, kv_cfg,
+                bt_rows, ctx_lens.reshape(-1), use_pallas=use_pallas)
+        with span("attn/out"):
+            ctx = ctx.reshape(n, q, heads_local * cfg.head_dim)
+            a = _row(ctx, lp["out_kernel"], lp["out_bias"], tp_axis,
+                     overlap=overlap)
+            if ad is not None:
+                a = a + lora_delta(ctx, ad["out_a"], ad["out_b"],
                                    adapter_ids)
-        qh, k, v = _split_qkv(qkv, heads_local, cfg.head_dim)  # (n,q,H,D)
-        k_flat = k.reshape(n * q, heads_local, cfg.head_dim)
-        v_flat = v.reshape(n * q, heads_local, cfg.head_dim)
-        cl = paged_write(cl, kv_cfg, k_flat.transpose(1, 0, 2),
-                         v_flat.transpose(1, 0, 2), bt_rows, pos_flat,
-                         valid_flat)
-        ctx = paged_attention(qh.reshape(n * q, heads_local, cfg.head_dim),
-                              cl, kv_cfg, bt_rows,
-                              ctx_lens.reshape(-1), use_pallas=use_pallas)
-        ctx = ctx.reshape(n, q, heads_local * cfg.head_dim)
-        a = _row(ctx, lp["out_kernel"], lp["out_bias"], tp_axis,
-                 overlap=overlap)
-        if ad is not None:
-            a = a + lora_delta(ctx, ad["out_a"], ad["out_b"], adapter_ids)
-        x = x + a
-        h2 = layer_norm(x, lp["ln2_w"], lp["ln2_b"],
-                        use_pallas=cfg.ln_pallas)
-        pre = _col(h2, lp["fc1_kernel"], lp["fc1_bias"], tp_axis)
-        if ad is not None:
-            pre = pre + lora_delta(h2, ad["fc1_a"], ad["fc1_b"],
-                                   adapter_ids)
-        y = jax.nn.gelu(pre, approximate=True)
-        m = _row(y, lp["fc2_kernel"], lp["fc2_bias"], tp_axis,
-                 overlap=overlap)
-        if ad is not None:
-            m = m + lora_delta(y, ad["fc2_a"], ad["fc2_b"], adapter_ids)
-        x = x + m
+        with span("residual"):
+            x = x + a
+        with span("ln2"):
+            h2 = layer_norm(x, lp["ln2_w"], lp["ln2_b"],
+                            use_pallas=cfg.ln_pallas)
+        with span("mlp/fc"):
+            pre = _col(h2, lp["fc1_kernel"], lp["fc1_bias"], tp_axis)
+            if ad is not None:
+                pre = pre + lora_delta(h2, ad["fc1_a"], ad["fc1_b"],
+                                       adapter_ids)
+        with span("mlp/act"):
+            y = jax.nn.gelu(pre, approximate=True)
+        with span("mlp/proj"):
+            m = _row(y, lp["fc2_kernel"], lp["fc2_bias"], tp_axis,
+                     overlap=overlap)
+            if ad is not None:
+                m = m + lora_delta(y, ad["fc2_a"], ad["fc2_b"], adapter_ids)
+        with span("residual"):
+            x = x + m
         return x, cl
 
     # the adapter pool rides the scan as read-only xs (sliced per layer,
@@ -638,7 +658,8 @@ def gpt_paged_forward(params, tokens, start_lens, n_valid, active, cache,
     offs = jnp.arange(q)
     positions = start_lens[:, None] + offs[None, :]            # (n, q)
     positions_c = jnp.minimum(positions, cfg.max_seq - 1)
-    x = _embed(params["embed"], tokens, positions_c, tp_axis)  # (n, q, h)
+    with span("embed"):
+        x = _embed(params["embed"], tokens, positions_c, tp_axis)  # (n, q, h)
     x, cache = paged_layer_stack(
         x, params["layers"], start_lens, n_valid, active, cache,
         block_tables, cfg, kv_cfg, tp_axis=tp_axis, use_pallas=use_pallas,

@@ -83,6 +83,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import time
 import zlib
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -97,7 +98,7 @@ from apex_tpu.monitor.hist import DEFAULT_LATENCY_SPEC, HistSpec, Histogram
 from apex_tpu.monitor.meter import Meter, modeled_request_flops
 from apex_tpu.monitor.metrics import Metrics
 from apex_tpu.monitor.slo import SloSpec, SloTracker
-from apex_tpu.monitor.trace import span
+from apex_tpu.monitor.trace import register_program, span
 from apex_tpu.serve.adapters import (
     AdapterRegistry,
     adapter_pool_bytes,
@@ -504,6 +505,43 @@ class InferenceEngine:
         self._use_pallas = use_pallas
         self._megakernel = self._resolve_megakernel()
         self._build_programs(wrap)
+        self._register_programs()
+
+    def _register_programs(self) -> None:
+        """Hand ``monitor.trace.scope_table`` the decode and chunk-prefill
+        programs under the module names a device trace gives them
+        (``jit_decode``, ``jit_chunk_prefill``), as thunks over shapes:
+        nothing is lowered here, and the thunks hold no array (the
+        engine's shapes are fixed, so they take no argument). An engine
+        whose programs are not single jitted functions registers none."""
+        def shape_of(a):
+            return jax.ShapeDtypeStruct(
+                a.shape, jax.dtypes.canonicalize_dtype(a.dtype),
+                sharding=getattr(a, "sharding", None))
+
+        state = jax.tree.map(shape_of, (self.params, self.cache) + (
+            () if self._lora_pool is None else (self._lora_pool,)))
+        mirrors = {nm: shape_of(getattr(self, "_" + nm))
+                   for nm in _MIRROR_NAMES}
+        lora = self._lora_pool is not None
+        i32 = jax.ShapeDtypeStruct((), jnp.int32)
+        chunk = jax.ShapeDtypeStruct((self.serve_cfg.prefill_chunk,),
+                                     jnp.int32)
+
+        def row(nm):
+            return jax.ShapeDtypeStruct(mirrors[nm].shape[1:],
+                                        mirrors[nm].dtype)
+
+        decode_args = state + tuple(mirrors[nm] for nm in (
+            "last_tokens", "seq_lens", "active", "block_tables", "keys")) + (
+            (mirrors["adapter_ids"],) if lora else ())
+        prefill_args = state + (chunk, i32, i32, row("block_tables"),
+                                row("keys")) + ((i32,) if lora else ())
+        for fn, args in ((self._decode, decode_args),
+                         (self._chunk_prefill, prefill_args)):
+            if hasattr(fn, "lower"):    # a staged engine's are host loops
+                register_program("jit_" + fn.__name__,
+                                 functools.partial(fn.lower, *args))
 
     def _resolve_megakernel(self) -> bool:
         """ServeConfig.megakernel -> whether the decode AND verify
@@ -1347,7 +1385,8 @@ class InferenceEngine:
         counts as progress: the queue moved, even though no slot did —
         otherwise ``run()`` would misread the step as a pool stall."""
         shed0 = self._rejected
-        admitted = self._try_admit()
+        with span("prefill.admit"):
+            admitted = self._try_admit()
         chunked = self._run_prefill_chunk()
         if not self._active.any():
             if self._sink is not None and chunked:
@@ -1361,107 +1400,110 @@ class InferenceEngine:
         t0 = time.perf_counter()
         drafts = self._collect_drafts()
         with span("decode"):
-            if drafts is None:
-                self._decode_steps += 1
-                if self._lora_pool is None:
-                    self.cache, toks, metrics = self._decode(
-                        self.params, self.cache,
-                        self._dev("last_tokens"), self._dev("seq_lens"),
-                        self._dev("active"), self._dev("block_tables"),
-                        self._dev("keys"))
+            with span("decode.dispatch"):
+                if drafts is None:
+                    self._decode_steps += 1
+                    if self._lora_pool is None:
+                        self.cache, toks, metrics = self._decode(
+                            self.params, self.cache,
+                            self._dev("last_tokens"), self._dev("seq_lens"),
+                            self._dev("active"), self._dev("block_tables"),
+                            self._dev("keys"))
+                    else:
+                        (self.cache, self._lora_pool, toks,
+                         metrics) = self._decode(
+                            self.params, self.cache, self._lora_pool,
+                            self._dev("last_tokens"), self._dev("seq_lens"),
+                            self._dev("active"), self._dev("block_tables"),
+                            self._dev("keys"), self._dev("adapter_ids"))
                 else:
-                    (self.cache, self._lora_pool, toks,
-                     metrics) = self._decode(
-                        self.params, self.cache, self._lora_pool,
-                        self._dev("last_tokens"), self._dev("seq_lens"),
-                        self._dev("active"), self._dev("block_tables"),
-                        self._dev("keys"), self._dev("adapter_ids"))
-            else:
-                self._verify_steps += 1
-                k1 = self.serve_cfg.spec_k + 1
-                n = self.serve_cfg.num_slots
-                fed = np.zeros((n, k1), np.int32)
-                fed[:, 0] = self._last_tokens
-                n_fed = np.where(self._active, 1, 0).astype(np.int32)
-                for i, d in drafts.items():
-                    fed[i, 1:1 + len(d)] = d
-                    n_fed[i] = 1 + len(d)
-                if self._lora_pool is None:
-                    self.cache, toks, metrics = self._verify(
-                        self.params, self.cache, jnp.asarray(fed),
-                        self._dev("seq_lens"), jnp.asarray(n_fed),
-                        self._dev("active"), self._dev("block_tables"),
-                        self._dev("keys"))
-                else:
-                    (self.cache, self._lora_pool, toks,
-                     metrics) = self._verify(
-                        self.params, self.cache, self._lora_pool,
-                        jnp.asarray(fed), self._dev("seq_lens"),
-                        jnp.asarray(n_fed), self._dev("active"),
-                        self._dev("block_tables"), self._dev("keys"),
-                        self._dev("adapter_ids"))
-            toks = np.asarray(toks)  # fence — the iteration-level sync
+                    self._verify_steps += 1
+                    k1 = self.serve_cfg.spec_k + 1
+                    n = self.serve_cfg.num_slots
+                    fed = np.zeros((n, k1), np.int32)
+                    fed[:, 0] = self._last_tokens
+                    n_fed = np.where(self._active, 1, 0).astype(np.int32)
+                    for i, d in drafts.items():
+                        fed[i, 1:1 + len(d)] = d
+                        n_fed[i] = 1 + len(d)
+                    if self._lora_pool is None:
+                        self.cache, toks, metrics = self._verify(
+                            self.params, self.cache, jnp.asarray(fed),
+                            self._dev("seq_lens"), jnp.asarray(n_fed),
+                            self._dev("active"), self._dev("block_tables"),
+                            self._dev("keys"))
+                    else:
+                        (self.cache, self._lora_pool, toks,
+                         metrics) = self._verify(
+                            self.params, self.cache, self._lora_pool,
+                            jnp.asarray(fed), self._dev("seq_lens"),
+                            jnp.asarray(n_fed), self._dev("active"),
+                            self._dev("block_tables"), self._dev("keys"),
+                            self._dev("adapter_ids"))
+            with span("decode.fence"):
+                toks = np.asarray(toks)  # the iteration-level sync
         dt = time.perf_counter() - t0
-        self.hists["decode_step_ms"].add([dt * 1e3])
-        if drafts is not None:
-            # the verify A/B's own latency dimension — spec steps also
-            # land in decode_step_ms (one engine iteration either way)
-            self.hists["verify_step_ms"].add([dt * 1e3])
-        now_ms = self._now_ms()
-        active_lens = [int(s) + 1 for s, a
-                       in zip(self._seq_lens, self._active) if a]
-        # tokens FED through the program per active slot (the write/flops
-        # unit: a verify step feeds 1 + len(drafts) per slot)
-        fed_counts = [1 + len(drafts.get(i, [])) if drafts is not None
-                      else 1
-                      for i in range(len(self._slots)) if self._active[i]]
-        n_active = len(active_lens)
-        step_proposed = step_accepted = step_emitted = 0
-        for i in range(len(self._slots)):
-            if not self._active[i]:
-                continue
-            state = self._slots[i]
-            if drafts is None:
-                emitted = [int(toks[i])]
-            else:
-                d = drafts.get(i, [])
-                step_proposed += len(d)
-                a = 1
-                while a <= len(d) and int(toks[i, a - 1]) == d[a - 1]:
-                    a += 1
-                emitted = [int(toks[i, j]) for j in range(a)]
-                step_accepted += a - 1
-            retired = False
-            n_emit = 0
-            for tok in emitted:
-                state.generated.append(tok)
-                state.history.append(tok)
-                self._tokens_generated += 1
-                n_emit += 1
-                if self._should_retire(state, tok):
-                    retired = True
-                    break
-            step_emitted += n_emit
-            self._seq_lens[i] += n_emit
-            self._last_tokens[i] = state.generated[-1]
-            if (self._events is not None and not retired
-                    and len(state.generated) - state.chunk_done
-                    >= self._chunk_tokens):
-                self._events.emit(
-                    "decode_chunk", state.request.uid, t_ms=now_ms,
-                    slot=i, start_ms=round(state.chunk_start_ms, 3),
-                    n_tokens=len(state.generated) - state.chunk_done)
-                state.chunk_start_ms = now_ms
-                state.chunk_done = len(state.generated)
-            if retired:
-                self._retire(i)
-        self._dirty("seq_lens", "last_tokens")
-        self._spec_proposed += step_proposed
-        self._spec_accepted += step_accepted
-        self._step_idx += 1
-        self._emit_metrics(metrics, dt, n_active, active_lens, fed_counts,
-                           step_proposed, step_accepted, step_emitted)
-        return True
+        with span("decode.retire"):
+            self.hists["decode_step_ms"].add([dt * 1e3])
+            if drafts is not None:
+                # the verify A/B's own latency dimension — spec steps also
+                # land in decode_step_ms (one engine iteration either way)
+                self.hists["verify_step_ms"].add([dt * 1e3])
+            now_ms = self._now_ms()
+            active_lens = [int(s) + 1 for s, a
+                           in zip(self._seq_lens, self._active) if a]
+            # tokens FED through the program per active slot (the write/flops
+            # unit: a verify step feeds 1 + len(drafts) per slot)
+            fed_counts = [1 + len(drafts.get(i, [])) if drafts is not None
+                          else 1
+                          for i in range(len(self._slots)) if self._active[i]]
+            n_active = len(active_lens)
+            step_proposed = step_accepted = step_emitted = 0
+            for i in range(len(self._slots)):
+                if not self._active[i]:
+                    continue
+                state = self._slots[i]
+                if drafts is None:
+                    emitted = [int(toks[i])]
+                else:
+                    d = drafts.get(i, [])
+                    step_proposed += len(d)
+                    a = 1
+                    while a <= len(d) and int(toks[i, a - 1]) == d[a - 1]:
+                        a += 1
+                    emitted = [int(toks[i, j]) for j in range(a)]
+                    step_accepted += a - 1
+                retired = False
+                n_emit = 0
+                for tok in emitted:
+                    state.generated.append(tok)
+                    state.history.append(tok)
+                    self._tokens_generated += 1
+                    n_emit += 1
+                    if self._should_retire(state, tok):
+                        retired = True
+                        break
+                step_emitted += n_emit
+                self._seq_lens[i] += n_emit
+                self._last_tokens[i] = state.generated[-1]
+                if (self._events is not None and not retired
+                        and len(state.generated) - state.chunk_done
+                        >= self._chunk_tokens):
+                    self._events.emit(
+                        "decode_chunk", state.request.uid, t_ms=now_ms,
+                        slot=i, start_ms=round(state.chunk_start_ms, 3),
+                        n_tokens=len(state.generated) - state.chunk_done)
+                    state.chunk_start_ms = now_ms
+                    state.chunk_done = len(state.generated)
+                if retired:
+                    self._retire(i)
+            self._dirty("seq_lens", "last_tokens")
+            self._spec_proposed += step_proposed
+            self._spec_accepted += step_accepted
+            self._step_idx += 1
+            self._emit_metrics(metrics, dt, n_active, active_lens, fed_counts,
+                               step_proposed, step_accepted, step_emitted)
+            return True
 
     def _emit_metrics(self, metrics: Metrics, dt: float, n_active: int,
                       active_lens: List[int], fed_counts: List[int],

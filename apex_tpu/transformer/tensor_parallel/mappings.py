@@ -48,14 +48,14 @@ from jax import lax
 from apex_tpu.ops._pallas_util import pvary_like  # noqa: F401 (re-export)
 from apex_tpu.parallel.mesh import TP_AXIS
 from apex_tpu.parallel.mesh import axis_size as _axis_size
+from apex_tpu.parallel.mesh import vma_tracked
 from apex_tpu.transformer.tensor_parallel.utils import divide
 
 
 def _is_varying(x, axis_name: str) -> bool:
-    try:
-        return axis_name in jax.typeof(x).vma
-    except (AttributeError, TypeError):
+    if not vma_tracked(axis_name):
         return True  # no vma tracking (check_vma=False) — treat as varying
+    return axis_name in jax.typeof(x).vma
 
 
 def _pvary(x, axis_name: str):

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from apex_tpu.monitor.trace import span, span_function
 from apex_tpu.ops.attention import flash_attention
 from apex_tpu.ops.layer_norm import layer_norm
 from apex_tpu.parallel.mesh import SP_AXIS, TP_AXIS
@@ -330,18 +331,38 @@ def _attention(p, x, cfg, heads_local: int, causal: bool = True, mask=None,
     b, s, h = x.shape
     if cfg.megatron_sp:
         s = s * lax.axis_size(TP_AXIS)
-    qkv = column_parallel_linear(x, p["qkv_kernel"], p["qkv_bias"],
-                                 gather_output=False,
-                                 sequence_parallel=cfg.megatron_sp,
-                                 overlap_comm=cfg.overlap_comm)
-    # per-head interleaved packing — column c of the global qkv kernel is
-    # (head, {q,k,v}, head_dim): a contiguous TP column split then assigns
-    # whole heads with their q, k, v together, so the computed function is
-    # EXACTLY invariant to the TP degree. The flat (3, heads, head_dim)
-    # order would make a tp split hand rank 0 "q of heads 0..H/2 but k of
-    # heads H/2..H", silently mixing regions across degrees.
-    qkv = qkv.reshape(b, s, heads_local, 3, cfg.head_dim)
-    q, k, v = (qkv[:, :, :, i].transpose(0, 2, 1, 3) for i in range(3))
+    with span("attn/qkv"):
+        qkv = column_parallel_linear(x, p["qkv_kernel"], p["qkv_bias"],
+                                     gather_output=False,
+                                     sequence_parallel=cfg.megatron_sp,
+                                     overlap_comm=cfg.overlap_comm)
+        # per-head interleaved packing — column c of the global qkv kernel
+        # is (head, {q,k,v}, head_dim): a contiguous TP column split then
+        # assigns whole heads with their q, k, v together, so the computed
+        # function is EXACTLY invariant to the TP degree. The flat (3,
+        # heads, head_dim) order would make a tp split hand rank 0 "q of
+        # heads 0..H/2 but k of heads H/2..H", silently mixing regions
+        # across degrees.
+        qkv = qkv.reshape(b, s, heads_local, 3, cfg.head_dim)
+        q, k, v = (qkv[:, :, :, i].transpose(0, 2, 1, 3) for i in range(3))
+    with span("attn/core"):
+        ctx = _attention_core(q, k, v, cfg, causal, mask, dropout_key)
+    with span("attn/out"):
+        ctx = ctx.transpose(0, 2, 1, 3).reshape(
+            b, s, heads_local * cfg.head_dim)
+        # (the dots_attn remat names live INSIDE the flash custom_vjp
+        # forward — ops/attention.py tags o and lse, the exact backward
+        # residuals; tagging here would save the output without lse and
+        # the kernel would replay anyway)
+        return row_parallel_linear(ctx, p["out_kernel"], p["out_bias"],
+                                   input_is_parallel=True,
+                                   sequence_parallel=cfg.megatron_sp,
+                                   overlap_comm=cfg.overlap_comm)
+
+
+def _attention_core(q, k, v, cfg, causal, mask, dropout_key):
+    """The attention core on (b, heads, s, head_dim): the flash kernel, or
+    the K/V ring where the sequence is sharded over sp."""
     try:
         sp = lax.axis_size(SP_AXIS)
     except NameError:
@@ -360,34 +381,23 @@ def _attention(p, x, cfg, heads_local: int, causal: bool = True, mask=None,
                 attention_dropout_seed,
             )
 
-            ctx = ring_attention(
+            return ring_attention(
                 q, k, v, causal=causal, dropout_rate=rate,
                 dropout_seed=attention_dropout_seed(dropout_key))
-        else:
-            ctx = ring_attention(q, k, v, causal=causal)
-    elif rate > 0.0:
+        return ring_attention(q, k, v, causal=causal)
+    if rate > 0.0:
         from apex_tpu.transformer.tensor_parallel.random import (
             attention_dropout_seed,
         )
 
         seed = attention_dropout_seed(dropout_key)
-        ctx = flash_attention(q, k, v, causal=causal, mask=mask,
-                              block_q=cfg.attn_block_q,
-                              block_k=cfg.attn_block_k,
-                              dropout_rate=rate, dropout_seed=seed)
-    else:
-        ctx = flash_attention(q, k, v, causal=causal, mask=mask,
-                              block_q=cfg.attn_block_q,
-                              block_k=cfg.attn_block_k)
-    ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, heads_local * cfg.head_dim)
-    # (the dots_attn remat names live INSIDE the flash custom_vjp forward
-    # — ops/attention.py tags o and lse, the exact backward residuals;
-    # tagging here would save the output without lse and the kernel would
-    # replay anyway)
-    return row_parallel_linear(ctx, p["out_kernel"], p["out_bias"],
-                               input_is_parallel=True,
-                               sequence_parallel=cfg.megatron_sp,
-                               overlap_comm=cfg.overlap_comm)
+        return flash_attention(q, k, v, causal=causal, mask=mask,
+                               block_q=cfg.attn_block_q,
+                               block_k=cfg.attn_block_k,
+                               dropout_rate=rate, dropout_seed=seed)
+    return flash_attention(q, k, v, causal=causal, mask=mask,
+                           block_q=cfg.attn_block_q,
+                           block_k=cfg.attn_block_k)
 
 
 def _mlp(p, x, cfg):
@@ -426,15 +436,18 @@ def _mlp(p, x, cfg):
         else:
             out, aux = moe_mlp(p, x, cfg.moe_config, ep_axis=DP_AXIS)
         return out, aux["loss"]
-    y = column_parallel_linear(x, p["fc1_kernel"], p["fc1_bias"],
-                               gather_output=False,
-                               sequence_parallel=cfg.megatron_sp,
-                               overlap_comm=cfg.overlap_comm)
-    y = jax.nn.gelu(y, approximate=True)
-    out = row_parallel_linear(y, p["fc2_kernel"], p["fc2_bias"],
-                              input_is_parallel=True,
-                              sequence_parallel=cfg.megatron_sp,
-                              overlap_comm=cfg.overlap_comm)
+    with span("mlp/fc"):
+        y = column_parallel_linear(x, p["fc1_kernel"], p["fc1_bias"],
+                                   gather_output=False,
+                                   sequence_parallel=cfg.megatron_sp,
+                                   overlap_comm=cfg.overlap_comm)
+    with span("mlp/act"):
+        y = jax.nn.gelu(y, approximate=True)
+    with span("mlp/proj"):
+        out = row_parallel_linear(y, p["fc2_kernel"], p["fc2_bias"],
+                                  input_is_parallel=True,
+                                  sequence_parallel=cfg.megatron_sp,
+                                  overlap_comm=cfg.overlap_comm)
     return out, jnp.zeros((), jnp.float32)
 
 
@@ -474,17 +487,20 @@ def _layer(p, x, cfg, heads_local: int, causal: bool = True, mask=None,
         k_h1, k_h2 = _hidden_key(k_h1, cfg), _hidden_key(k_h2, cfg)
     else:
         k_attn = k_h1 = k_h2 = None
-    a = _attention(p, layer_norm(x, p["ln1_w"], p["ln1_b"],
-                             use_pallas=cfg.ln_pallas), cfg,
-                   heads_local, causal, mask, dropout_key=k_attn)
-    if k_h1 is not None and cfg.hidden_dropout > 0.0:
-        a = _hidden_dropout(a, cfg.hidden_dropout, k_h1)
-    x = x + a
-    m, aux = _mlp(p, layer_norm(x, p["ln2_w"], p["ln2_b"],
-                            use_pallas=cfg.ln_pallas), cfg)
-    if k_h2 is not None and cfg.hidden_dropout > 0.0:
-        m = _hidden_dropout(m, cfg.hidden_dropout, k_h2)
-    return x + m, aux
+    with span("ln1"):
+        h = layer_norm(x, p["ln1_w"], p["ln1_b"], use_pallas=cfg.ln_pallas)
+    a = _attention(p, h, cfg, heads_local, causal, mask, dropout_key=k_attn)
+    with span("residual"):
+        if k_h1 is not None and cfg.hidden_dropout > 0.0:
+            a = _hidden_dropout(a, cfg.hidden_dropout, k_h1)
+        x = x + a
+    with span("ln2"):
+        h = layer_norm(x, p["ln2_w"], p["ln2_b"], use_pallas=cfg.ln_pallas)
+    m, aux = _mlp(p, h, cfg)
+    with span("residual"):
+        if k_h2 is not None and cfg.hidden_dropout > 0.0:
+            m = _hidden_dropout(m, cfg.hidden_dropout, k_h2)
+        return x + m, aux
 
 
 def dots_attn_policy():
@@ -587,7 +603,8 @@ def _layer_stack(layers, x, cfg, causal: bool = True, mask=None,
 
     def body(h, lp_key):
         lp, key = lp_key
-        h, aux = one(lp, h, key if dropout_key is not None else None)
+        with span("layer"):
+            h, aux = one(lp, h, key if dropout_key is not None else None)
         return h, aux
 
     out, aux_per_layer = lax.scan(body, x, (layers, keys),
@@ -629,6 +646,7 @@ def embed_tokens(embed, tokens, megatron_sp: bool = False):
     return h + pos[None].astype(h.dtype)
 
 
+@span_function(name="embed")
 def _embed_with_dropout(embed, tokens, cfg: GPTConfig, dropout_key):
     x = embed_tokens(embed, tokens, megatron_sp=cfg.megatron_sp)
     if dropout_key is not None and cfg.hidden_dropout > 0.0:
@@ -679,9 +697,19 @@ def gpt_head(params, x, cfg: GPTConfig):
     """Final LN + LM head -> vocab-sharded logits. Tied: logits_i = h @ tok_iᵀ
     (each rank's vocab shard). Under ``cfg.megatron_sp`` the final LN runs
     on the sequence shard; :func:`tied_vocab_logits` gathers at the exit."""
-    head = params["head"]
-    x = layer_norm(x, head["ln_w"], head["ln_b"],
-                   use_pallas=cfg.ln_pallas)
+    x = _final_ln(params["head"], x, cfg.ln_pallas)
+    with span("lm_head"):
+        return _lm_logits(params, x, cfg)
+
+
+def _final_ln(head, x, ln_use_pallas):
+    with span("final_ln"):
+        return layer_norm(x, head["ln_w"], head["ln_b"],
+                          use_pallas=ln_use_pallas)
+
+
+def _lm_logits(params, x, cfg: GPTConfig):
+    """The LM head on the final LayerNorm's output."""
     if cfg.tie_embeddings:
         return tied_vocab_logits(x, params["embed"]["tok"], cfg.megatron_sp)
     if cfg.megatron_sp:
@@ -690,7 +718,8 @@ def gpt_head(params, x, cfg: GPTConfig):
         )
 
         x = gather_from_sequence_parallel_region(x)
-    return column_parallel_linear(x, head["lm"], gather_output=False)
+    return column_parallel_linear(x, params["head"]["lm"],
+                                  gather_output=False)
 
 
 def _use_fused_loss(cfg: GPTConfig, n_rows: int) -> bool:
@@ -723,20 +752,22 @@ def fused_head_loss(head_rows_w, ln_w, ln_b, x, targets,
         pvary_like,
     )
 
-    x = layer_norm(x, ln_w, ln_b, use_pallas=ln_use_pallas)
-    if gather_sequence:
-        x = gather_from_sequence_parallel_region(x)
-    x = copy_to_tensor_model_parallel_region(x)
-    # the loss kernel's custom_vjp hides w's linearity from shard_map's
-    # invariant-input reduction; vary it explicitly over the activations'
-    # axes so dw is psum'd over the data axes at the pvary transpose
-    w = pvary_like(head_rows_w, x)
-    kw = {}
-    if block_n:
-        kw["block_n"] = block_n
-    if block_v:
-        kw["block_v"] = block_v
-    return jnp.mean(lm_head_loss(x, w, targets, axis_name=TP_AXIS, **kw))
+    x = _final_ln({"ln_w": ln_w, "ln_b": ln_b}, x, ln_use_pallas)
+    with span("lm_head_loss"):
+        if gather_sequence:
+            x = gather_from_sequence_parallel_region(x)
+        x = copy_to_tensor_model_parallel_region(x)
+        # the loss kernel's custom_vjp hides w's linearity from shard_map's
+        # invariant-input reduction; vary it explicitly over the
+        # activations' axes so dw is psum'd over the data axes at the pvary
+        # transpose
+        w = pvary_like(head_rows_w, x)
+        kw = {}
+        if block_n:
+            kw["block_n"] = block_n
+        if block_v:
+            kw["block_v"] = block_v
+        return jnp.mean(lm_head_loss(x, w, targets, axis_name=TP_AXIS, **kw))
 
 
 def gpt_loss(params, tokens, targets, cfg: GPTConfig, dropout_key=None):
@@ -752,9 +783,13 @@ def gpt_loss(params, tokens, targets, cfg: GPTConfig, dropout_key=None):
     x, aux = _layer_stack(params["layers"], x, cfg, dropout_key=dropout_key)
     head = params["head"]
     if not _use_fused_loss(cfg, tokens.shape[0] * tokens.shape[1]):
-        logits = gpt_head(params, x, cfg)
-        # logits stay in model dtype; CE upcasts internally (fused by XLA)
-        return jnp.mean(vocab_parallel_cross_entropy(logits, targets)) + aux
+        x = _final_ln(head, x, cfg.ln_pallas)
+        with span("lm_head_loss"):
+            logits = _lm_logits(params, x, cfg)
+            # logits stay in model dtype; CE upcasts internally (fused by
+            # XLA)
+            return jnp.mean(
+                vocab_parallel_cross_entropy(logits, targets)) + aux
     w = (params["embed"]["tok"] if cfg.tie_embeddings
          else head["lm"].T)  # (vocab/tp, hidden) rows
     return fused_head_loss(w, head["ln_w"], head["ln_b"], x, targets,

@@ -46,6 +46,7 @@ def flagship_config(seq: int = SEQ, **overrides):
 def train_step_fn(cfg, mesh):
     """The jitted fwd+bwd+FusedAdam step of ``cfg`` over ``mesh`` (params
     and optimizer state donated), plus the optimizer it steps."""
+    from apex_tpu.monitor.trace import register_program, span
     from apex_tpu.optimizers import FusedAdam
     from apex_tpu.transformer.pipeline_parallel.schedules.common import (
         replicate_loss,
@@ -76,11 +77,39 @@ def train_step_fn(cfg, mesh):
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def train_step(params, opt_state, tok, tgt):
         loss, grads = jax.value_and_grad(loss_fn)(params, tok, tgt)
-        updates, opt_state = update(grads, opt_state, params)
-        params = jax.tree.map(lambda p, u: p + u, params, updates)
+        with span("opt"):
+            updates, opt_state = update(grads, opt_state, params)
+            params = jax.tree.map(lambda p, u: p + u, params, updates)
         return params, opt_state, loss
 
+    def lower(rows: int, seq: int):
+        """The step lowered at the shapes and shardings a job hands it,
+        from shapes alone: for ``monitor.trace.scope_table``."""
+        return train_step.lower(
+            *abstract_train_args(cfg, opt, mesh, rows, seq))
+
+    register_program("jit_train_step", lower)
     return train_step, opt
+
+
+def abstract_train_args(cfg, opt, mesh, rows: int, seq: int):
+    """``(params, opt_state, tok, tgt)`` as ``ShapeDtypeStruct``s placed as
+    :func:`build_train_step` places the real ones: no array is made."""
+    from apex_tpu.transformer.testing import gpt_param_specs, init_gpt_params
+
+    def placed(a, spec):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    specs = gpt_param_specs(cfg)
+    params = jax.tree.map(placed, jax.eval_shape(
+        lambda: init_gpt_params(jax.random.PRNGKey(0), cfg)), specs)
+    state = jax.eval_shape(opt.init, params)
+    state = state._replace(count=placed(state.count, P()),
+                           mu=jax.tree.map(placed, state.mu, specs),
+                           nu=jax.tree.map(placed, state.nu, specs))
+    tok = placed(jax.ShapeDtypeStruct((rows, seq), jnp.int32), P("dp"))
+    return params, state, tok, tok
 
 
 _STEP_CACHE: dict = {}

@@ -68,26 +68,6 @@ def _compile(tag, jitted, *args, min_kernels=1):
     return True
 
 
-def _train_args(cfg, opt, mesh, batch, seq):
-    """Abstract (params, opt_state, tok, tgt) placed as
-    ``bench.build_train_step`` places the real ones."""
-    from apex_tpu.transformer.testing import gpt_param_specs, init_gpt_params
-
-    placed = lambda a, spec: jax.ShapeDtypeStruct(
-        a.shape, a.dtype, sharding=NamedSharding(mesh, spec))
-    specs = gpt_param_specs(cfg)
-    params = jax.tree.map(
-        placed, jax.eval_shape(
-            lambda: init_gpt_params(jax.random.PRNGKey(0), cfg)), specs)
-    state = jax.eval_shape(opt.init, params)
-    state = state._replace(
-        count=placed(state.count, P()),
-        mu=jax.tree.map(placed, state.mu, specs),
-        nu=jax.tree.map(placed, state.nu, specs))
-    tok = placed(jax.ShapeDtypeStruct((batch, seq), jnp.int32), P("dp"))
-    return params, state, tok, tok
-
-
 def main() -> int:
     import bench
     from apex_tpu.parallel.mesh import build_mesh
@@ -100,7 +80,8 @@ def main() -> int:
         mesh = build_mesh(tp=tp, pp=1, sp=1, dp=dp, devices=devices[:dp * tp])
         cfg = bench.flagship_config(seq, **cfg_kw)
         step, opt = bench.train_step_fn(cfg, mesh)
-        return _compile(tag, step, *_train_args(cfg, opt, mesh, batch, seq),
+        return _compile(tag, step,
+                        *bench.abstract_train_args(cfg, opt, mesh, batch, seq),
                         min_kernels=4)
 
     # --- the flagship train step, every sweep configuration -------------

@@ -337,19 +337,25 @@ def test_span_phases_in_measured_table():
 # report helpers
 
 
-def test_phase_breakdown_unwraps_ad_wrappers():
-    """Spans traced under jax.grad surface as jvp(name)/transpose(jvp(name))
-    scope components; the phase rollup must peel the AD wrappers so one
-    logical phase stays one bucket."""
+def test_phase_breakdown_keeps_the_passes_of_one_scope_apart():
+    """A span traced under jax.grad surfaces under jvp(...) and
+    transpose(jvp(...)) wrappers, and its replay under a checkpoint's
+    rematted_computation: the rollup names the pass (``split_scope``), so
+    forward, replay and backward of one scope are three buckets, and a span
+    no differentiation wraps keeps its own name."""
     measured = {"rows": [
-        {"scope": "jit(main)/fwd", "time_ms": 1.0},
-        {"scope": "jit(main)/jvp(fwd)", "time_ms": 2.0},
-        {"scope": "jit(main)/transpose(jvp(fwd))", "time_ms": 3.0},
-        {"scope": "opt", "time_ms": 4.0},
+        {"scope": "jit(main)/jvp(loss)", "time_ms": 2.0},
+        {"scope": "jit(main)/jvp()/while/body/closed_call/layer", "time_ms": 1.0},
+        {"scope": "jit(main)/transpose(jvp())/while/body/closed_call/layer/"
+                  "checkpoint/rematted_computation/attn", "time_ms": 1.5},
+        {"scope": "jit(main)/transpose(jvp(loss))", "time_ms": 3.0},
+        {"scope": "jit(main)/opt/adam_tail", "time_ms": 4.0},
+        {"scope": "opt", "time_ms": 0.25},
         {"scope": "jit(main)", "time_ms": 0.5},
     ]}
     assert phase_breakdown(measured) == {
-        "fwd": 6.0, "opt": 4.0, "<no-scope>": 0.5}
+        "opt": 4.25, "fwd": 3.0, "bwd": 3.0, "recompute": 1.5,
+        "<no-scope>": 0.5}
 
 
 def test_sink_log_every_enables_metrics_logger(tmp_path):
@@ -557,6 +563,7 @@ def test_instrumented_gpt_step_compiles_once_and_sinks_jsonl(tmp_path):
     where every record carries step/loss/grad-norm/loss-scale/overflow/
     comm-bytes, the comm bytes match accounting on the compiled HLO, and
     the compile count is 1 with monitoring on AND off."""
+    from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
     from apex_tpu.amp import LossScaler
@@ -611,11 +618,17 @@ def test_instrumented_gpt_step_compiles_once_and_sinks_jsonl(tmp_path):
         if monitored:
             # discover the step's full metric-name set WITHOUT compiling,
             # then pre-seed so the carried treedef is stable from step 0
-            out_shape = jax.eval_shape(step, p, s, scaler_state, Metrics(),
-                                       tok, tok)
+            # (through a second jit of the same body: a trace with the
+            # empty Metrics would count in the measured step's own cache)
+            out_shape = jax.eval_shape(build(True), p, s, scaler_state,
+                                       Metrics(), tok, tok)
             m = Metrics({k: 0.0 for k in out_shape[3].names()})
         else:
             m = Metrics()
+        # the state placed on the mesh as the step hands it back: a first
+        # call on unplaced arrays is another input type, and a second trace
+        p, s, scaler_state, m = jax.device_put(
+            (p, s, scaler_state, m), NamedSharding(mesh, P()))
         compiled = step.lower(p, s, scaler_state, m, tok, tok).compile()
         path = str(tmp_path / f"gpt_{monitored}.jsonl")
         with JsonlSink(path, buffer_steps=2) as sink:

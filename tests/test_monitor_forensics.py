@@ -13,9 +13,6 @@ All stock-jax-safe (single device, manual clock, SimTransport):
   == fleet totals to the unit; deterministic across identical runs;
   cardinality overflow folds into ``_overflow`` LOUDLY; unknown
   resources raise; worker cost rates accrue and ride heartbeats;
-* **trend gating** — the ``python -m apex_tpu.monitor.trend`` CLI exits
-  1 on a step change in the bad direction, 0 on a stationary series and
-  0 on an improvement (good-direction moves never flag);
 * satellites: the tier-4 ``monitor.regress`` polarity rows, provenance
   byte-compatibility on ``json_record``, the ``monitor.view``
   attribution table / tenant rollup / ``--baseline`` diagnosis, and the
@@ -30,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.monitor import sink as sink_mod
-from apex_tpu.monitor import trend, view
+from apex_tpu.monitor import view
 from apex_tpu.monitor.attrib import (
     COMPONENTS,
     DEFAULT_TOL_MS,
@@ -415,71 +412,6 @@ def test_standalone_engine_attribution_and_meter():
     assert st["cost_per_token"] > 0.0
     assert m.stats()["totals"]["requests"] == len(TREQS)
     assert m.worker_cost_rate("solo") > 0.0
-
-
-# -- trend gating ------------------------------------------------------------
-
-
-def _bank(tmp_path, values, start=0):
-    hist = str(tmp_path / "hist.jsonl")
-    for i, v in enumerate(values):
-        trend.append_history(hist, {"metric": "serve", "ok": True,
-                                    "tokens_per_s": v}, stage="s10")
-    return hist
-
-
-def test_trend_cli_stationary_exit_0(tmp_path, capsys):
-    hist = _bank(tmp_path, [100.0, 101.0, 102.0] * 4)
-    assert trend.main(["check", hist, "--stage", "s10"]) == 0
-    rep = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rep["ok"] is True and rep["checked"] >= 1
-
-
-def test_trend_cli_step_change_exit_1(tmp_path, capsys):
-    hist = _bank(tmp_path, [100.0, 101.0, 102.0] * 4 + [70.0] * 5)
-    assert trend.main(["check", hist, "--stage", "s10"]) == 1
-    rep = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rep["ok"] is False
-    assert any(d["key"] == "tokens_per_s" and d["kind"] == "step"
-               for d in rep["drifts"])
-    assert rep["drift_score"] > 1.0
-
-
-def test_trend_good_direction_never_flags(tmp_path):
-    hist = _bank(tmp_path, [100.0, 101.0, 102.0] * 4 + [150.0] * 5)
-    assert trend.main(["check", hist, "--stage", "s10"]) == 0
-
-
-def test_trend_slow_drift_caught(tmp_path, capsys):
-    """Every pairwise hop stays inside a 15% regress gate (-3% each);
-    the series still walks 24% down off a stable baseline — the gap
-    trend gating exists to close."""
-    vals = [100.0, 101.0, 102.0] * 4 + [100.0 - 3.0 * i
-                                        for i in range(1, 9)]
-    hist = _bank(tmp_path, vals)
-    assert trend.main(["check", hist, "--stage", "s10"]) == 1
-    rep = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert any(d["key"] == "tokens_per_s" for d in rep["drifts"])
-
-
-def test_trend_thin_history_passes(tmp_path):
-    hist = _bank(tmp_path, [100.0, 50.0, 100.0])
-    assert trend.main(["check", hist, "--stage", "s10"]) == 0
-
-
-def test_trend_append_cli_stamps_and_filters(tmp_path, capsys):
-    hist = str(tmp_path / "h.jsonl")
-    rec = tmp_path / "rec.json"
-    rec.write_text(json.dumps({"metric": "m", "tokens_per_s": 9.0}) + "\n")
-    assert trend.main(["append", hist, str(rec), "--stage", "a"]) == 0
-    assert trend.main(["append", hist, str(rec), "--stage", "b"]) == 0
-    capsys.readouterr()
-    assert len(trend.load_history(hist, stage="a")) == 1
-    assert len(trend.load_history(hist)) == 2
-    pts = [json.loads(ln) for ln in open(hist)]
-    assert all(p["kind"] == "trend_point" for p in pts)
-    # the CLI stamps provenance so a drift can be tied to what changed
-    assert "provenance" in pts[0]
 
 
 # -- satellites: polarity, provenance, view ---------------------------------
