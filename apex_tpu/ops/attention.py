@@ -7,13 +7,28 @@ QKV+softmax+dropout+out-proj, drivers ``apex/contrib/multihead_attn/``).
 Those CUDA kernels exist because eager attention materializes the (sq, sk)
 score matrix in HBM; they are hard-limited to seqlen ≤ 512.
 
-TPU re-design: the flash-attention scheme — tile Q into VMEM blocks, stream
-K/V blocks through the MXU, keep a running row-max and denominator (online
-softmax), never materialize the score matrix. This removes the reference's
+TPU re-design: the flash-attention scheme — scores are computed a
+(block_q, block_k) tile at a time with a running row-max and denominator
+(online softmax) and never materialized. This removes the reference's
 sequence-length limit entirely and is the building block for ring attention
 (``apex_tpu/transformer/sequence_parallel.py``). Backward recomputes scores
-blockwise from the saved output and row log-sum-exp (the standard flash
+tile by tile from the saved output and row log-sum-exp (the standard flash
 backward), as two accumulation kernels (dQ, and dK/dV).
+
+Who loops over the tiles is the *tile schedule*, chosen per shape by
+``_tile_plan`` and by nothing else:
+
+* **streamed** — q tiles and K/V tiles both on the grid, the statistics in
+  VMEM scratch across the innermost grid dim, dead causal steps skipped by
+  predicate. Any length, and the additive bias (its tile rides the grid).
+* **resident** — one head a grid step, its Q, K, V (and dO, lse, delta)
+  whole in VMEM, the loops over q tiles and K/V tiles unrolled inside the
+  kernel with static indices. A causal call computes only the tiles on or
+  under the diagonal and builds the mask only on those that straddle it;
+  m, l and the accumulators are values, stored once a row (or K/V) block.
+  Every unrolled tile body is compiled, lowered and hashed at each set-up,
+  so a kernel holds at most ``_RESIDENT_MAX_BODIES`` of them (a 2 x 2
+  rectangle): a call over that, or over the VMEM budget, streams.
 
 Layout: (batch, heads, seq, head_dim) — matches the Megatron attention core
 the transformer layer uses.
@@ -23,7 +38,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -92,7 +107,8 @@ def attention_reference(q, k, v, mask=None, scale: Optional[float] = None,
 
 
 # ---------------------------------------------------------------------------
-# Pallas forward
+# Dropout: the counter-hash keep mask the kernels and the dense / ring
+# einsum paths share
 
 def _dropout_keep(seed_ref, rate, block_q, block_k, q_i, kv_i, bh_i):
     """Deterministic keep mask from a counter-based hash of (seed, batch*head,
@@ -112,10 +128,10 @@ def _dropout_keep(seed_ref, rate, block_q, block_k, q_i, kv_i, bh_i):
     # all-uint32 arithmetic: mixing a signed scalar into the uint32 iota
     # would promote/wrap and skew the keep probability
     qpos = (seed_ref[1].astype(jnp.uint32)
-            + (q_i * block_q).astype(jnp.uint32)
+            + jnp.asarray(q_i * block_q).astype(jnp.uint32)
             + jax.lax.broadcasted_iota(jnp.uint32, (block_q, block_k), 0))
     kpos = (seed_ref[2].astype(jnp.uint32)
-            + (kv_i * block_k).astype(jnp.uint32)
+            + jnp.asarray(kv_i * block_k).astype(jnp.uint32)
             + jax.lax.broadcasted_iota(jnp.uint32, (block_q, block_k), 1))
     return _hash_keep(qpos, kpos, seed_ref[0].astype(jnp.uint32),
                       bh_i.astype(jnp.uint32), rate)
@@ -166,6 +182,199 @@ def attention_dropout_mask(seed, rate: float, bh: int, sq: int, sk: int,
     bh_i = jnp.arange(bh, dtype=jnp.uint32)[:, None, None]
     return _hash_keep(qpos, kpos, jnp.asarray(seed).astype(jnp.uint32),
                       bh_i, rate)
+
+
+# ---------------------------------------------------------------------------
+# The tile schedule: which (q tile, K/V tile) pairs a call visits, at what
+# size, and whether the loop over them is the kernel's own or the grid's.
+
+# Unrolled tile bodies a resident kernel may hold: a 2 x 2 rectangle, of which
+# a causal call visits 3 (s 1024 at 512 x 512, the benchmark cells' call).
+# The v5e's kernel time barely moves with more of them (3 -> 20 bodies: 4.14
+# -> 3.95 ms a layer at the cells' shape), while every body is traced,
+# lowered, hashed into the compile-cache key and loaded at each warm set-up
+# and compiled at each cold one: the three kernels' first call takes 1.0-1.6
+# s streamed, 2.0 s at 3 bodies, 2.1 s at 4 and 5.1 s at 20, which cost PR 26
+# 12% of gpt2-medium's setup_s (PERF.md section 6, PR 26 and PR 27).
+_RESIDENT_MAX_BODIES = 4
+
+# VMEM a resident grid step may plan for: the widest kernel's operands and
+# results (Q, dO, K, V, dK, dV, lse, delta in flash_bwd_dkv), double-buffered
+# and counted as Mosaic lays them out (the minor dim padded to 128 lanes, so
+# an (s, 1) fp32 column costs s * 512 bytes), plus six fp32 score-sized
+# temporaries of one tile. 11 MiB of it at the cells' shape; XLA's default
+# scoped limit is 16 MiB.
+_RESIDENT_VMEM_BYTES = 14 * 1024 * 1024
+
+
+class TilePlan(NamedTuple):
+    """What a call's kernels run, static per shape. ``visited`` / ``masked``
+    / ``rectangle`` count (q tile, K/V tile) pairs per head: computed, built
+    with the causal mask, and in the whole (sq, sk) rectangle."""
+    schedule: str  # "resident" | "streamed"
+    block_q: int
+    block_k: int
+    visited: int
+    masked: int
+    rectangle: int
+
+    @property
+    def bodies(self) -> int:
+        """Tile bodies one kernel's program holds: every visited tile when
+        the loops are unrolled in the kernel, one when the grid loops."""
+        return self.visited if self.schedule == "resident" else 1
+
+
+def _tile_kind(causal, q_i, kv_i, block_q, block_k):
+    """Tile (q_i, kv_i) -> (live: some score is visible, interior: every
+    score is, so the tile needs no mask)."""
+    if not causal:
+        return True, True
+    return (kv_i * block_k <= q_i * block_q + block_q - 1,
+            kv_i * block_k + block_k - 1 <= q_i * block_q)
+
+
+def _tile_plan(sq, sk, d, dtype, causal, block_q=512, block_k=512,
+               has_bias=False) -> TilePlan:
+    """The one place the schedule and the tile are chosen, from what the call
+    can observe. ``block_q`` / ``block_k`` bound the tile from above; the
+    widest divisor under them is taken on either schedule."""
+    bq, bk = _pick_block(sq, block_q), _pick_block(sk, block_k)
+    nq, nk = sq // bq, sk // bk
+    kinds = [_tile_kind(causal, i, j, bq, bk)
+             for i in range(nq) for j in range(nk)]
+    visited = sum(live for live, _ in kinds)
+    interior = sum(full for _, full in kinds)
+    itemsize = jnp.dtype(dtype).itemsize
+    lanes = -(-d // 128) * 128
+    vmem = (2 * (3 * (sq + sk) * lanes * itemsize + 2 * sq * 128 * 4)
+            + 6 * bq * bk * 4)
+    resident = (
+        visited <= _RESIDENT_MAX_BODIES and vmem <= _RESIDENT_VMEM_BYTES
+        # the bias tile rides the grid; a causal rectangle (no caller makes
+        # one) keeps the grid's own bounds
+        and not has_bias and (not causal or sq == sk)
+        # the kernel slices whole operands at multiples of the tile: they
+        # must sit on the dtype's sublane tiling (8 rows fp32, 16 bf16)
+        and bq % (32 // itemsize) == 0 and bk % (32 // itemsize) == 0)
+    if resident:
+        return TilePlan("resident", bq, bk, visited, visited - interior,
+                        nq * nk)
+    # the streamed kernels build the causal mask on every tile they compute
+    return TilePlan("streamed", bq, bk, visited, visited if causal else 0,
+                    nq * nk)
+
+
+def _scale_folds(scale) -> bool:
+    """A power-of-two ``scale`` (1/8 at head size 64) multiplies a bf16 or
+    fp32 operand exactly, so the resident kernels apply it to the (rows, d)
+    operand tile once instead of to every score."""
+    return math.frexp(scale)[0] == 0.5
+
+
+# ---------------------------------------------------------------------------
+# Tile bodies of the resident schedule
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
+
+
+def _tile_scores(q, k, scale, mask_at):
+    """fp32 score tile q @ k.T. ``scale`` is None when an operand carries it
+    (``_scale_folds``). ``mask_at`` = (first q row, first k column), static,
+    builds the causal mask: tiles that straddle the diagonal only; interior
+    tiles pass None and pay no iota, compare or select."""
+    # inputs stay in model dtype: MXU runs bf16 x bf16 -> fp32 natively
+    s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)
+    if scale is not None:
+        s = s * scale
+    if mask_at is not None:
+        q0, k0 = mask_at
+        ahead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                 - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
+        s = jnp.where(ahead > q0 - k0, NEG_INF, s)
+    return s
+
+
+def _tile_extras(interior, seed_ref, dropout_rate, block_q, block_k, q_i,
+                 kv_i, bh_i):
+    """(mask_at, keep) of tile (q_i, kv_i): the mask's origin if the tile
+    straddles the diagonal, the dropout keep mask if there is dropout."""
+    mask_at = None if interior else (q_i * block_q, kv_i * block_k)
+    keep = None
+    if dropout_rate > 0.0:
+        keep = _dropout_keep(seed_ref, dropout_rate, block_q, block_k, q_i,
+                             kv_i, bh_i)
+    return mask_at, keep
+
+
+def _fwd_tile(carry, q, k, v, scale, mask_at, keep, dropout_rate):
+    """One online-softmax step: (m, l, acc) after this K/V tile. ``carry``
+    None = the row block's first tile, with nothing to rescale."""
+    s = _tile_scores(q, k, scale, mask_at)
+    m_new = jnp.max(s, axis=1, keepdims=True)
+    if carry is not None:
+        m_prev, l_prev, acc = carry
+        m_new = jnp.maximum(m_prev, m_new)
+        corr = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    # l accumulates the UNdropped p: normalization precedes dropout,
+    # so the final divide yields dropout(softmax(s)) @ v exactly
+    l_new = jnp.sum(p, axis=1, keepdims=True)
+    if keep is not None:
+        p = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
+    pv = jax.lax.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    if carry is None:
+        return m_new, l_new, pv
+    return m_new, corr * l_prev + l_new, acc * corr + pv
+
+
+def _bwd_tile(q, k, v, do, lse, delta, scale, mask_at, keep, dropout_rate):
+    """Scores recomputed from the saved lse -> (p as dV sees it, dL/ds
+    without the q·kᵀ ``scale``, which the caller applies to its sum)."""
+    s = _tile_scores(q, k, scale, mask_at)
+    p = jnp.exp(s - lse)
+    dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
+    p_v = p
+    if keep is not None:
+        inv = 1.0 / (1.0 - dropout_rate)
+        p_v = jnp.where(keep, p * inv, 0.0)
+        dp = jnp.where(keep, dp * inv, 0.0)
+    return p_v, p * (dp - delta)
+
+
+def _rows(i, block):
+    """Tile ``i`` of the one head a resident block holds."""
+    return 0, slice(i * block, (i + 1) * block)
+
+
+# ---------------------------------------------------------------------------
+# Pallas forward
+
+def _fa_fwd_resident_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+                            scale, causal, block_q, block_k, dropout_rate):
+    """Resident schedule: Q, K and V of one head whole in VMEM, the loops
+    over q tiles and K/V tiles unrolled here with every index static."""
+    bh_i = pl.program_id(0)
+    fold = _scale_folds(scale)
+    for q_i in range(q_ref.shape[1] // block_q):
+        q_at = _rows(q_i, block_q)
+        q = q_ref[q_at] * scale if fold else q_ref[q_at]
+        carry = None
+        for kv_i in range(k_ref.shape[1] // block_k):
+            live, interior = _tile_kind(causal, q_i, kv_i, block_q, block_k)
+            if not live:
+                continue
+            kv_at = _rows(kv_i, block_k)
+            carry = _fwd_tile(
+                carry, q, k_ref[kv_at], v_ref[kv_at], None if fold else scale,
+                *_tile_extras(interior, seed_ref, dropout_rate, block_q,
+                              block_k, q_i, kv_i, bh_i), dropout_rate)
+        # no l == 0 guard as in the streamed _finish: a resident row sees its
+        # own diagonal (or, not causal, every key), so its max gives l >= 1
+        m, l, acc = carry
+        o_ref[q_at] = (acc / l).astype(o_ref.dtype)
+        lse_ref[q_at] = m + jnp.log(l)
 
 
 def _fa_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *refs,
@@ -256,14 +465,44 @@ def _bias_spec(num_heads, block_q, block_k, causal=False):
     return pl.BlockSpec((1, block_q, block_k), index)
 
 
+def _head_spec(rows, width):
+    """A resident kernel's operand: one whole head a grid step."""
+    return pl.BlockSpec((1, rows, width), lambda b: (b, 0, 0))
+
+
 def _fa_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret,
             dropout_rate=0.0, seed=None, bias=None):
+    """(o, lse (bh, sq, 1)) of one attention call on (bh, seq, d) arrays.
+    ``block_q`` / ``block_k`` bound the tile; ``_tile_plan`` picks it and
+    the schedule."""
     bh, sq, d = q3.shape
     sk = k3.shape[1]
-    nq = sq // block_q
-    nk = sk // block_k
     seed = _seed3(seed)
     has_bias = bias is not None
+    plan = _tile_plan(sq, sk, d, q3.dtype, causal, block_q, block_k, has_bias)
+    block_q, block_k = plan.block_q, plan.block_k
+    nq = sq // block_q
+    nk = sk // block_k
+    out_shape = [
+        _sds((bh, sq, d), q3.dtype, q3, k3, v3),
+        _sds((bh, sq, 1), jnp.float32, q3, k3, v3),
+    ]
+    if plan.schedule == "resident":
+        return pl.pallas_call(
+            functools.partial(
+                _fa_fwd_resident_kernel, scale=scale, causal=causal,
+                block_q=block_q, block_k=block_k, dropout_rate=dropout_rate),
+            name="flash_fwd",
+            grid=(bh,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                      _head_spec(sq, d), _head_spec(sk, d), _head_spec(sk, d)],
+            out_specs=[_head_spec(sq, d), _head_spec(sq, 1)],
+            out_shape=out_shape,
+            compiler_params=None if interpret else pltpu.CompilerParams(
+                dimension_semantics=("parallel",)),
+            interpret=interpret,
+        )(seed, q3, k3, v3)
+
     kernel = functools.partial(
         _fa_fwd_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, nk=nk, dropout_rate=dropout_rate,
@@ -298,10 +537,7 @@ def _fa_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret,
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
-        out_shape=[
-            _sds((bh, sq, d), q3.dtype, q3, k3, v3),
-            _sds((bh, sq, 1), jnp.float32, q3, k3, v3),
-        ],
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
@@ -315,10 +551,75 @@ def _fa_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret,
 
 
 # ---------------------------------------------------------------------------
-# Pallas backward: dQ kernel (grid over K/V blocks innermost) and dK/dV kernel
-# (grid over Q blocks innermost). Scores are recomputed from q, k and the
+# Pallas backward: dQ kernel (loops over K/V blocks) and dK/dV kernel (loops
+# over Q blocks), each on the forward's schedule: the grid's innermost dim
+# when streamed, unrolled when resident. Scores are recomputed from q, k and the
 # saved lse — p = exp(s - lse) is already normalized, so no second pass over
 # the row is needed (the flash-attention backward identity).
+
+def _fa_bwd_dq_resident_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref,
+                               lse_ref, delta_ref, dq_ref, *,
+                               scale, causal, block_q, block_k, dropout_rate):
+    """Resident schedule (see ``_fa_fwd_resident_kernel``): dq summed over a
+    q tile's live K/V tiles in a value, scaled and written once."""
+    bh_i = pl.program_id(0)
+    fold = _scale_folds(scale)
+    for q_i in range(q_ref.shape[1] // block_q):
+        q_at = _rows(q_i, block_q)
+        q = q_ref[q_at] * scale if fold else q_ref[q_at]
+        do, lse, delta = do_ref[q_at], lse_ref[q_at], delta_ref[q_at]
+        dq = None
+        for kv_i in range(k_ref.shape[1] // block_k):
+            live, interior = _tile_kind(causal, q_i, kv_i, block_q, block_k)
+            if not live:
+                continue
+            kv_at = _rows(kv_i, block_k)
+            k = k_ref[kv_at]
+            _, ds = _bwd_tile(
+                q, k, v_ref[kv_at], do, lse, delta, None if fold else scale,
+                *_tile_extras(interior, seed_ref, dropout_rate, block_q,
+                              block_k, q_i, kv_i, bh_i), dropout_rate)
+            part = jax.lax.dot(ds.astype(k.dtype), k,
+                               preferred_element_type=jnp.float32)
+            dq = part if dq is None else dq + part
+        dq_ref[q_at] = (dq * scale).astype(dq_ref.dtype)
+
+
+def _fa_bwd_dkv_resident_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref,
+                                lse_ref, delta_ref, dk_ref, dv_ref, *,
+                                scale, causal, block_q, block_k,
+                                dropout_rate):
+    """Resident schedule, mirrored: for each K/V tile the q tiles from the
+    diagonal down, dk and dv summed in values and written once."""
+    bh_i = pl.program_id(0)
+    fold = _scale_folds(scale)
+    for kv_i in range(k_ref.shape[1] // block_k):
+        kv_at = _rows(kv_i, block_k)
+        k = k_ref[kv_at] * scale if fold else k_ref[kv_at]
+        v = v_ref[kv_at]
+        dk = dv = None
+        for q_i in range(q_ref.shape[1] // block_q):
+            live, interior = _tile_kind(causal, q_i, kv_i, block_q, block_k)
+            if not live:
+                continue
+            q_at = _rows(q_i, block_q)
+            q, do = q_ref[q_at], do_ref[q_at]
+            p_v, ds = _bwd_tile(
+                q, k, v, do, lse_ref[q_at], delta_ref[q_at],
+                None if fold else scale,
+                *_tile_extras(interior, seed_ref, dropout_rate, block_q,
+                              block_k, q_i, kv_i, bh_i), dropout_rate)
+            dv_part = jax.lax.dot_general(
+                p_v.astype(do.dtype), do, _TN,
+                preferred_element_type=jnp.float32)
+            dk_part = jax.lax.dot_general(
+                ds.astype(q.dtype), q, _TN,
+                preferred_element_type=jnp.float32)
+            dv = dv_part if dv is None else dv + dv_part
+            dk = dk_part if dk is None else dk + dk_part
+        dk_ref[kv_at] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[kv_at] = dv.astype(dv_ref.dtype)
+
 
 def _fa_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                       delta_ref, *refs,
@@ -493,14 +794,46 @@ def _fa_bwd_dbias_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _fa_bwd(q3, k3, v3, o3, lse, do3, scale, causal, block_q, block_k,
             interpret, dropout_rate=0.0, seed=None, bias=None):
+    """(dq, dk, dv, dbias or None) on the schedule ``_tile_plan`` gives the
+    shape — the forward's."""
     bh, sq, d = q3.shape
     sk = k3.shape[1]
-    nq = sq // block_q
-    nk = sk // block_k
     seed = _seed3(seed)
     has_bias = bias is not None
+    plan = _tile_plan(sq, sk, d, q3.dtype, causal, block_q, block_k, has_bias)
+    block_q, block_k = plan.block_q, plan.block_k
+    nq = sq // block_q
+    nk = sk // block_k
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
                     axis=-1, keepdims=True)
+    dq_shape = _sds((bh, sq, d), q3.dtype, q3, k3, v3, do3)
+    dkv_shape = [
+        _sds((bh, sk, d), k3.dtype, q3, k3, v3, do3),
+        _sds((bh, sk, d), v3.dtype, q3, k3, v3, do3),
+    ]
+
+    if plan.schedule == "resident":
+        static = dict(scale=scale, causal=causal, block_q=block_q,
+                      block_k=block_k, dropout_rate=dropout_rate)
+        call = dict(
+            grid=(bh,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                      _head_spec(sq, d), _head_spec(sk, d), _head_spec(sk, d),
+                      _head_spec(sq, d), _head_spec(sq, 1), _head_spec(sq, 1)],
+            compiler_params=None if interpret else pltpu.CompilerParams(
+                dimension_semantics=("parallel",)),
+            interpret=interpret)
+        inputs = (seed, q3, k3, v3, do3, lse, delta)
+        dq = pl.pallas_call(
+            functools.partial(_fa_bwd_dq_resident_kernel, **static),
+            name="flash_bwd_dq", out_specs=_head_spec(sq, d),
+            out_shape=dq_shape, **call)(*inputs)
+        dk, dv = pl.pallas_call(
+            functools.partial(_fa_bwd_dkv_resident_kernel, **static),
+            name="flash_bwd_dkv",
+            out_specs=[_head_spec(sk, d), _head_spec(sk, d)],
+            out_shape=dkv_shape, **call)(*inputs)
+        return dq, dk, dv, None
 
     dq_kernel = functools.partial(
         _fa_bwd_dq_kernel, scale=scale, causal=causal,
@@ -531,7 +864,7 @@ def _fa_bwd(q3, k3, v3, o3, lse, do3, scale, causal, block_q, block_k,
         grid=(bh, nq, nk),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=_sds((bh, sq, d), q3.dtype, q3, k3, v3, do3),
+        out_shape=dq_shape,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -573,10 +906,7 @@ def _fa_bwd(q3, k3, v3, o3, lse, do3, scale, causal, block_q, block_k,
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
         ],
-        out_shape=[
-            _sds((bh, sk, d), k3.dtype, q3, k3, v3, do3),
-            _sds((bh, sk, d), v3.dtype, q3, k3, v3, do3),
-        ],
+        out_shape=dkv_shape,
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),

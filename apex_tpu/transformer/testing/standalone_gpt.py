@@ -136,8 +136,12 @@ class GPTConfig:
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
     # Pallas kernel block sizes (benchmarks/tune_blocks.py sweeps these on
-    # hardware; 0 = the kernel's own default). Attention blocks trade VMEM
-    # residency vs grid parallelism; LM-head blocks trade the vocab-tile
+    # hardware; 0 = the kernel's own default). The attention pair are upper
+    # bounds on the tile: ``ops.attention._tile_plan`` takes the widest
+    # divisor of the sequence under them and picks the schedule from (seq,
+    # head size, dtype, causal, bias) — at seq 1024 a causal head runs
+    # resident on 512 x 512 tiles, three unrolled bodies a kernel; narrower
+    # bounds, or a longer head, stream. LM-head blocks trade the vocab-tile
     # streaming pattern.
     attn_block_q: int = 512
     attn_block_k: int = 512

@@ -7,6 +7,13 @@ bench.py's remat auto-tune already covers). This script times each
 candidate on the real chip with the value-transfer fence and prints the
 winner as the GPTConfig overrides to commit.
 
+The attention pair are upper bounds: ``ops.attention._tile_plan`` takes
+the widest divisor of the sequence under them and picks the schedule
+(resident: one head whole in VMEM and at most ``_RESIDENT_MAX_BODIES``
+tile bodies unrolled inside each kernel; streamed: tiles on the grid), and
+each attention row prints the plan it timed. At seq 1024 only 512 x 512
+keeps a causal head resident (3 bodies); narrower bounds stream (10 or more).
+
 Run: ``python benchmarks/tune_blocks.py [--steps N]``. Refuses to sweep
 on a non-TPU backend (interpret-mode timings would be meaningless) and
 prints the shapes it would have swept.
@@ -47,7 +54,7 @@ def _time(fn, *args, steps=5):
 def sweep_attention(steps: int):
     import jax.numpy as jnp
 
-    from apex_tpu.ops.attention import flash_attention
+    from apex_tpu.ops.attention import _tile_plan, flash_attention
 
     k = jax.random.PRNGKey(0)
     q = jax.random.normal(k, (B, HEADS, S, HEAD_DIM), jnp.bfloat16)
@@ -64,13 +71,17 @@ def sweep_attention(steps: int):
 
             return jax.grad(loss, argnums=(0, 1, 2))(q, kk, v)
 
+        plan = _tile_plan(S, S, HEAD_DIM, q.dtype, True, bq, bk)
         try:
             dt = _time(jax.jit(fwd_bwd), q, kk, v, steps=steps)
         except Exception as e:  # block combo invalid/OOM on this chip
             print(f"attn bq={bq:4d} bk={bk:4d}  FAILED "
                   f"{type(e).__name__}", flush=True)
             continue
-        print(f"attn bq={bq:4d} bk={bk:4d}  {dt * 1e3:8.3f} ms", flush=True)
+        print(f"attn bq={bq:4d} bk={bk:4d}  {dt * 1e3:8.3f} ms  "
+              f"{plan.schedule} {plan.block_q}x{plan.block_k}, "
+              f"{plan.visited} tiles ({plan.masked} masked) of "
+              f"{plan.rectangle}, {plan.bodies} bodies a kernel", flush=True)
         results.append((dt, bq, bk))
     if results:
         dt, bq, bk = min(results)

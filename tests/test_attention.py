@@ -182,3 +182,242 @@ def test_flash_bwd_kernels_respect_global_offsets():
         np.testing.assert_allclose(
             np.asarray(a).reshape(b, h, s, d), np.asarray(e), atol=2e-4,
             err_msg=name)
+
+
+# ---------------------------------------------------------------------------
+# The tile schedule (``_tile_plan``): resident (one head whole in VMEM, the
+# tile loops unrolled inside the kernel) against streamed (tiles on the grid).
+
+def _force_schedule(monkeypatch, schedule):
+    """Steer ``_tile_plan`` from the test through the cap on unrolled tile
+    bodies: none allowed means every call streams, no cap means every call
+    whose head fits VMEM is resident."""
+    from apex_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_RESIDENT_MAX_BODIES",
+                        0 if schedule == "streamed" else 10 ** 6)
+
+
+@pytest.mark.parametrize("rate", [0.0, 0.3])
+@pytest.mark.parametrize("block_q,block_k", [(32, 32), (32, 16), (16, 32)])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("schedule", ["resident", "streamed"])
+def test_schedule_matches_reference(monkeypatch, schedule, causal, block_q,
+                                    block_k, rate):
+    """Forward and all three gradients on either schedule, interior and
+    diagonal tiles, equal and unequal tiles (several diagonal tiles a q
+    tile, or one K/V tile across several q tiles), with and without the
+    in-kernel dropout."""
+    from apex_tpu.ops.attention import _tile_plan, attention_dropout_mask
+
+    _force_schedule(monkeypatch, schedule)
+    b, h, s, d = 1, 2, 64, 32
+    q, k, v = _qkv(jax.random.PRNGKey(12), b, h, s, s, d)
+    plan = _tile_plan(s, s, d, q.dtype, causal, block_q, block_k)
+    assert plan[:3] == (schedule, block_q, block_k)
+    seed = jnp.int32(41)
+    keep = None
+    if rate:
+        keep = attention_dropout_mask(seed, rate, b * h, s, s).reshape(
+            b, h, s, s)
+
+    def loss_flash(q, k, v):
+        o = flash_attention(q, k, v, causal=causal, use_pallas=True,
+                            block_q=block_q, block_k=block_k,
+                            dropout_rate=rate,
+                            dropout_seed=seed if rate else None)
+        return jnp.sum(jnp.sin(o)), o
+
+    def loss_ref(q, k, v):
+        o = attention_reference(q, k, v, causal=causal, dropout_rate=rate,
+                                dropout_keep=keep)
+        return jnp.sum(jnp.sin(o)), o
+
+    g1, o1 = jax.grad(loss_flash, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    g2, o2 = jax.grad(loss_ref, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=2e-5)
+    for a, e, name in zip(g1, g2, "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(e), atol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("schedule", ["resident", "streamed"])
+def test_schedule_respects_global_offsets(monkeypatch, schedule):
+    """``[seed, q_off, k_off]`` on either schedule, as the ring passes them.
+    K/V in two chunks at their global column offsets, merged by lse,
+    reproduce the dense call's output, gradients and dropout mask; the
+    second half of the q rows at its global row offset reproduces those
+    rows of the dense output."""
+    from apex_tpu.ops.attention import _fa_bwd, _fa_fwd, _tile_plan
+
+    _force_schedule(monkeypatch, schedule)
+    b, h, s, d = 1, 2, 128, 16
+    rate, seed, scale = 0.3, 77, 1.0 / d ** 0.5
+    q, k, v = (x.reshape(b * h, s, d)
+               for x in _qkv(jax.random.PRNGKey(13), b, h, s, s, d))
+    half = s // 2
+    assert _tile_plan(s, half, d, q.dtype, False, 32, 32)[0] == schedule
+    sv = lambda q_off, k_off: jnp.asarray([seed, q_off, k_off], jnp.int32)
+    o, lse = _fa_fwd(q, k, v, scale, False, 32, 32, True, rate, sv(0, 0))
+    do = jnp.cos(o)
+    want = _fa_bwd(q, k, v, o, lse, do, scale, False, 32, 32, True, rate,
+                   sv(0, 0))[:3]
+
+    parts = [_fa_fwd(q, k[:, c:c + half], v[:, c:c + half], scale, False,
+                     32, 32, True, rate, sv(0, c)) for c in (0, half)]
+    w = [jnp.exp(l - lse) for _, l in parts]
+    np.testing.assert_allclose(
+        np.asarray(sum(wi * oi for wi, (oi, _) in zip(w, parts))),
+        np.asarray(o), atol=2e-5)
+    got = [_fa_bwd(q, k[:, c:c + half], v[:, c:c + half], o, lse, do, scale,
+                   False, 32, 32, True, rate, sv(0, c)) for c in (0, half)]
+    got = (got[0][0] + got[1][0],
+           jnp.concatenate([got[0][1], got[1][1]], 1),
+           jnp.concatenate([got[0][2], got[1][2]], 1))
+    for a, e, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(e), atol=1e-4,
+                                   err_msg=name)
+
+    o_low, lse_low = _fa_fwd(q[:, half:], k, v, scale, False, 32, 32, True,
+                             rate, sv(half, 0))
+    np.testing.assert_allclose(np.asarray(o_low), np.asarray(o[:, half:]),
+                               atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse_low),
+                               np.asarray(lse[:, half:]), atol=2e-5)
+
+
+@pytest.mark.parametrize("block_q,block_k", [(32, 32), (16, 32)])
+def test_wholly_masked_row_gives_zero_output_and_neg_inf_lse(block_q,
+                                                             block_k):
+    """A row no key reaches (here: a -inf bias row; under the ring, a
+    partial block) leaves l == 0: the kernel must emit o = 0 and
+    lse = NEG_INF, the merge's identity, and not 0/0. Such a call carries a
+    bias, so it streams: a resident row always sees its own diagonal."""
+    from apex_tpu.ops.attention import NEG_INF, _fa_fwd, _tile_plan
+
+    q, k, v = (x.reshape(2, 64, 16)
+               for x in _qkv(jax.random.PRNGKey(14), 1, 2, 64, 64, 16))
+    assert _tile_plan(64, 64, 16, q.dtype, False, block_q, block_k,
+                      has_bias=True).schedule == "streamed"
+    bias = jnp.zeros((2, 64, 64)).at[:, 5, :].set(-jnp.inf)
+    o, lse = _fa_fwd(q, k, v, 0.25, False, block_q, block_k, True, bias=bias)
+    assert np.all(np.asarray(o)[:, 5] == 0.0)
+    assert np.all(np.asarray(lse)[:, 5, 0] == NEG_INF)
+    assert np.isfinite(np.asarray(o)).all()
+    want = attention_reference(q, k, v, scale=0.25)
+    rest = np.arange(64) != 5
+    np.testing.assert_allclose(np.asarray(o)[:, rest],
+                               np.asarray(want)[:, rest], atol=2e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("d", [64, 24])
+def test_scale_folded_into_operand_matches_scaled_scores(monkeypatch, d,
+                                                         dtype):
+    """1/sqrt(64) is a power of two and multiplies the q (or k) tile of a
+    resident kernel exactly; 1/sqrt(24) is not and stays on the fp32 scores.
+    Either way the result is the unfolded kernel's."""
+    from apex_tpu.ops import attention
+
+    assert attention._scale_folds(1.0 / d ** 0.5) == (d == 64)
+    q, k, v = _qkv(jax.random.PRNGKey(15), 1, 2, 64, 64, d, dtype)
+    assert attention._tile_plan(64, 64, d, dtype, True, 32,
+                                32).schedule == "resident"
+
+    def run(q, k, v):
+        def loss(q, k, v):
+            o = flash_attention(q, k, v, causal=True, use_pallas=True,
+                                block_q=32, block_k=32)
+            return jnp.sum(jnp.sin(o.astype(jnp.float32))), o
+        return jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+
+    g1, o1 = run(q, k, v)
+    monkeypatch.setattr(attention, "_scale_folds", lambda scale: False)
+    g2, o2 = run(q, k, v)
+    f32 = lambda x: np.asarray(x, np.float32)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(f32(o1), f32(o2), atol=2e-5)
+        for a, e, name in zip(g1, g2, "qkv"):
+            np.testing.assert_allclose(f32(a), f32(e), atol=1e-4,
+                                       err_msg=name)
+    else:
+        # bf16 operands: the fold is exact, so not one bit moves forward
+        np.testing.assert_array_equal(f32(o1), f32(o2))
+        for a, e, name in zip(g1, g2, "qkv"):
+            np.testing.assert_allclose(f32(a), f32(e), atol=3e-2,
+                                       err_msg=name)
+
+
+# (sq, sk, causal, bound, has_bias) -> the plan; d 64, bf16 throughout
+_PLANS = {
+    "cells": ((1024, 1024, True, 512, False),
+              ("resident", 512, 512, 3, 2, 4, 3)),
+    "s2048": ((2048, 2048, True, 512, False),
+              ("streamed", 512, 512, 10, 10, 16, 1)),
+    "s8192": ((8192, 8192, True, 512, False),
+              ("streamed", 512, 512, 136, 136, 256, 1)),
+    "biased": ((1024, 1024, True, 512, True),
+               ("streamed", 512, 512, 3, 3, 4, 1)),
+    "noncausal": ((1024, 1024, False, 512, False),
+                  ("resident", 512, 512, 4, 0, 4, 4)),
+    "noncausal_s1536": ((1536, 1536, False, 512, False),
+                        ("streamed", 512, 512, 9, 0, 9, 1)),
+    "causal_s1536": ((1536, 1536, True, 512, False),
+                     ("streamed", 512, 512, 6, 6, 9, 1)),
+    "one_tile": ((512, 512, True, 512, False),
+                 ("resident", 512, 512, 1, 1, 1, 1)),
+    "bound_256": ((1024, 1024, True, 256, False),
+                  ("streamed", 256, 256, 10, 10, 16, 1)),
+    "causal_rectangle": ((512, 1024, True, 512, False),
+                         ("streamed", 512, 512, 1, 1, 2, 1)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PLANS))
+def test_tile_plan_counts(case):
+    """The plan is the mechanism's counter: schedule, tile, tiles visited /
+    masked / in the rectangle per head, and unrolled bodies a kernel —
+    static per shape. The benchmark cells' call is resident at three bodies
+    and a non-causal 2 x 2 at four; longer heads, a bias, a call over the cap
+    and a causal rectangle stream."""
+    from apex_tpu.ops.attention import _tile_plan
+
+    (sq, sk, causal, bound, has_bias), want = _PLANS[case]
+    plan = _tile_plan(sq, sk, 64, jnp.bfloat16, causal, bound, bound,
+                      has_bias)
+    assert (*plan, plan.bodies) == want
+
+
+@pytest.mark.parametrize("cap,cells,noncausal", [
+    (2, "streamed", "streamed"), (3, "resident", "streamed"),
+    (4, "resident", "resident")])
+def test_tile_plan_holds_the_cap_on_unrolled_bodies(monkeypatch, cap, cells,
+                                                    noncausal):
+    """``_RESIDENT_MAX_BODIES`` is the program-size budget: a kernel that
+    would unroll more tile bodies streams, whatever its kernel time."""
+    from apex_tpu.ops import attention
+
+    assert attention._RESIDENT_MAX_BODIES == 4
+    monkeypatch.setattr(attention, "_RESIDENT_MAX_BODIES", cap)
+    plan = attention._tile_plan(1024, 1024, 64, jnp.bfloat16, True)
+    assert plan.schedule == cells and plan.bodies <= cap
+    plan = attention._tile_plan(1024, 1024, 64, jnp.bfloat16, False)
+    assert plan.schedule == noncausal and plan.bodies <= cap
+
+
+@pytest.mark.parametrize("d,dtype,block,want", [
+    (64, jnp.bfloat16, 512, "resident"),
+    (128, jnp.float32, 512, "resident"),
+    (256, jnp.float32, 512, "streamed"),    # the head outgrows VMEM
+    (64, jnp.bfloat16, 1024, "streamed"),   # the score tile does
+    (64, jnp.bfloat16, 8, "streamed"),      # off bf16's 16-row tiling
+])
+def test_tile_plan_vmem_budget_and_tiling(monkeypatch, d, dtype, block, want):
+    """With the cap out of the way: a head, or one tile's scores, that does
+    not fit the VMEM budget streams, and so does a tile that does not sit on
+    the dtype's sublane tiling (the kernel slices whole operands by it)."""
+    from apex_tpu.ops.attention import _tile_plan
+
+    _force_schedule(monkeypatch, "resident")
+    s = 1024 if block >= 512 else 16
+    assert _tile_plan(s, s, d, dtype, True, block, block).schedule == want

@@ -43,18 +43,17 @@ def qkv():
     return q, kk, v
 
 
-def test_flash_attention_fwd_bwd_causal(qkv):
+def _flash_sq_loss(q, k, v):
     from apex_tpu.ops.attention import flash_attention
 
-    q, k, v = qkv
+    o = flash_attention(q, k, v, causal=True, use_pallas=True,
+                        interpret=False)
+    return jnp.sum(o.astype(jnp.float32) ** 2)
 
-    def loss(q, k, v):
-        o = flash_attention(q, k, v, causal=True, use_pallas=True,
-                            interpret=False)
-        return jnp.sum(o.astype(jnp.float32) ** 2)
 
+def test_flash_attention_fwd_bwd_causal(qkv):
     with force_compiled():
-        _lower_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+        _lower_tpu(jax.grad(_flash_sq_loss, argnums=(0, 1, 2)), *qkv)
 
 
 def test_flash_attention_dropout(qkv):
@@ -99,6 +98,64 @@ def test_flash_attention_unequal_blocks(qkv):
 
     with force_compiled():
         _lower_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+
+
+@pytest.mark.parametrize("seq,schedule", [(S, "resident"),
+                                          (8192, "streamed")])
+def test_flash_attention_compiles_on_both_schedules(seq, schedule):
+    """The rows above only *lower* the benchmark cells' shape (s 1024, d 64,
+    bf16), which the tile plan runs resident: one whole head a grid step in
+    VMEM, three tile bodies unrolled inside each kernel. Here that shape and
+    one too long to hold (streamed: q and K/V tiles on the grid) go through
+    Mosaic's own compiler for a described v5e, where a VMEM overflow or an
+    unaligned slice of a resident operand would surface, and all three
+    kernels must be in the compiled program once, under the names the trace
+    and ``flash_attn_roofline`` read."""
+    from apex_tpu.ops._pallas_util import compile_for_tpu, mosaic_calls
+    from apex_tpu.ops.attention import _tile_plan
+
+    assert _tile_plan(seq, seq, D, jnp.bfloat16, True).schedule == schedule
+    q = jax.ShapeDtypeStruct((2, H, seq, D), jnp.bfloat16)
+    _, compiled = compile_for_tpu(
+        jax.jit(jax.grad(_flash_sq_loss, argnums=(0, 1, 2))), q, q, q)
+    # under grad the segment reads jvp(flash_fwd), transpose(jvp(...))
+    calls = mosaic_calls(compiled.as_text())
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert sum(n for name, n in calls.items() if kernel in name) == 1, (
+            kernel, calls)
+
+
+# Characters of the lowered text (StableHLO with the three kernels' Mosaic
+# payloads) of one ``jax.checkpoint``ed flash forward-and-backward at the
+# benchmark cells' shape (16 x 20 heads, s 1024, d 64, bf16, causal), at the
+# commit before the resident schedule (25a2ffd: streamed, one tile body a
+# kernel) and with it (three unrolled bodies a kernel). The checkout's path
+# is in the payloads' locations and moves either by a few hundred.
+_FLASH_LOWERED_CHARS_STREAMED = 26_047
+_FLASH_LOWERED_CHARS_RESIDENT = 22_947
+
+
+def test_flash_program_size_at_the_cells_shape():
+    """Every unrolled tile body is traced, lowered, hashed into the compile
+    cache's key and loaded at each warm set-up, and compiled at each cold
+    one: PR 26's 20 bodies a kernel cost ``setup_s`` 12% at gpt2-medium and
+    the PR with it. The program's size is countable here: the lowered text
+    may not pass 1.25 times the streamed program's, so a later PR that
+    unrolls more is told before a chip is."""
+    from apex_tpu.ops.attention import _RESIDENT_MAX_BODIES, _tile_plan
+
+    plan = _tile_plan(S, S, D, jnp.bfloat16, True)
+    assert plan.schedule == "resident"
+    assert plan.bodies == 3 <= _RESIDENT_MAX_BODIES
+    q = jax.ShapeDtypeStruct((16, 20, S, D), jnp.bfloat16)
+    with force_compiled():
+        text = _lower_tpu(
+            jax.grad(jax.checkpoint(_flash_sq_loss), argnums=(0, 1, 2)),
+            q, q, q).as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert len(text) <= 1.25 * _FLASH_LOWERED_CHARS_STREAMED, (
+        len(text), _FLASH_LOWERED_CHARS_STREAMED,
+        _FLASH_LOWERED_CHARS_RESIDENT)
 
 
 def test_varlen_fwd_bwd(qkv):
