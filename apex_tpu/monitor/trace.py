@@ -36,6 +36,28 @@ Device scopes of the train step (``transformer/testing/standalone_gpt.py``,
                                 parameters and write of the stacked
                                 gradients, once a layer (no user scope)
 
+Device scopes of the hybrid decoder's train step (``transformer/hybrid.py``:
+gated-delta-rule linear-attention layers among full ones; the same
+``bench.train_step_fn``, so ``opt`` and ``scan_carry`` as above)::
+
+    embed                       token embedding (rows of the vocabulary held)
+    layer                       one layer of a period, and inside it
+    layer/linattn/proj          q, k, v, gate, beta and decay projections
+    layer/linattn/conv          short causal convolutions, SiLU, q and k to
+                                unit length
+    layer/linattn/core          the gated delta rule, whatever implements it
+                                (``ops/delta_rule.py``: XLA fusions today)
+    layer/linattn/gate_norm     RMSNorm of a head's output times SiLU(gate)
+    layer/linattn/out           output projection
+    layer/attn/qkv  layer/attn/qk_norm  layer/attn/core  layer/attn/out
+                                the full layer: projections, QK-norm, the
+                                flash call and its layout changes, out
+    layer/mlp/gate_up  layer/mlp/act  layer/mlp/down      the gated FFN
+    layer/post_norm             the RMSNorm on a sublayer's output
+    layer/residual              the two residual adds
+    final_norm                  the RMSNorm after the stack
+    lm_head_loss                untied head + cross entropy, fused
+
 each under one phase: ``fwd``, ``recompute`` (the forward replayed under
 ``jax.checkpoint``), ``bwd``, or the first user scope where no
 differentiation wraps the operation (``opt``, ``comm``, ...).

@@ -383,6 +383,21 @@ _lm_head_loss.defvjp(_lm_fwd, _lm_bwd)
 DEFAULT_BLOCK_N = 1024
 DEFAULT_BLOCK_V = 512
 _MIN_BLOCK_N = 128
+# What the dx kernel's tile set may take of ``_VMEM_LIMIT_BYTES``, by the
+# blocks it keeps: x and dx double-buffered in the model's type and the fp32
+# accumulator (12 bytes an element of a (block_n, h) tile), W's tile double
+# buffered (4 bytes). 1024 rows hold up to a hidden of about 1,600; at 3,840
+# the widest row block is 256.
+_TILE_BUDGET_BYTES = 24 * 1024 * 1024
+
+
+def _widest_block_n(h: int, block_n: int, block_v: int) -> int:
+    """The widest row block <= ``block_n`` (halving, not under the 128-row
+    floor) whose dx tile set fits the budget at hidden ``h``."""
+    while (block_n > _MIN_BLOCK_N
+           and 12 * block_n * h + 4 * block_v * h > _TILE_BUDGET_BYTES):
+        block_n //= 2
+    return block_n
 
 
 def _resolve_block_n(n: int, block_n: int) -> Optional[int]:
@@ -429,7 +444,7 @@ def lm_head_loss(
     x2 = x.reshape(-1, h)
     t1 = targets.reshape(-1)
     n = x2.shape[0]
-    bn = _resolve_block_n(n, block_n)
+    bn = _resolve_block_n(n, _widest_block_n(h, block_n, block_v))
     fits = bn is not None and h % 128 == 0
     if use_pallas is None:
         use_pallas = fits and _compiled_backend()

@@ -204,6 +204,16 @@ class GPTConfig:
                          capacity_factor=self.moe_capacity_factor,
                          dtype=self.dtype)
 
+    # -- the protocol ``bench.train_step_fn`` takes a model by ----------------
+    def param_specs(self) -> Pytree:
+        return gpt_param_specs(self)
+
+    def init_params(self, rng) -> Pytree:
+        return init_gpt_params(rng, self)
+
+    def loss(self, params, tokens, targets):
+        return gpt_loss(params, tokens, targets, self)
+
 
 # ---------------------------------------------------------------------------
 # init (global shapes)

@@ -45,20 +45,25 @@ def flagship_config(seq: int = SEQ, **overrides):
 
 def train_step_fn(cfg, mesh):
     """The jitted fwd+bwd+FusedAdam step of ``cfg`` over ``mesh`` (params
-    and optimizer state donated), plus the optimizer it steps."""
+    and optimizer state donated), plus the optimizer it steps.
+
+    ``cfg`` is any model's config that gives ``param_specs()`` (a
+    ``PartitionSpec`` a leaf), ``init_params(rng)`` and ``loss(params, tokens,
+    targets)`` (the local mean loss inside ``shard_map``): ``GPTConfig`` and
+    ``transformer.hybrid.HybridConfig`` both do, and both run this one
+    step."""
     from apex_tpu.monitor.trace import register_program, span
     from apex_tpu.optimizers import FusedAdam
     from apex_tpu.transformer.pipeline_parallel.schedules.common import (
         replicate_loss,
     )
-    from apex_tpu.transformer.testing import gpt_loss, gpt_param_specs
 
-    specs = gpt_param_specs(cfg)
+    specs = cfg.param_specs()
     opt = FusedAdam(lr=1e-4)
 
     def loss_fn(p, tok, tgt):
         def body(p, tok, tgt):
-            return replicate_loss(gpt_loss(p, tok, tgt, cfg), mesh,
+            return replicate_loss(cfg.loss(p, tok, tgt), mesh,
                                   masked_axis=None)
 
         return jax.shard_map(body, mesh=mesh,
@@ -95,15 +100,13 @@ def train_step_fn(cfg, mesh):
 def abstract_train_args(cfg, opt, mesh, rows: int, seq: int):
     """``(params, opt_state, tok, tgt)`` as ``ShapeDtypeStruct``s placed as
     :func:`build_train_step` places the real ones: no array is made."""
-    from apex_tpu.transformer.testing import gpt_param_specs, init_gpt_params
-
     def placed(a, spec):
         return jax.ShapeDtypeStruct(a.shape, a.dtype,
                                     sharding=NamedSharding(mesh, spec))
 
-    specs = gpt_param_specs(cfg)
+    specs = cfg.param_specs()
     params = jax.tree.map(placed, jax.eval_shape(
-        lambda: init_gpt_params(jax.random.PRNGKey(0), cfg)), specs)
+        lambda: cfg.init_params(jax.random.PRNGKey(0))), specs)
     state = jax.eval_shape(opt.init, params)
     state = state._replace(count=placed(state.count, P()),
                            mu=jax.tree.map(placed, state.mu, specs),

@@ -340,6 +340,69 @@ def test_lm_head_loss():
         _lower_tpu(jax.grad(loss, argnums=(0, 1)), x, w)
 
 
+def test_lm_head_loss_row_block_follows_the_hidden_width():
+    """The row block is chosen from what the call can observe: the GPT-2
+    cells' widths keep 1,024 rows a tile (their compiled step is the
+    parent's), and a hidden of 3,840 takes 256, where 1,024 asked Mosaic for
+    48 MiB of scoped VMEM against a limit of 32."""
+    from apex_tpu.ops.lm_head_loss import DEFAULT_BLOCK_V, _widest_block_n
+
+    for hidden in (768, 1024, 1280, 1600):
+        assert _widest_block_n(hidden, 1024, DEFAULT_BLOCK_V) == 1024
+    assert _widest_block_n(3840, 1024, DEFAULT_BLOCK_V) == 256
+    assert _widest_block_n(3840, 128, DEFAULT_BLOCK_V) == 128
+
+
+def test_hybrid_cell_kernels_compile_at_its_widths():
+    """The hybrid decoder's cell (hidden 3,840, 12,544 rows of the
+    vocabulary, 2 x 8,192 tokens) through Mosaic's own compiler for a
+    described v5e: the fused head's three kernels and the RMSNorm pair at the
+    third width, where a VMEM overflow would surface."""
+    from apex_tpu.ops._pallas_util import compile_for_tpu, mosaic_calls
+    from apex_tpu.ops.layer_norm import rms_norm
+    from apex_tpu.ops.lm_head_loss import lm_head_loss
+
+    n, h, vocab = 16384, 3840, 12544
+
+    def loss(x, w, nw, t):
+        return jnp.sum(lm_head_loss(rms_norm(x, nw, 1e-6), w, t))
+
+    x = jax.ShapeDtypeStruct((n, h), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((vocab, h), jnp.bfloat16)
+    nw = jax.ShapeDtypeStruct((h,), jnp.bfloat16)
+    t = jax.ShapeDtypeStruct((n,), jnp.int32)
+    _, compiled = compile_for_tpu(jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+                                  x, w, nw, t)
+    calls = mosaic_calls(compiled.as_text())
+    for kernel in ("lm_head_fwd", "lm_head_bwd_dx", "lm_head_bwd_dw",
+                   "rms_norm_fwd", "rms_norm_bwd"):
+        assert sum(c for name, c in calls.items() if kernel in name) == 1, (
+            kernel, calls)
+
+
+def test_delta_rule_core_compiles_in_blocks_at_the_cells_shape():
+    """The cell's delta rule (2 x 8,192 tokens, 30 heads, d_k 96, d_v 192,
+    chunk 64), forward and backward, for a described v5e: the triangular
+    solve, the scan over chunks and their transposes are XLA's, and with the
+    call cut into six blocks of a row and ten heads its temporaries stay
+    under 3 GiB (all 2 x 30 heads at once asked for some 8 GiB and the step
+    for 22.5 GiB)."""
+    from apex_tpu.ops._pallas_util import compile_for_tpu
+    from apex_tpu.ops.delta_rule import _block_plan, gated_delta_rule
+
+    assert _block_plan(2, 8192, 30, 96, 192, 64) == (1, 10)
+
+    def loss(q, k, v, g, beta):
+        o = gated_delta_rule(q, k, v, g, beta, chunk=64)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    sds = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype)
+    args = (sds(2, 8192, 30, 96), sds(2, 8192, 30, 96), sds(2, 8192, 30, 192),
+            sds(2, 8192, 30, dtype=jnp.float32), sds(2, 8192, 30, dtype=jnp.float32))
+    _, compiled = compile_for_tpu(jax.jit(jax.grad(loss, argnums=range(5))), *args)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+
+
 @pytest.mark.parametrize("quantized", [False, True])
 def test_paged_attention_kernel_lowers_for_tpu(quantized):
     """AOT TPU lowering of the serve gather-attend kernel: scalar-prefetch
