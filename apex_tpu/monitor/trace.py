@@ -46,7 +46,10 @@ gated-delta-rule linear-attention layers among full ones; the same
     layer/linattn/conv          short causal convolutions, SiLU, q and k to
                                 unit length
     layer/linattn/core          the gated delta rule, whatever implements it
-                                (``ops/delta_rule.py``: XLA fusions today)
+                                (``ops/delta_rule.py``: the decay's sum and
+                                what XLA runs round the kernels here, the
+                                kernels below it as ``.../delta_rule_fwd``,
+                                ``..._bwd``)
     layer/linattn/gate_norm     RMSNorm of a head's output times SiLU(gate)
     layer/linattn/out           output projection
     layer/attn/qkv  layer/attn/qk_norm  layer/attn/core  layer/attn/out

@@ -5,7 +5,7 @@ rows execute the COMPILED kernels on the chip and compare each with its
 reference. ``chip_smoke.py`` imports the rows of the kernels on the main
 path (``MAIN_PATH``: flash causal fwd+bwd, fused LM-head+CE at vocab 50304,
 LayerNorm at 768, paged attention fp and int8, the fused decode block, the
-Adam tail); this script runs all of them:
+Adam tail, the gated delta rule); this script runs all of them:
 
     python benchmarks/smoke_tpu.py [--out smoke.json]     # on the chip
     python benchmarks/smoke_tpu.py --cpu-rehearsal        # tiny, interpret
@@ -357,6 +357,52 @@ def softmax_xent(small: bool) -> float:
     return max(e1, float(jnp.abs(l1 - l2)))
 
 
+def delta_rule(small: bool) -> float:
+    """The gated delta rule's kernels (``ops.delta_rule._kernels``, called
+    as such so that no dispatch can hand the row XLA's form) at the hybrid
+    cell's head sizes (d_k 96, d_v 192, chunk 64), forward and the five
+    gradients, where the write strength passes 1 and where the decay is
+    strong. float32 q, k, v, so that what is compared is the float32 the
+    configuration states for the core and not bfloat16's rounding of ``o``.
+    Two comparisons, each the worst entry's error over a limit, at most 1:
+
+    * against the recurrence token by token, with the CPU tests' limits
+      (``tests/test_delta_rule.py``: forward 2e-6 + 2e-5 |want|, a gradient
+      2e-5 x its largest entry + 1e-4 |want|) at those tests' 128 tokens.
+      Most of what is read here is the distance between the chip's
+      recurrence and any chunked form: XLA's reads 0.72, and 1.0 over 256;
+    * against XLA's chunked form (the same arithmetic, so the tight one) at a
+      quarter of those limits, over 640 tokens: five pairs of chunks, the state
+      carried from one to the next."""
+    from apex_tpu.ops import delta_rule as dr
+
+    b, h, dk, dv, chunk = (1, 2, 16, 32, 16) if small else (1, 2, 96, 192, 64)
+    kernels = lambda *a: dr._kernels(*a, chunk, interpret=small)
+    worst = 0.0
+    for shift_beta, shift_g in ((2.0, -3.0), (0.0, 4.0)):    # beta_above_1, strong_decay
+        for t, reference, tighter in (
+                (2 * chunk, dr.gated_delta_rule_reference, 1.0),
+                (10 * chunk, lambda *a: dr._chunked(*a, chunk), 0.25)):
+            ks = jax.random.split(jax.random.fold_in(KEY, 29), 6)
+            q = dr.l2_normalize(jax.random.normal(ks[0], (b, t, h, dk))) * dk ** -0.5
+            k = dr.l2_normalize(jax.random.normal(ks[1], (b, t, h, dk)))
+            v = jax.random.normal(ks[2], (b, t, h, dv))
+            beta = 2.0 * jax.nn.sigmoid(jax.random.normal(ks[3], (b, t, h)) + shift_beta)
+            g = -jax.nn.softplus(jax.random.normal(ks[4], (b, t, h)) + shift_g)
+            w = jax.random.normal(ks[5], (b, t, h, dv))
+            out = lambda fn: lambda *a: (fn(*a), jax.grad(
+                lambda *b: jnp.sum(w * fn(*b)), argnums=range(5))(*a))
+            assert dr._kernels_take(q, k, v, chunk)
+            got, got_g = jax.jit(out(kernels))(q, k, v, g, beta)
+            want, want_g = _highest(out(reference), q, k, v, g, beta)
+            over = lambda a, b, atol, rtol: float(jnp.max(
+                jnp.abs(a - b) / (tighter * (atol + rtol * jnp.abs(b)))))
+            worst = max([worst, over(got, want, 2e-6, 2e-5)] + [
+                over(a, b, 2e-5 * float(jnp.max(jnp.abs(b))), 1e-4)
+                for a, b in zip(got_g, want_g)])
+    return worst
+
+
 # ---------------------------------------------------------------------------
 
 
@@ -378,6 +424,8 @@ ROWS: Dict[str, Row] = {
     "paged_attention_int8": Row(paged_row(True), 2e-2),
     "fused_decode_block": Row(fused_decode, 3e-2),
     "fused_adam_tail": Row(adam_tail, 1e-5),
+    # the worst error over its limit, not an error: at most 1
+    "gated_delta_rule_fwd_bwd": Row(delta_rule, 1.0),
     "flash_attention_inkernel_dropout": Row(flash_dropout_determinism, 0.0,
                                             zero_is_fallback=False),
     "flash_attention_dropout_global_offsets": Row(
@@ -389,10 +437,11 @@ ROWS: Dict[str, Row] = {
                                        zero_is_fallback=False),
 }
 
-# the kernels the GPT-2 train step and the paged serve engine run
+# the kernels the GPT-2 and hybrid train steps and the paged serve engine run
 MAIN_PATH = ("flash_attention_fwd_bwd_causal", "fused_lm_head_cross_entropy",
              "pallas_layer_norm_h768", "paged_attention_fp",
-             "paged_attention_int8", "fused_decode_block", "fused_adam_tail")
+             "paged_attention_int8", "fused_decode_block", "fused_adam_tail",
+             "gated_delta_rule_fwd_bwd")
 
 
 def run_row(name: str, small: bool = False) -> dict:

@@ -1,6 +1,8 @@
 """The gated delta rule in chunked form (``ops/delta_rule.py``) against the
 recurrence token by token, forward and gradients, where the write strength
-passes 1 and where the decay is strong; and the two small ops beside it."""
+passes 1 and where the decay is strong: XLA's chunked form on the path the CPU
+takes, the Pallas kernels through the interpreter; and the two small ops
+beside it."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,7 +20,7 @@ from apex_tpu.ops.delta_rule import (
 B, T, H, DK, DV = 2, 128, 2, 16, 32
 
 
-def _inputs(regime: str, seed: int = 0):
+def _inputs(regime: str, seed: int = 0, T: int = T):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     q = l2_normalize(jax.random.normal(ks[0], (B, T, H, DK))) * DK ** -0.5
     k = l2_normalize(jax.random.normal(ks[1], (B, T, H, DK)))
@@ -91,36 +93,129 @@ def test_gated_rms_norm_is_the_norm_times_the_weight_times_silu_of_the_gate():
     np.testing.assert_allclose(np.asarray(got, np.float64), want, atol=0.02, rtol=0.01)
 
 
-_PER_HEAD = 8 * T * (6 * DK + 5 * DV + 3 * 16 + DK * DV // 16 + 2)    # bytes, chunk 16
+def _kernels(chunk):
+    return lambda *a: delta_rule._kernels(*a, chunk, interpret=True)
 
 
-@pytest.mark.parametrize("budget, plan", [(1 << 40, (B, H)), (3 * _PER_HEAD, (1, H)),
-                                          (1, (1, 1))])
-def test_a_block_of_rows_and_heads_at_a_time_gives_the_same_output_and_gradients(
-        monkeypatch, budget, plan):
-    """Whatever the byte budget cuts the call into, the result is that of all
-    rows and heads at once (``_chunked``)."""
-    args = _inputs("beta_above_1", seed=2)
+@pytest.mark.parametrize("chunk", [16, 64])
+@pytest.mark.parametrize("regime", ["beta_above_1", "strong_decay"])
+def test_kernels_forward_equals_the_recurrence(regime, chunk):
+    args = _inputs(regime)
+    want = gated_delta_rule_reference(*args)
+    got = _kernels(chunk)(*args)
+    assert got.shape == (B, T, H, DV) and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-5)
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+@pytest.mark.parametrize("regime", ["beta_above_1", "strong_decay"])
+def test_kernels_gradients_equal_the_recurrences(regime, chunk):
+    """The backward kernel's own cotangents (nothing is differentiated
+    through) against autodiff of the recurrence."""
+    args = _inputs(regime, seed=1)
     w = jax.random.normal(jax.random.PRNGKey(9), (B, T, H, DV))
-    monkeypatch.setattr(delta_rule, "_BLOCK_BYTES", budget)
-    assert delta_rule._block_plan(B, T, H, DK, DV, 16) == plan
-    run = lambda fn: jax.value_and_grad(
-        lambda *a: jnp.sum(w * fn(*a, 16)), argnums=range(5))(*args)
-    (want, want_g), (got, got_g) = run(delta_rule._chunked), run(gated_delta_rule)
-    np.testing.assert_allclose(got, want, rtol=1e-6)
-    for a, b in zip(got_g, want_g):
-        np.testing.assert_allclose(a, b, atol=1e-6 * float(jnp.max(jnp.abs(b))), rtol=1e-5)
+    loss = lambda fn: lambda *a: jnp.sum(w * fn(*a))
+    want = jax.grad(loss(gated_delta_rule_reference), argnums=range(5))(*args)
+    got = jax.grad(loss(_kernels(chunk)), argnums=range(5))(*args)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(a, b, atol=2e-5 * scale, rtol=1e-4, err_msg=name)
 
 
-@pytest.mark.parametrize("shape, plan", [
-    ((2, 8192, 30, 96, 192, 64), (1, 10)),      # the hybrid cell: 132 MB a head
-    ((16, 1024, 30, 96, 192, 64), (2, 30)),     # short rows: whole rows, two at a time
-    ((1, 65536, 30, 96, 192, 64), (1, 1)),      # the extended context: a head at a time
-    ((3, 8192, 7, 96, 192, 64), (1, 7)),        # divisors only: 7 heads fit, 2 rows do not
+def test_kernels_at_the_cells_head_sizes_in_bfloat16():
+    """d_k 96, d_v 192, chunk 64, q, k, v in bfloat16 widened inside, three
+    blocks of chunks a head: ``o`` and the cotangents of q, k, v come back on
+    bfloat16's grid (one step of it is the limit there); those of g and beta
+    are float32 and hold the float32 limits."""
+    b, t, h, dk, dv = 1, 24 * 64, 2, 96, 192
+    ks = jax.random.split(jax.random.PRNGKey(3), 6)
+    bf = lambda x: x.astype(jnp.bfloat16)
+    q = bf(l2_normalize(jax.random.normal(ks[0], (b, t, h, dk))) * dk ** -0.5)
+    k = bf(l2_normalize(jax.random.normal(ks[1], (b, t, h, dk))))
+    v = bf(jax.random.normal(ks[2], (b, t, h, dv)))
+    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(ks[3], (b, t, h)) + 1.0)
+    g = -jax.nn.softplus(jax.random.normal(ks[4], (b, t, h)) - 3.0)
+    w = bf(jax.random.normal(ks[5], (b, t, h, dv))).astype(jnp.float32)
+    assert delta_rule._kernels_take(q, k, v, 64)
+    run = lambda fn: jax.value_and_grad(lambda *a: jnp.sum(w * fn(*a)), argnums=range(5))
+    out = _kernels(64)(q, k, v, g, beta)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(out.astype(jnp.float32),
+                               gated_delta_rule_reference(q, k, v, g, beta),
+                               atol=2e-3, rtol=2 ** -8)
+    _, got = run(lambda *a: _kernels(64)(*a).astype(jnp.float32))(q, k, v, g, beta)
+    _, want = run(gated_delta_rule_reference)(q, k, v, g, beta)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        scale = float(jnp.max(jnp.abs(b)))
+        if a.dtype == jnp.bfloat16:
+            np.testing.assert_allclose(a.astype(jnp.float32), b.astype(jnp.float32),
+                                       atol=2 ** -8 * scale, rtol=2 ** -7, err_msg=name)
+        else:
+            np.testing.assert_allclose(a, b, atol=2e-5 * scale, rtol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("chunk, tokens, dtype", [
+    (64, 128, "float32"),       # the cell's form: a pair of chunks a tile
+    (64, 192, "float32"),       # an odd count: the last pair's second chunk is padding
+    (64, 64, "float32"),        # one chunk: half its tile is padding
+    (128, 256, "float32"),      # a chunk that fills the tile alone
+    (32, 96, "float32"),        # four chunks a tile, three of them tokens
+    (16, 128, "float32"),       # eight chunks a tile
+    (32, 128, "bfloat16"),
+    (64, 2048, "float32"),      # two grid steps of eight spans: the state carried across
+    (64, 1152, "float32"),      # nine spans: two grid steps of five, one span of padding
 ])
-def test_the_block_plan_follows_the_shapes_and_the_byte_budget(shape, plan):
-    assert delta_rule._block_plan(*shape) == plan
-    rows, heads = plan
-    b, t, h, dk, dv, chunk = shape
-    per_head = 8 * t * (6 * dk + 5 * dv + 3 * chunk + dk * dv // chunk + 2)
-    assert rows * heads * per_head <= delta_rule._BLOCK_BYTES or (rows, heads) == (1, 1)
+def test_the_kernels_and_the_chunked_form_in_xla_agree(chunk, tokens, dtype):
+    """The two forms of one arithmetic, output and gradients, at every shape
+    of tile the dispatch accepts (one chunk a tile, two, four, eight),
+    on sequences padded to whole tiles and whole grid steps."""
+    args = _inputs("beta_above_1", seed=2, T=tokens)
+    args = tuple(a.astype(dtype) for a in args[:3]) + args[3:]
+    assert delta_rule._kernels_take(*args[:3], chunk)
+    w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape).astype(dtype)
+
+    def run(fn):    # o, and the gradients of sum(w o)
+        o, vjp = jax.vjp(fn, *args)
+        return (o,) + vjp(w)
+
+    want, got = run(lambda *a: delta_rule._chunked(*a, chunk)), run(_kernels(chunk))
+    # bfloat16: both forms round float32 results once, a unit apart at most
+    rtol = 1e-5 if dtype == "float32" else 2 ** -7
+    for a, b in zip(got, want):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        np.testing.assert_allclose(a, b, atol=rtol * float(jnp.max(jnp.abs(b))), rtol=rtol)
+
+
+def test_the_plan_pads_a_sequence_instead_of_refusing_it():
+    """(chunks a span, spans a grid step, grid steps): the cell's 8,192 tokens
+    go into eight steps of eight pairs with nothing added; other lengths get
+    the fewest steps of at most eight spans, dealt evenly."""
+    assert delta_rule._plan(8192, 64) == (2, 8, 8)
+    assert delta_rule._plan(2560, 64) == (2, 7, 3)      # 20 pairs: 21 with one of padding
+    assert delta_rule._plan(3584, 64) == (2, 7, 4)      # 28 pairs: none
+    assert delta_rule._plan(8704, 64) == (2, 8, 9)      # 68 pairs: 72
+    assert delta_rule._plan(8256, 64) == (2, 8, 9)      # 129 chunks: 65 pairs
+    assert delta_rule._plan(64, 64) == (2, 1, 1)        # a tile is 128 tokens wide
+    assert delta_rule._plan(1024, 128) == (1, 8, 1)
+    assert delta_rule._plan(2048, 16) == (8, 8, 2)      # 1,024 tokens a step at most
+
+
+def test_off_the_chip_the_call_takes_the_chunked_form_in_xla():
+    """No argument chooses: the CPU has no compiled backend, so the public
+    entry point traces no kernel; and what the kernels tile is read from the
+    call (a chunk of whole sublane tiles of the operands' type, head sizes of
+    whole tiles, one type), never from its length."""
+    args = _inputs("beta_above_1")
+    assert "pallas_call" not in str(jax.make_jaxpr(gated_delta_rule)(*args))
+    assert "pallas_call" in str(jax.make_jaxpr(_kernels(64))(*args))
+    q, k, v = args[:3]
+    bf = lambda x: x.astype(jnp.bfloat16)
+    for chunk in (16, 32, 64, 128):
+        assert delta_rule._kernels_take(q, k, v, chunk)
+        assert delta_rule._kernels_take(bf(q), bf(k), bf(v), chunk)
+    for chunk in (8, 24, 48, 256):      # no whole number of them is a tile
+        assert not delta_rule._kernels_take(q, k, v, chunk)
+    assert not delta_rule._kernels_take(q[..., :12], k[..., :12], v, 16)
+    assert not delta_rule._kernels_take(bf(q)[..., :8], bf(k)[..., :8], bf(v), 16)
+    assert not delta_rule._kernels_take(q, k, bf(v), 16)

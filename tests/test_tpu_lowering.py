@@ -380,27 +380,90 @@ def test_hybrid_cell_kernels_compile_at_its_widths():
             kernel, calls)
 
 
-def test_delta_rule_core_compiles_in_blocks_at_the_cells_shape():
-    """The cell's delta rule (2 x 8,192 tokens, 30 heads, d_k 96, d_v 192,
-    chunk 64), forward and backward, for a described v5e: the triangular
-    solve, the scan over chunks and their transposes are XLA's, and with the
-    call cut into six blocks of a row and ten heads its temporaries stay
-    under 3 GiB (all 2 x 30 heads at once asked for some 8 GiB and the step
-    for 22.5 GiB)."""
-    from apex_tpu.ops._pallas_util import compile_for_tpu
-    from apex_tpu.ops.delta_rule import _block_plan, gated_delta_rule
+def _delta_rule_args(b=2, t=8192, h=30, dk=96, dv=192, dtype=jnp.bfloat16):
+    sds = lambda *shape, dtype=dtype: jax.ShapeDtypeStruct(shape, dtype)
+    return (sds(b, t, h, dk), sds(b, t, h, dk), sds(b, t, h, dv),
+            sds(b, t, h, dtype=jnp.float32), sds(b, t, h, dtype=jnp.float32))
 
-    assert _block_plan(2, 8192, 30, 96, 192, 64) == (1, 10)
+
+def _delta_rule_sq_loss(chunk, devices):
+    """The squared output of the delta rule as the train step calls it: in a
+    fully manual ``shard_map`` (over one device here), which is where a
+    Mosaic kernel can be placed (``mosaic_placeable``)."""
+    from jax.sharding import Mesh
+    from jax.sharding import PartitionSpec as P
+
+    from apex_tpu.ops.delta_rule import gated_delta_rule
 
     def loss(q, k, v, g, beta):
-        o = gated_delta_rule(q, k, v, g, beta, chunk=64)
+        o = gated_delta_rule(q, k, v, g, beta, chunk=chunk)
         return jnp.sum(o.astype(jnp.float32) ** 2)
 
-    sds = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype)
-    args = (sds(2, 8192, 30, 96), sds(2, 8192, 30, 96), sds(2, 8192, 30, 192),
-            sds(2, 8192, 30, dtype=jnp.float32), sds(2, 8192, 30, dtype=jnp.float32))
-    _, compiled = compile_for_tpu(jax.jit(jax.grad(loss, argnums=range(5))), *args)
-    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+    return jax.shard_map(loss, mesh=Mesh(list(devices)[:1], ("dp",)),
+                         in_specs=P(), out_specs=P())
+
+
+def test_delta_rule_kernels_compile_at_the_cells_shape():
+    """The cell's delta rule (2 x 8,192 tokens, 30 heads, d_k 96, d_v 192,
+    chunk 64), forward and backward, through Mosaic's own compiler for a
+    described v5e: both kernels are in the compiled program once, under the
+    names the scope table and the ledger's ``device_ops`` read, and what the
+    forward leaves for the backward (a state a grid step, an inverse a pair
+    of chunks) is all the temporaries there are, 0.34 GiB: the operands are
+    read with time last, which is how they arrive, so nothing is copied or
+    padded (XLA's chunked form a row and ten heads at a time asked for under
+    3 GiB, all heads at once for some 8)."""
+    from apex_tpu.ops._pallas_util import compile_for_tpu, mosaic_calls, tpu_topology_devices
+
+    loss = _delta_rule_sq_loss(64, tpu_topology_devices())
+    _, compiled = compile_for_tpu(jax.jit(jax.grad(loss, argnums=range(5))), *_delta_rule_args())
+    calls = mosaic_calls(compiled.as_text())
+    for kernel in ("delta_rule_fwd", "delta_rule_bwd"):
+        assert sum(n for name, n in calls.items() if kernel in name) == 1, (kernel, calls)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
+
+
+# Characters of the lowered text (StableHLO with the two kernels' Mosaic
+# payloads) of one ``jax.checkpoint``ed delta rule, forward and backward, at
+# the cell's shape, as this file's flash case counts them: chunks are a grid
+# axis and a ``fori_loop``, heads a grid axis, and what is written out twice
+# is a pair of chunks' products with the state. The flash kernels' three
+# bodies a kernel stand at 22,947.
+_DELTA_RULE_LOWERED_CHARS = 42_732
+
+
+def test_delta_rule_program_size_at_the_cells_shape():
+    """The step traces every kernel several times and a warm set-up lowers
+    and hashes the payloads again (PR 26's lesson): the lowered text may not
+    pass 1.25 times what it is today, so a later PR that unrolls the chunks
+    or the heads is told before a chip is."""
+    with force_compiled():
+        text = _lower_tpu(
+            jax.grad(jax.checkpoint(_delta_rule_sq_loss(64, jax.devices())), argnums=range(5)),
+            *_delta_rule_args()).as_text()
+    assert text.count("tpu_custom_call") == 2       # the forward replayed, the backward
+    assert len(text) <= 1.25 * _DELTA_RULE_LOWERED_CHARS, (
+        len(text), _DELTA_RULE_LOWERED_CHARS)
+
+
+def test_delta_rule_takes_the_chunked_form_in_xla_at_a_shape_the_kernels_refuse():
+    """No whole number of chunks of 24 tokens is a tile of 128: nothing
+    chooses but the shapes, the call runs as XLA's triangular solve and scan,
+    and the compiled program holds no ``tpu_custom_call``. A length is no
+    reason to refuse: 2,560 tokens are 20 pairs of chunks, three grid steps
+    of seven with one pair of padding, and the kernels take them."""
+    from apex_tpu.ops._pallas_util import compile_for_tpu, mosaic_calls, tpu_topology_devices
+
+    args = _delta_rule_args(b=1, t=384, h=2)
+    grad = lambda chunk: jax.jit(jax.grad(
+        _delta_rule_sq_loss(chunk, tpu_topology_devices()), argnums=range(5)))
+    _, compiled = compile_for_tpu(grad(24), *args)
+    assert not mosaic_calls(compiled.as_text())
+    assert "tpu_custom_call" not in compiled.as_text()
+    _, compiled = compile_for_tpu(grad(16), *args)       # eight chunks a tile: the kernels
+    assert len(mosaic_calls(compiled.as_text())) == 2
+    _, compiled = compile_for_tpu(grad(64), *_delta_rule_args(b=1, t=2560, h=2))
+    assert len(mosaic_calls(compiled.as_text())) == 2
 
 
 @pytest.mark.parametrize("quantized", [False, True])
