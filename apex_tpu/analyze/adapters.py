@@ -21,9 +21,7 @@ caches and ``compile_counts`` are untouched — and require every leaf of
 the KV cache AND the adapter pool (donate argnums 1 and 2) to appear in
 the executable's ``input_output_alias`` map.
 
-Wired into the stage-16/graph-lint CI surface via
-``benchmarks/analyze_contracts.py`` (the ``adapter_donation_ok`` record
-field) and pinned by tier-1 tests.
+Pinned by tier-1 tests (``tests/test_analyze.py``).
 """
 
 from __future__ import annotations

@@ -61,9 +61,8 @@ collective's ring cost exactly. One deliberate exception program-wide:
 Megatron-SP backward recipe — shard-sized residuals instead of storing
 the gathered activation), so under full-remat training, which ALSO
 replays the forward ring, the program pays one extra input gather per
-column layer (~10% on the flagship; ``benchmarks/bench_overlap.py``
-reports both totals) — bytes traded for activation memory, and hops that
-all travel behind GEMMs regardless.
+column layer (~10% more wire bytes on the flagship) — bytes traded for
+activation memory, and hops that all travel behind GEMMs regardless.
 
 ``matmul_param_gather(x, w_shard)``
     ``x @ all_gather(w_shard, axis=-1)`` — the same decomposition in **FSDP
@@ -556,4 +555,8 @@ def matmul_param_gather(x, w_shard, *, axis_name: str = DP_AXIS,
     vs the monolithic gather + reduce-scatter pair
     (:func:`matmul_param_gather_wire_bytes`). Same ``pvary_like``/mesh
     contract as :func:`all_gather_matmul`."""
+    # a weight split over more axes than the activations vary on (its
+    # columns over tp, x replicated there): dX is then the sum of every
+    # such rank's partial, which is this cast's transpose
+    x = _pvary_like(x, w_shard)
     return _matmul_param_gather(x, w_shard, axis_name, bool(bidirectional))

@@ -29,9 +29,7 @@ the step:
   shared one-JSON-line convention every bench prints.
 * :mod:`~apex_tpu.monitor.report` — :func:`step_report`, the measured-time
   × HLO-flops × bytes-on-wire join (MFU, ICI bandwidth, per-phase ms);
-  :func:`mfu_check` / :func:`hlo_stats` compile-only variants
-  (``benchmarks/profile_step.py`` and ``check_mfu_accounting.py`` are thin
-  wrappers over these).
+  :func:`mfu_check` / :func:`hlo_stats` compile-only variants.
 
 Tier 2 (the serving side — request-level attribution, not step averages):
 

@@ -357,7 +357,7 @@ class FleetScraper:
     the view, drags ``scrape_coverage`` below 1.0, and its name lands in
     ``view.missed`` (what a heartbeat-absence rule reads). Each pull is
     wall-timed into the ``scrape_ms`` histogram — the observability
-    plane measures itself, and ``bench_observe.py`` gates the cost."""
+    plane measures itself."""
 
     def __init__(self, targets: Callable[[], List[Tuple[str, Callable]]],
                  clock: Optional[Callable[[], float]] = None):

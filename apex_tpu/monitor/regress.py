@@ -92,8 +92,8 @@ _LOWER = ("_ms", "violation", "latency", "bubble", "exposed_bytes",
           # per-chip param HBM, peak HBM or FSDP bytes-on-wire is a
           # regression (hidden_fraction, the overlap headline, is in
           # _HIGHER; wire_bytes_fsdp only — the generic "wire_bytes"
-          # fragment would also gate baseline-side columns like
-          # bench_overlap's wire_bytes_off, where only the ratio matters)
+          # fragment would also gate baseline-side columns such as
+          # wire_bytes_off, where only the ratio matters)
           "hbm_params_bytes", "peak_hbm_bytes", "wire_bytes_fsdp",
           # analyze round (stage 16): the contract-checker record fields —
           # growing exposed collective traffic (exposed_bytes above),

@@ -11,7 +11,7 @@ previously joined ad hoc by every consumer):
   on-wire, not in Python);
 * analytic / XLA-cost-model FLOPs — the MFU denominator,
   cross-checked against ``compiled.cost_analysis()`` so it is never
-  self-graded (``benchmarks/check_mfu_accounting.py``).
+  self-graded (:func:`mfu_check`).
 
 :func:`step_report` runs a jittable step under the profiler and returns one
 flat dict (step time, MFU, wire bytes + modeled ICI bandwidth, per-phase
@@ -36,8 +36,7 @@ def gpt_analytic_flops_per_token(n_params: int, num_layers: int,
                                  hidden: int, seq: int) -> float:
     """Standard decoder MFU accounting: ``6·N`` per token (fwd+bwd matmuls)
     plus causal attention ``6·L·hidden·seq``. Remat recompute is NOT
-    credited. Shared by ``bench.py`` and the HLO cross-check so the bench
-    always divides by the constant the check validates."""
+    credited. :func:`mfu_check` holds it against XLA's own count."""
     return float(6 * n_params + 6 * num_layers * hidden * seq)
 
 
@@ -75,8 +74,8 @@ def hlo_stats(compiled, default_group_size: Optional[int] = None
 def mfu_check(fn: Callable, *args: Any, analytic_flops: float,
               **kwargs: Any) -> Dict[str, Any]:
     """Compile-only MFU-denominator validation: compare the analytic flops
-    model against ``cost_analysis()`` on the exact compiled step (the
-    ``check_mfu_accounting.py`` join). Returns the stats dict plus
+    model against ``cost_analysis()`` on the exact compiled step. Returns
+    the stats dict plus
     ``analytic_flops`` and ``hlo_over_analytic``."""
     jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
     compiled = jitted.lower(*args, **kwargs).compile()

@@ -4,9 +4,8 @@ One record per train step, one JSON object per line, appended to a file.
 The format choices are all crash-shaped:
 
 * **versioned schema** — every record carries ``"schema": SCHEMA_VERSION``
-  so a reader of mixed-age logs can dispatch; bench scripts share the same
-  convention via :func:`json_record` (the one-JSON-line contract
-  ``bench.py`` / ``benchmarks/bench_comm.py`` print).
+  so a reader of mixed-age logs can dispatch; scripts that print one
+  JSON line share the convention via :func:`json_record`.
 * **buffered flush** — records buffer host-side and flush every
   ``buffer_steps`` (or on ``close``/``__exit__``), so the sink never adds a
   filesystem write to the step's critical path.

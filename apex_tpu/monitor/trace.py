@@ -104,8 +104,8 @@ import jax
 # handoff between hosts (serve.cluster — pack/ship/unpack around the
 # SimTransport or ICI hop). "scrape" is the fleet-observability tier's
 # host-side phase: the FleetScraper pulling worker snapshots on the
-# cluster clock (its cost is itself measured — scrape_ms — and gated by
-# bench_observe.py). "recompute" is the forward replayed in the backward
+# cluster clock (its cost is itself measured: scrape_ms).
+# "recompute" is the forward replayed in the backward
 # pass (split_scope tells it from "fwd" and "bwd").
 PHASES = ("fwd", "recompute", "bwd", "comm", "opt", "ckpt", "prefill",
           "decode", "transfer", "scrape")

@@ -3,8 +3,8 @@
 The one-command read of a serve telemetry log (step records, lifecycle
 events and gauges share one JSONL file — ``view`` partitions by the
 ``kind`` field). Human table to **stderr**, one machine-readable
-``json_record`` line to **stdout** — the bench.py pipe convention, so
-scripts and humans read the same invocation.
+``json_record`` line to **stdout**, so scripts and humans read the same
+invocation.
 
 Per-request latencies are reconstructed from the lifecycle events
 (``submitted → admitted → first_token → retired``); pass SLO budgets

@@ -440,8 +440,7 @@ class CheckpointManager:
         self.last_save_ms: Optional[float] = None
         self.last_save_bytes: Optional[int] = None
         # host ms spent in the reshard arithmetic of the last elastic
-        # restore (0.0 when the last restore bound exactly) — the
-        # bench_elastic reshard_ms source
+        # restore (0.0 when the last restore bound exactly)
         self.last_reshard_ms: float = 0.0
         self._pool: Optional[ThreadPoolExecutor] = None
         self._pending: List[Future] = []

@@ -29,8 +29,7 @@ the fault-free run:
   is the cluster's; the stream must still land bitwise.
 
 ``ServeCluster(chaos=ClusterChaos([...]))`` consults the plan at the
-top of every tick; ``benchmarks/bench_serve_mh.py --chaos`` uses the
-same plan objects for the goodput-under-chaos record.
+top of every tick.
 """
 
 from __future__ import annotations

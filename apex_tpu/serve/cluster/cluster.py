@@ -174,8 +174,8 @@ class ClusterConfig:
     # attribution: an AttributionAccumulator tapped on the shared
     # EventLog decomposes every retired request's e2e into queue/
     # prefill/transfer/decode/stall components on cluster.stats().
-    # Both default ON (host-side dict work only — bench_attrib_cost
-    # pins the A/B overhead ≤ 5%); OFF restores the tier-3 cluster.
+    # Both default ON (host-side dict work only; their cost on the
+    # chip is not measured); OFF restores the tier-3 cluster.
     metering: bool = True
     attribution: bool = True
     cost_model: Optional[CostModel] = None

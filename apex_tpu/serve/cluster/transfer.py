@@ -25,8 +25,7 @@ is that wire:
   handoff with the ``comm.accounting`` convention (whole transfers priced
   from shapes, scale overhead amortized per element exactly like
   ``kv_cache._elem_bytes``); the packed payload's measured ``nbytes``
-  agrees with the model to the byte, and ``benchmarks/bench_serve_mh.py``
-  asserts that agreement into its record.
+  agrees with the model to the byte (``tests/test_serve_cluster.py``).
 * **transports** — :class:`SimTransport` is the host-simulated in-process
   link (modeled latency = fixed + bytes/bandwidth against the cluster's
   one monotonic clock) that lets the whole multi-"host" cluster run on a

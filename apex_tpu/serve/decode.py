@@ -469,9 +469,7 @@ def gpt_prefill(params, tokens, prompt_len, cache, block_row,
         qkv = _col(h1, lp["qkv_kernel"], lp["qkv_bias"], tp_axis)
         q, k, v = _split_qkv(qkv, heads_local, cfg.head_dim)  # (1,t,H,D)
         q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))  # (1,H,t,D)
-        ctx = flash_attention(q, k, v, causal=True,
-                              block_q=cfg.attn_block_q,
-                              block_k=cfg.attn_block_k)
+        ctx = flash_attention(q, k, v, causal=True)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(1, t,
                                                 heads_local * cfg.head_dim)
         a = _row(ctx, lp["out_kernel"], lp["out_bias"], tp_axis,

@@ -1819,6 +1819,6 @@ def decode_flops_per_token(n_params: int, num_layers: int, hidden: int,
     ``2N`` matmul flops plus paged attention ``4·L·hidden·context`` (QKᵀ
     and PV against the cached context). The serving analogue of
     ``monitor.report.gpt_analytic_flops_per_token`` (which counts fwd+bwd
-    at 6N) — bench_serve divides by this so its MFU column is honest about
-    being a model."""
+    at 6N): a serving MFU divided by this is honest about being a
+    model."""
     return float(2 * n_params + 4 * num_layers * hidden * context)

@@ -38,8 +38,7 @@ gradient wire apply to the cache.
 
 Byte accounting (:func:`kv_write_bytes_per_token`, :func:`kv_read_bytes`)
 uses the same modeled-bytes convention as ``comm.accounting`` — the
-engine reports both through the ``monitor`` pipeline and
-``benchmarks/bench_serve.py`` prints them on the one-JSON-line record.
+engine reports both through the ``monitor`` pipeline.
 """
 
 from __future__ import annotations
@@ -511,8 +510,7 @@ class BlockAllocator:
 
 # ---------------------------------------------------------------------------
 # Byte accounting — modeled HBM traffic of the paged cache, the serving
-# analogue of comm.accounting's modeled wire bytes. bench_serve.py joins
-# these with collective_report() on the compiled decode program.
+# analogue of comm.accounting's modeled wire bytes.
 
 
 def _elem_bytes(cfg: KVCacheConfig) -> float:

@@ -1,0 +1,91 @@
+"""The train step: forward, backward and FusedAdam as one jitted program
+over a mesh. Every model the package trains runs this one step, and it is
+the step the benchmark's cells time (``perfbench/kinds/``).
+
+A model is any object with three methods:
+
+* ``param_specs()``: the parameters' pytree with a ``PartitionSpec`` a leaf;
+* ``init_params(rng)``: the parameters, in that pytree;
+* ``loss(params, tokens, targets)``: the local mean loss, called inside
+  ``shard_map`` over the mesh (parameters per ``param_specs()``, the batch
+  split over ``dp``).
+
+``transformer.testing.GPTConfig`` and ``transformer.hybrid.HybridConfig``
+both are.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
+
+
+def train_step_fn(model, mesh):
+    """The jitted fwd+bwd+FusedAdam step of ``model`` over ``mesh`` (params
+    and optimizer state donated), plus the optimizer it steps."""
+    from apex_tpu.monitor.trace import register_program, span
+    from apex_tpu.optimizers import FusedAdam
+    from apex_tpu.transformer.pipeline_parallel.schedules.common import (
+        replicate_loss,
+    )
+
+    specs = model.param_specs()
+    opt = FusedAdam(lr=1e-4)
+
+    def loss_fn(p, tok, tgt):
+        def body(p, tok, tgt):
+            return replicate_loss(model.loss(p, tok, tgt), mesh,
+                                  masked_axis=None)
+
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(specs, P("dp"), P("dp")),
+                             out_specs=P())(p, tok, tgt)
+
+    def update(grads, opt_state, params):
+        # the optimizer steps each device's own shards inside the mesh
+        # program: jit's partitioner cannot split FusedAdam's Pallas tail
+        state_specs = opt_state._replace(count=P(), mu=specs, nu=specs)
+        return jax.shard_map(opt.update, mesh=mesh,
+                             in_specs=(specs, state_specs, specs),
+                             out_specs=(specs, state_specs))(
+                                 grads, opt_state, params)
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def train_step(params, opt_state, tok, tgt):
+        loss, grads = jax.value_and_grad(loss_fn)(params, tok, tgt)
+        with span("opt"):
+            updates, opt_state = update(grads, opt_state, params)
+            params = jax.tree.map(lambda p, u: p + u, params, updates)
+        return params, opt_state, loss
+
+    def lower(rows: int, seq: int):
+        """The step lowered at the shapes and shardings a job hands it,
+        from shapes alone: for ``monitor.trace.scope_table``."""
+        return train_step.lower(
+            *abstract_train_args(model, opt, mesh, rows, seq))
+
+    register_program("jit_train_step", lower)
+    return train_step, opt
+
+
+def abstract_train_args(model, opt, mesh, rows: int, seq: int):
+    """``(params, opt_state, tok, tgt)`` as ``ShapeDtypeStruct``s placed as
+    a job places the real ones (parameters and Adam's moments per
+    ``param_specs()``, the batch over ``dp``): no array is made."""
+    def placed(a, spec):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    specs = model.param_specs()
+    params = jax.tree.map(placed, jax.eval_shape(
+        lambda: model.init_params(jax.random.PRNGKey(0))), specs)
+    state = jax.eval_shape(opt.init, params)
+    state = state._replace(count=placed(state.count, P()),
+                           mu=jax.tree.map(placed, state.mu, specs),
+                           nu=jax.tree.map(placed, state.nu, specs))
+    tok = placed(jax.ShapeDtypeStruct((rows, seq), jnp.int32), P("dp"))
+    return params, state, tok, tok

@@ -93,11 +93,6 @@ class GPTConfig:
     # across the TP group).
     attention_dropout: float = 0.0
     hidden_dropout: float = 0.0
-    # lax.scan unroll factor for the layer stack: 1 = one compiled layer
-    # body (fast compiles); num_layers = straight-line HLO (cross-layer
-    # fusion, and XLA cost analysis then counts every layer — see
-    # benchmarks/check_mfu_accounting.py).
-    scan_unroll: int = 1
     # Megatron-style sequence parallelism over the tp axis (Korthikanti;
     # NOT in the reference): LN/dropout/residual regions run on (b, s/tp, h)
     # shards, TP blocks all_gather on entry and reduce-scatter on exit,
@@ -135,18 +130,6 @@ class GPTConfig:
     num_experts: int = 0
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
-    # Pallas kernel block sizes (benchmarks/tune_blocks.py sweeps these on
-    # hardware; 0 = the kernel's own default). The attention pair are upper
-    # bounds on the tile: ``ops.attention._tile_plan`` takes the widest
-    # divisor of the sequence under them and picks the schedule from (seq,
-    # head size, dtype, causal, bias) — at seq 1024 a causal head runs
-    # resident on 512 x 512 tiles, three unrolled bodies a kernel; narrower
-    # bounds, or a longer head, stream. LM-head blocks trade the vocab-tile
-    # streaming pattern.
-    attn_block_q: int = 512
-    attn_block_k: int = 512
-    lm_block_n: int = 0
-    lm_block_v: int = 0
     # Under megatron_sp, dispatch from the LOCAL sequence shard instead of
     # gathering the full sequence per TP rank: tp-fold less router/dispatch
     # compute, SP activation saving kept. Capacity becomes per-shard, so
@@ -155,9 +138,8 @@ class GPTConfig:
     moe_seq_dispatch: bool = False
     # LayerNorm implementation override: None = layer_norm's own auto
     # (Pallas kernel on TPU when shapes allow), True/False forces it.
-    # benchmarks/tune_blocks.py A/Bs the full step both ways — a Pallas
-    # call is an XLA fusion barrier, so at small hidden the fused XLA LN
-    # can win despite the kernel's fewer HBM passes.
+    # A Pallas call is an XLA fusion barrier, so at small hidden the
+    # fused XLA LN can win despite the kernel's fewer HBM passes.
     ln_pallas: Optional[bool] = None
 
     @property
@@ -406,12 +388,8 @@ def _attention_core(q, k, v, cfg, causal, mask, dropout_key):
 
         seed = attention_dropout_seed(dropout_key)
         return flash_attention(q, k, v, causal=causal, mask=mask,
-                               block_q=cfg.attn_block_q,
-                               block_k=cfg.attn_block_k,
                                dropout_rate=rate, dropout_seed=seed)
-    return flash_attention(q, k, v, causal=causal, mask=mask,
-                           block_q=cfg.attn_block_q,
-                           block_k=cfg.attn_block_k)
+    return flash_attention(q, k, v, causal=causal, mask=mask)
 
 
 def _mlp(p, x, cfg):
@@ -621,8 +599,7 @@ def _layer_stack(layers, x, cfg, causal: bool = True, mask=None,
             h, aux = one(lp, h, key if dropout_key is not None else None)
         return h, aux
 
-    out, aux_per_layer = lax.scan(body, x, (layers, keys),
-                                  unroll=min(cfg.scan_unroll, n_layers))
+    out, aux_per_layer = lax.scan(body, x, (layers, keys))
     return out, jnp.mean(aux_per_layer)
 
 
@@ -751,9 +728,7 @@ def _use_fused_loss(cfg: GPTConfig, n_rows: int) -> bool:
 
 
 def fused_head_loss(head_rows_w, ln_w, ln_b, x, targets,
-                    gather_sequence: bool = False,
-                    block_n: int = 0, block_v: int = 0,
-                    ln_use_pallas=None):
+                    gather_sequence: bool = False, ln_use_pallas=None):
     """Shared fused LM-head + CE block: final LN -> copy-to-TP-region ->
     pvary (so dw reduces over the data axes) -> fused loss kernel.
     ``head_rows_w``: (vocab/tp, hidden) projection rows. With
@@ -776,12 +751,7 @@ def fused_head_loss(head_rows_w, ln_w, ln_b, x, targets,
         # activations' axes so dw is psum'd over the data axes at the pvary
         # transpose
         w = pvary_like(head_rows_w, x)
-        kw = {}
-        if block_n:
-            kw["block_n"] = block_n
-        if block_v:
-            kw["block_v"] = block_v
-        return jnp.mean(lm_head_loss(x, w, targets, axis_name=TP_AXIS, **kw))
+        return jnp.mean(lm_head_loss(x, w, targets, axis_name=TP_AXIS))
 
 
 def gpt_loss(params, tokens, targets, cfg: GPTConfig, dropout_key=None):
@@ -808,8 +778,6 @@ def gpt_loss(params, tokens, targets, cfg: GPTConfig, dropout_key=None):
          else head["lm"].T)  # (vocab/tp, hidden) rows
     return fused_head_loss(w, head["ln_w"], head["ln_b"], x, targets,
                            gather_sequence=cfg.megatron_sp,
-                           block_n=cfg.lm_block_n,
-                           block_v=cfg.lm_block_v,
                            ln_use_pallas=cfg.ln_pallas) + aux
 
 
@@ -892,8 +860,6 @@ def gpt_pipeline_spec(cfg: GPTConfig, dropout: bool = False) -> PipelineSpec:
             return fused_head_loss(head["lm"].T, head["ln_w"], head["ln_b"],
                                    h, targets,
                                    gather_sequence=cfg.megatron_sp,
-                                   block_n=cfg.lm_block_n,
-                                   block_v=cfg.lm_block_v,
                                    ln_use_pallas=cfg.ln_pallas)
         logits = gpt_head({"head": head}, h, cfg=dataclasses.replace(
             cfg, tie_embeddings=False))

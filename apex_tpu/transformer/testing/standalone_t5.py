@@ -69,8 +69,6 @@ class T5Config:
     dtype: Any = jnp.bfloat16
     remat: bool = True
     fused_loss: bool = True
-    attn_block_q: int = 512
-    attn_block_k: int = 512
     # Ref attention-/hidden-dropout sites, same RNG policy as
     # standalone_gpt: active only when the caller passes ``dropout_key``;
     # attention dropout runs INSIDE the flash kernel with a TP-rank-folded
@@ -385,14 +383,9 @@ def _attn_core(q, k, v, cfg: T5Config, causal: bool, dropout_key,
         )
 
         seed = attention_dropout_seed(dropout_key)
-        return flash_attention(q, k, v, causal=causal,
-                               block_q=cfg.attn_block_q,
-                               block_k=cfg.attn_block_k,
-                               dropout_rate=rate, dropout_seed=seed,
-                               bias=bias)
-    return flash_attention(q, k, v, causal=causal,
-                           block_q=cfg.attn_block_q,
-                           block_k=cfg.attn_block_k, bias=bias)
+        return flash_attention(q, k, v, causal=causal, dropout_rate=rate,
+                               dropout_seed=seed, bias=bias)
+    return flash_attention(q, k, v, causal=causal, bias=bias)
 
 
 def _self_attention(p, x, cfg: T5Config, causal: bool, dropout_key=None,

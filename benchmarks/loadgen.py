@@ -26,16 +26,15 @@ through it:
 * **per-tenant adapters** — ``n_adapters`` binds tenant ``t{i}`` to LoRA
   adapter ``ad{i % n_adapters}`` deterministically (no extra rng draws:
   an ``n_adapters=0`` workload is bit-identical to the pre-adapter one).
-  This is the fleet-mix workload ``bench_serve_mh.py --lora`` drives
-  (adapter hit rate, warm-dispatch rate, aid=0 ``streams_equal``).
+  This is the fleet-mix workload (adapter hit rate, warm-dispatch rate,
+  aid=0 ``streams_equal``).
 
 ``run_workload`` drives the engine with ``retain_streams=False`` — state
 stays O(slots + backlog) no matter how many requests flow — and returns
 ``engine.stats()`` (histquantiles + goodput-under-SLO). ``main`` builds
 the pinned bench model, runs a Poisson+burst workload against a default
 SLO and prints ONE ``json_record`` line (goodput req/s, TTFT/TPOT
-p50/p99, violation counts) — ``benchmarks/bench_serve.py --loadgen``
-calls straight into this.
+p50/p99, violation counts).
 
 Run: ``python benchmarks/loadgen.py [--out FILE] [--trace-dir DIR]``.
 """
@@ -314,7 +313,7 @@ def main(argv=None) -> int:
     if not on_tpu:
         name += "_CPU_FALLBACK"
 
-    # the pinned bench model (bench_serve.py's canary constants)
+    # the pinned bench model
     HIDDEN, LAYERS, HEADS, VOCAB, MAX_SEQ = 128, 2, 8, 512, 256
     SLOTS, BLOCK_SIZE = 4, 16
     cfg = GPTConfig(vocab_size=VOCAB, max_seq=MAX_SEQ, hidden=HIDDEN,

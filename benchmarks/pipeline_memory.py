@@ -49,7 +49,7 @@ from apex_tpu.transformer.testing import (
 HID, SEQ, HEADS, LAYERS = 64, 64, 4, 4
 B_PER_MB = 2  # per-dp-shard microbatch rows: fixed as M varies
 
-# flagship operating point (bench.py's GPT-2 124M-class architecture at a
+# flagship operating point (the GPT-2 124M-class architecture at a
 # pipeline-able depth): the boundary:interior byte ratio shifts with
 # hidden, so the O(M) slope and recompute-factor claims are also pinned
 # here, not just at the toy shape (VERDICT r3 weak #5)

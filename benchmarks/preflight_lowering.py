@@ -2,12 +2,14 @@
 
 Chip minutes are budgeted, and debugging is the expensive way to spend
 them: every Pallas/Mosaic failure found here instead of on the chip is
-saved for measurement. This builds bench.py's OWN train step
-(``bench.train_step_fn`` — same model, same code path the headline times)
-for every auto-tune sweep configuration, on one chip and on the four-chip
-meshes ``chip_smoke.py`` runs, plus the ring-attention long-context step,
-and compiles each for a v5e topology description: XLA:TPU and Mosaic's
-own compiler run in full (VMEM allocation, layout inference, unsupported
+saved for measurement. This builds the package's train step
+(``apex_tpu.train.train_step_fn``, the step the benchmark's cells time) at
+GPT-2-124M's widths, 32 x 1024 tokens, for the programs something still
+runs: remat off and each remat policy with the fused loss, remat full
+without it, on one chip, and remat full on the four-chip meshes
+``chip_smoke.py`` runs, plus the ring-attention long-context step, and
+compiles each for a v5e topology description: XLA:TPU and Mosaic's own
+compiler run in full (VMEM allocation, layout inference, unsupported
 vector ops), no chip needed. ``tests/test_tpu_lowering.py`` guards single
 kernels and the serve engine's programs; this guards the composed train
 programs.
@@ -15,7 +17,7 @@ programs.
 Run: ``JAX_PLATFORMS=cpu python benchmarks/preflight_lowering.py``
 Exit 1 if any configuration fails to compile or lost a kernel. A config
 XLA reports as over the chip's HBM prints ``NOFIT`` and does not fail:
-bench.py's sweep probes above the fit on purpose and skips those.
+remat off at this batch is above the fit, and is compiled for its kernels.
 """
 
 from __future__ import annotations
@@ -49,8 +51,8 @@ def _compile(tag, jitted, *args, min_kernels=1):
         _, compiled = compile_for_tpu(jitted, *args)
     except Exception as e:  # noqa: BLE001 — report every config, then fail
         if "memory space hbm" in str(e):
-            # the sweep probes configs above the HBM fit on purpose and
-            # bench.py skips them the same way; a VMEM overflow stays a FAIL
+            # a config above the HBM fit is no fault of a kernel; a VMEM
+            # overflow stays a FAIL
             print(f"NOFIT {tag}: {str(e).split('. ', 1)[-1][:160]}",
                   flush=True)
             return True
@@ -69,37 +71,33 @@ def _compile(tag, jitted, *args, min_kernels=1):
 
 
 def main() -> int:
-    import bench
     from apex_tpu.parallel.mesh import build_mesh
+    from apex_tpu.train import abstract_train_args, train_step_fn
+    from apex_tpu.transformer.testing import GPTConfig
 
     ok = True
     devices = tpu_topology_devices()
-    seq = 1024
+    batch, seq = 32, 1024
 
-    def train(tag, dp, tp, batch, **cfg_kw):
+    def train(tag, dp, tp, **cfg_kw):
         mesh = build_mesh(tp=tp, pp=1, sp=1, dp=dp, devices=devices[:dp * tp])
-        cfg = bench.flagship_config(seq, **cfg_kw)
-        step, opt = bench.train_step_fn(cfg, mesh)
+        cfg = GPTConfig(max_seq=seq, **cfg_kw)
+        step, opt = train_step_fn(cfg, mesh)
         return _compile(tag, step,
-                        *bench.abstract_train_args(cfg, opt, mesh, batch, seq),
+                        *abstract_train_args(cfg, opt, mesh, batch, seq),
                         min_kernels=4)
 
-    # --- the flagship train step, every sweep configuration -------------
-    # bench.py sweeps (remat, policy, scan_unroll, fused loss) at the full
-    # batch: VMEM use depends on the row count, so compile what runs
-    for remat, policy, unroll, fused in [
-            (False, "full", 1, True), (True, "full", 1, True),
-            (True, "dots", 1, True), (True, "dots_attn", 1, True),
-            (False, "full", 12, True),
-            (True, "dots", 12, True), (False, "full", 1, False),
-            (True, "full", 1, False)]:
-        ok &= train(f"train_step remat={remat}/{policy} unroll={unroll} "
-                    f"fused={fused}", 1, 1, bench.BATCH, remat=remat,
-                    remat_policy=policy, scan_unroll=unroll,
-                    fused_loss=fused)
+    # --- the GPT-2-124M train step on one chip ----------------------------
+    # at the full batch: VMEM use depends on the row count
+    for remat, policy, fused in [
+            (False, "full", True), (True, "full", True),
+            (True, "dots", True), (True, "dots_attn", True),
+            (True, "full", False)]:
+        ok &= train(f"train_step remat={remat}/{policy} fused={fused}",
+                    1, 1, remat=remat, remat_policy=policy, fused_loss=fused)
     # --- the four-chip meshes chip_smoke.py trains on --------------------
     for dp, tp in ((4, 1), (2, 2)):
-        ok &= train(f"train_step dp={dp} tp={tp}", dp, tp, bench.BATCH,
+        ok &= train(f"train_step dp={dp} tp={tp}", dp, tp,
                     remat=True, remat_policy="full")
 
     # --- ring attention (long-context SP path), fwd + bwd ---------------

@@ -10,7 +10,7 @@ layers, 12 heads, bf16, random weights from a seed):
 
 * **kernels** — each main-path Pallas kernel against its reference
   (``benchmarks/smoke_tpu.py``'s rows, imported);
-* **train** — ``bench.build_train_step``: ``build_mesh`` ->
+* **train** — ``apex_tpu.train.train_step_fn``: ``build_mesh`` ->
   ``shard_map(gpt_loss)`` -> ``value_and_grad`` -> ``FusedAdam`` with donated
   params, batch 32 x 1024, remat full; the compiled step must contain the
   Mosaic kernels its dispatch sites should pick, and seven steps must give a
@@ -64,7 +64,7 @@ class Sizes:
 
 
 CHIP = Sizes(
-    model={},  # bench.flagship_config's defaults ARE the GPT-2-124M widths
+    model={},  # GPTConfig's defaults ARE the GPT-2-124M widths
     batch=32, seq=1024,
     prompt_lens=(5, 17, 40, 9, 33, 128, 300, 640), max_new=32)
 REHEARSAL = Sizes(
@@ -154,15 +154,40 @@ def kernel_phase(rehearsal: bool) -> None:
             check(row["ok"], f"kernel row {name}: {row}")
 
 
+def _placed_train_step(cfg, batch: int, seq: int, *, dp: int, tp: int):
+    """``(train_step, params, opt_state, tok, tgt)`` on a dp x tp mesh over
+    the first ``dp * tp`` devices, every input placed by the mesh's
+    shardings (parameters per the model's own ``param_specs()``, the batch
+    split over ``dp``), so that no device is left empty."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from apex_tpu.parallel.mesh import build_mesh
+    from apex_tpu.train import train_step_fn
+
+    mesh = build_mesh(tp=tp, pp=1, sp=1, dp=dp,
+                      devices=jax.devices()[:dp * tp])
+    step, opt = train_step_fn(cfg, mesh)
+    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                             cfg.param_specs())
+    data = NamedSharding(mesh, P("dp"))
+    params = jax.device_put(cfg.init_params(jax.random.PRNGKey(0)), shardings)
+    tok = jax.random.randint(jax.random.PRNGKey(1), (batch, seq), 0,
+                             cfg.vocab_size)
+    return (step, params, opt.init(params), jax.device_put(tok, data),
+            jax.device_put(jnp.roll(tok, -1, axis=1), data))
+
+
 def _train_leg(sizes: Sizes, rehearsal: bool, *, dp: int, tp: int,
                steps: int) -> None:
-    import bench
     from apex_tpu.ops._pallas_util import mosaic_calls
+    from apex_tpu.transformer.testing import GPTConfig
 
-    cfg = bench.flagship_config(sizes.seq, remat=True, remat_policy="full",
-                                **sizes.model)
+    cfg = GPTConfig(max_seq=sizes.seq, remat=True, remat_policy="full",
+                    **sizes.model)
     t0 = time.perf_counter()
-    step, params, opt_state, tok, tgt = bench.build_train_step(
+    step, params, opt_state, tok, tgt = _placed_train_step(
         cfg, sizes.batch, sizes.seq, dp=dp, tp=tp)
     compiled = step.lower(params, opt_state, tok, tgt).compile()
     kernels = mosaic_calls(compiled.as_text())
@@ -209,12 +234,11 @@ def serve_phase(sizes: Sizes, rehearsal: bool) -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    import bench
     from apex_tpu.serve import InferenceEngine, Request, ServeConfig
-    from apex_tpu.transformer.testing import init_gpt_params
+    from apex_tpu.transformer.testing import GPTConfig, init_gpt_params
 
     rng = np.random.default_rng(0)
-    vocab = bench.flagship_config(**sizes.model).vocab_size
+    vocab = GPTConfig(**sizes.model).vocab_size
     prompts = [rng.integers(0, vocab, size=n).tolist()
                for n in sizes.prompt_lens]
 
@@ -253,8 +277,8 @@ def serve_phase(sizes: Sizes, rehearsal: bool) -> None:
         token the two streams differ at}. The interpreter stands in for
         Mosaic on the CPU: "on" forces the fused block there, and the
         per-op body takes the reference."""
-        cfg = bench.flagship_config(**({"seq": 256} if rehearsal else {}),
-                                    **sizes.model, dtype=dtype)
+        cfg = GPTConfig(**({"max_seq": 256} if rehearsal else {}),
+                        **sizes.model, dtype=dtype)
         params = init_gpt_params(jax.random.PRNGKey(0), cfg)
         fused = run(cfg, params, "on" if rehearsal else "auto", "fused")
         per_op = run(cfg, params, "off",

@@ -7,9 +7,9 @@ the docs never drift from the code: every public module under ``apex_tpu``
 gets one page listing its public classes/functions with signatures and
 docstrings (which already carry the reference file:line citations).
 
-Run: ``python docs/generate_api.py`` (rewrites docs/api/). CI-free repo:
-regenerate whenever the public surface changes; test_misc_subsystems
-checks the tree is importable either way.
+Run: ``python docs/generate_api.py`` (writes docs/api/, about four
+seconds). The pages are built, not committed (``.gitignore``), so they
+cannot fall behind the code.
 """
 
 from __future__ import annotations

@@ -143,8 +143,8 @@ def train(args) -> List[float]:
         if args.opt_level != "O0":
             raise SystemExit(
                 f"--plan {args.plan} runs the sharded fp32 Adam loop; "
-                "pass --opt-level O0 (amp×FSDP composition is a "
-                "benchmarks/bench_fsdp.py + GPT story)")
+                "pass --opt-level O0 (this loop does not compose amp "
+                "with FSDP)")
         return _train_sharded(args, plan, mesh, model, params, batch_stats)
 
     overrides = {}

@@ -14,7 +14,7 @@ with the parallelism strategy picked by ONE declarative
 * ``--plan fsdp+tp`` — the same FSDP engine on a dp×tp mesh (this toy
   model defines no tensor-parallel layers, so tp only replicates compute —
   the point is that the PLAN resolves the composed mesh; see
-  ``benchmarks/bench_fsdp.py`` for fsdp+tp on the TP GPT).
+  ``tests/test_fsdp.py`` for fsdp+tp on the TP GPT).
 
 Run directly; on a CPU-only machine set
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` to fake a mesh.

@@ -605,61 +605,76 @@ def test_flagship_gpt_train_step_donation_and_recompile():
 
 
 def test_flagship_gpt_mesh_loss_step_donation_and_recompile():
-    """Acceptance: the REAL flagship step — ``gpt_loss``
-    under ``shard_map`` — donated params aliased, one compilation."""
+    """Acceptance: the step the benchmark's cells time
+    (``apex_tpu.train.train_step_fn``, on a tiny config, parameters and
+    state placed by ``param_specs()`` as ``perfbench/kinds/train.py``
+    places them, Adam's moments beside them): donated params and optimizer
+    state aliased, one compilation over three steps."""
+    from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
     from apex_tpu.parallel.mesh import build_mesh
-    from apex_tpu.transformer.testing import (
-        GPTConfig, gpt_loss, gpt_param_specs, init_gpt_params,
-    )
+    from apex_tpu.train import train_step_fn
+    from apex_tpu.transformer.testing import GPTConfig
 
     cfg = GPTConfig(vocab_size=96, max_seq=32, hidden=32, num_layers=2,
                     num_heads=4, dtype=jnp.float32)
-    params = init_gpt_params(jax.random.PRNGKey(0), cfg)
-    mesh = build_mesh(tp=1, pp=1, sp=1)
-    specs = gpt_param_specs(cfg)
-    tok = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, 96)
+    mesh = build_mesh(tp=1, pp=1, sp=1, dp=1, devices=jax.devices()[:1])
+    step, opt = train_step_fn(cfg, mesh)
+    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                             cfg.param_specs())
+    params = jax.device_put(cfg.init_params(jax.random.PRNGKey(0)),
+                            shardings)
+    state = opt.init(params)
+    state = jax.device_put(state, state._replace(
+        count=NamedSharding(mesh, P()), mu=shardings, nu=shardings))
+    tok = jax.device_put(
+        jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, 96),
+        NamedSharding(mesh, P("dp")))
 
-    def body(p, t, y):
-        loss, g = jax.value_and_grad(gpt_loss)(p, t, y, cfg)
-        return jax.tree_util.tree_map(
-            lambda a, b: a - 0.01 * b, p, g), loss
+    # Lowerings are counted, not ``recompile_guard``'s cache entries: jit's
+    # fast path keys on the sharding objects, the step hands its state back
+    # under normalised specs (P(None, "tp", None) as P(None, "tp"); P() on
+    # one device), and the second call adds an entry that compiles nothing.
+    lowered = []
 
-    sharded = jax.shard_map(body, mesh=mesh,
-                            in_specs=(specs, P(), P()),
-                            out_specs=(specs, P()))
-    n_leaves = len(jax.tree_util.tree_leaves(params))
-    rep = analyze.check_donation(
-        jax.jit(sharded, donate_argnums=(0,)), params, tok, tok,
-        donate_argnums=(0,))
-    assert rep.n_aliased >= n_leaves
-    step = jax.jit(sharded, donate_argnums=(0,))
-    p = jax.tree_util.tree_map(jnp.copy, params)
-    with analyze.recompile_guard(step):
+    def on_event(event, secs, **kw):
+        if event.endswith("jaxpr_to_mlir_module_duration"):
+            lowered.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
         for _ in range(3):
-            p, loss = step(p, tok, tok)
+            params, state, loss = step(params, state, tok, tok)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
     assert np.isfinite(float(loss))
+    assert len(lowered) == 1, lowered
+
+    n_donated = len(jax.tree.leaves((params, state)))
+    rep = analyze.assert_donated(step, params, state, tok, tok,
+                                 donate_argnums=(0, 1))
+    assert rep.n_aliased >= n_donated
 
 
-def _serve_fixture():
+def _serve_fixture(dtype=jnp.float32):
     from apex_tpu.serve import KVCacheConfig, init_kv_cache
     from apex_tpu.transformer.testing import GPTConfig, init_gpt_params
 
     cfg = GPTConfig(vocab_size=97, max_seq=64, hidden=32, num_layers=2,
-                    num_heads=4, dtype=jnp.float32, fused_loss=False)
+                    num_heads=4, dtype=dtype, fused_loss=False)
     params = init_gpt_params(jax.random.PRNGKey(0), cfg)
     kv = KVCacheConfig(num_layers=2, num_heads=4, head_dim=8,
-                       num_blocks=8, block_size=8, dtype=jnp.float32)
+                       num_blocks=8, block_size=8, dtype=dtype)
     return cfg, params, kv, init_kv_cache(kv)
 
 
-def test_flagship_serve_decode_step_donation():
-    """Acceptance: the serve decode step's donated KV pools are aliased —
-    a silently-copied pool would double serve HBM."""
+def _decode_program(dtype=jnp.float32):
+    """The serve decode step (model and pools in ``dtype``) with the
+    arguments of one step over three slots: ``(decode, (cache, ...))``."""
     from apex_tpu.serve.decode import gpt_decode_step
 
-    cfg, params, kv, cache = _serve_fixture()
+    cfg, params, kv, cache = _serve_fixture(dtype)
     n = 3
     toks = jnp.zeros((n,), jnp.int32)
     lens = jnp.array([4, 2, 0], jnp.int32)
@@ -670,14 +685,36 @@ def test_flagship_serve_decode_step_donation():
         return gpt_decode_step(params, toks, lens, active, cache, bt,
                                cfg, kv, tp_axis=None, use_pallas=False)
 
-    n_pool_leaves = len(jax.tree_util.tree_leaves(cache))
-    rep = analyze.assert_donated(decode, cache, toks, lens, active, bt,
-                                 donate_argnums=(0,))
+    return decode, (cache, toks, lens, active, bt)
+
+
+def test_flagship_serve_decode_step_donation():
+    """Acceptance: the serve decode step's donated KV pools are aliased —
+    a silently-copied pool would double serve HBM."""
+    decode, args = _decode_program()
+    n_pool_leaves = len(jax.tree_util.tree_leaves(args[0]))
+    rep = analyze.assert_donated(decode, *args, donate_argnums=(0,))
     assert rep.n_aliased >= n_pool_leaves
     # ... and the step itself is host-sync-free
-    sync = analyze.assert_no_host_sync(decode, cache, toks, lens, active,
-                                       bt)
+    sync = analyze.assert_no_host_sync(decode, *args)
     assert sync.host_syncs == 0
+
+
+def test_flagship_serve_decode_dtype_profile():
+    """Acceptance: the bf16 decode program keeps two fp32 dots (attention's
+    scores and its weighted values, for stability: the accepted level) and
+    no convert round trips."""
+    decode, args = _decode_program(jnp.bfloat16)
+    leak = analyze.dtype_leak_report(decode, *args, policy=jnp.bfloat16)
+    assert leak.fp32_dots == 2, leak
+    assert leak.convert_churn_ops == 0, leak
+
+
+def test_flagship_serve_decode_no_host_sync():
+    """Acceptance: nothing reachable from the decode step in the deployed
+    dtype (bf16 model and pools) waits for the device."""
+    decode, args = _decode_program(jnp.bfloat16)
+    assert analyze.host_sync_report(decode, *args).host_syncs == 0
 
 
 def test_flagship_serve_chunk_prefill_donation():

@@ -9,7 +9,7 @@ reads interleaved with in-flight work — had no dedicated test until this
 one.
 
 Strategy: run the donated flagship-style train step (the same
-donate_argnums=(0,1) shape bench.py and the EP dryrun use) many steps with
+donate_argnums=(0,1) shape ``apex_tpu.train`` and the EP dryrun use) many steps with
 host reads interleaved at different cadences; every cadence must produce
 the bitwise-identical loss trajectory. If XLA ever handed a donated buffer
 to a new step while a prior consumer was still in flight — or a host read
@@ -144,7 +144,7 @@ def test_interleaved_param_reads_see_consistent_state(small_cfg):
 def test_donated_input_is_consumed(small_cfg):
     """Reading a donated argument AFTER the step must raise — the buffer
     belongs to the new state. Pins the deletion semantics the donated
-    entry points (bench.py, the EP dryrun) rely on."""
+    entry points (``apex_tpu.train``, the EP dryrun) rely on."""
     mesh = parallel_state.initialize_model_parallel()
     step, init = _make_step(mesh, small_cfg, donate=True)
     params, opt_state, tok, tgt = init()

@@ -102,7 +102,11 @@ def test_quantization_error_is_the_ef_residual():
     e = quantization_error(x, 256)
     q, s = quantize_blockwise(x, 256)
     want = np.asarray(x) - np.asarray(dequantize_blockwise(q, s, 256))
-    np.testing.assert_allclose(np.asarray(e), want, atol=1e-7)
+    # both sides subtract a float32 round trip of x from x, so each is good
+    # to an ulp of |x| (1.2e-7 at 1, 2.4e-7 at 2), not of the residual
+    np.testing.assert_allclose(
+        np.asarray(e), want,
+        atol=2 * np.finfo(np.float32).eps * float(np.abs(x).max()))
 
 
 # ---------------------------------------------------------------------------

@@ -10,9 +10,9 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-import bench
 from apex_tpu.monitor import trace as monitor_trace
 from apex_tpu.parallel.mesh import build_mesh
+from apex_tpu.train import abstract_train_args, train_step_fn
 from apex_tpu.transformer.hybrid import (
     FULL,
     LINEAR,
@@ -144,12 +144,12 @@ def _step(cfg, **mesh_kw):
     n = int(np.prod(list(mesh_kw.values())))
     mesh = build_mesh(**{"tp": 1, "pp": 1, "sp": 1, "dp": 1, **mesh_kw},
                       devices=jax.devices()[:n])
-    return (*bench.train_step_fn(cfg, mesh), mesh)
+    return (*train_step_fn(cfg, mesh), mesh)
 
 
 def test_tensor_parallelism_is_refused_with_a_message():
     step, opt, mesh = _step(_cfg(), tp=2)
-    args = bench.abstract_train_args(_cfg(), opt, mesh, 2, SEQ)
+    args = abstract_train_args(_cfg(), opt, mesh, 2, SEQ)
     with pytest.raises(NotImplementedError, match=r"not written for tensor parallelism \(tp = 2\)"):
         step.lower(*args)
 
@@ -174,7 +174,7 @@ def test_data_parallel_step_equals_the_one_device_step():
               dtype=jnp.float32),
 ], ids=["hybrid", "gpt"])
 def test_both_families_run_the_one_train_step_registered_as_jit_train_step(cfg):
-    """``bench.train_step_fn`` takes a model by three methods; the step it
+    """``apex_tpu.train.train_step_fn`` takes a model by three methods; the step it
     returns is the one ``monitor.trace`` knows as ``jit_train_step``."""
     for method in ("param_specs", "init_params", "loss"):
         assert callable(getattr(cfg, method))

@@ -383,7 +383,7 @@ def test_pipeline_bubble_fraction():
 
 
 def test_gpt_analytic_flops_per_token():
-    # 6N + causal-attention term, the constant bench.py divides by
+    # 6N + causal-attention term, the constant an MFU report divides by
     assert gpt_analytic_flops_per_token(100, 2, 8, 16) == \
         6 * 100 + 6 * 2 * 8 * 16
 

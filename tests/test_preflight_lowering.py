@@ -1,9 +1,9 @@
-"""The composed-program preflight must stay green: every bench sweep
-configuration of the flagship train step, the four-chip meshes and the
-ring-attention SP step AOT-COMPILE for a v5e topology with their Mosaic
-kernels present (not the reference fallbacks). Complements
-tests/test_tpu_lowering.py (single kernels, serve programs) at the program
-level bench.py actually times.
+"""The composed-program preflight must stay green: the GPT-2-124M train
+step under each remat policy, with and without the fused loss, on one chip
+and on the four-chip meshes, and the ring-attention SP step AOT-COMPILE for
+a v5e topology with their Mosaic kernels present (not the reference
+fallbacks). Complements tests/test_tpu_lowering.py (single kernels, serve
+programs) at the level of the whole step (``apex_tpu.train``).
 
 Runs in a subprocess: the preflight pins the process to the CPU platform
 at import time, which must not leak into the pytest process (reviewer

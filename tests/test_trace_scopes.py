@@ -115,14 +115,14 @@ def test_moves_only(name, moves):
 # -- the train step's scopes in a compiled module --------------------------------
 
 def _toy_step():
-    import bench
     from apex_tpu.parallel.mesh import build_mesh
+    from apex_tpu.train import train_step_fn
     from apex_tpu.transformer.testing import GPTConfig
 
     cfg = GPTConfig(vocab_size=256, max_seq=32, hidden=64, num_layers=2,
                     num_heads=2, dtype=jnp.float32, remat=True)
     mesh = build_mesh(tp=1, pp=1, sp=1, dp=1, devices=jax.devices()[:1])
-    return bench.train_step_fn(cfg, mesh)
+    return train_step_fn(cfg, mesh)
 
 
 @pytest.fixture(scope="module")
