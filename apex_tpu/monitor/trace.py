@@ -31,7 +31,9 @@ Device scopes of the train step (``transformer/testing/standalone_gpt.py``,
     layer/residual              the two residual adds (and hidden dropout)
     final_ln                    the LayerNorm after the stack
     lm_head_loss                LM head + cross entropy, fused or not
-    opt                         FusedAdam's update and ``p + u``
+    opt                         ``FusedAdam(...).step``: one fusion a leaf
+                                that reads g, m, v, p and writes m, v, p
+                                (no kernel below it since PR 31)
     scan_carry                  the scan's own slice of the stacked
                                 parameters and write of the stacked
                                 gradients, once a layer (no user scope)

@@ -1,20 +1,18 @@
-"""Fused optimizer update tail — one Pallas kernel per parameter leaf.
+"""Fused optimizer update tail — one Pallas kernel per flat shard leaf.
 
-The ZeRO half of the megakernel PR (ROADMAP item 4): after the gradient
+For the ZeRO optimizers and FSDP, whose state is one-dimensional by nature
+(each leaf flattened and cut into 1/dp shards): after the gradient
 reduce-scatter, the optimizer "tail" — moment updates, bias correction,
 weight decay, the update direction — is a chain of ~10 tiny elementwise
-XLA ops **per leaf**. Like the q_len=1 decode step, the math is
-bandwidth-trivial and the per-op dispatch dominates on a sharded state
-(ZeRO shards are 1/dp of each leaf). This module fuses the whole chain
-into ONE kernel per leaf:
+XLA ops **per leaf**. This module fuses the whole chain into ONE kernel
+per leaf:
 
 * :func:`fused_adam_tail` — ``m' = β₁m + (1-β₁)g``, ``v' = β₂v +
   (1-β₂)g²``, ``u = (m'/c₁)/(√(v'/c₂)+ε)`` with either decay mode
   (ADAM_MODE_0 decoupled / ADAM_MODE_1 L2 — the ``multi_tensor_adam.cu``
-  split), emitted as ``(u, m', v')``. The caller applies ``p - lr·u``
-  (or feeds ``-lr·u`` to optax) — the one op deliberately left outside,
-  since LAMB must scale ``u`` by the trust ratio first and FusedAdam's
-  optax contract returns updates, not params.
+  split), emitted as ``(u, m', v')``. The caller applies ``p - lr·u`` to
+  its float32 master shard — the one op deliberately left outside, since
+  LAMB must scale ``u`` by the trust ratio first.
 * :func:`fused_lamb_tail` — the same kernel with two extra ``(8, 128)``
   per-lane partial-sum tiles accumulated across the sequential grid (folded
   by the wrapper): the LOCAL sq-sums
@@ -30,10 +28,15 @@ across leaves would need a concat/split round-trip of the whole optimizer
 state through HBM every step, trading real bandwidth for saved dispatch.
 
 Wired behind ``fused_update=`` on the ZeRO
-``DistributedFusedAdam``/``DistributedFusedLAMB`` and ``fused_tail=`` on
-the single-device ``FusedAdam`` ("auto" picks the kernel only on a
-compiled Mosaic backend). ``*_reference`` twins carry the identical math
-for parity tests and the off-TPU fallback.
+``DistributedFusedAdam``/``DistributedFusedLAMB`` and FSDP's optimizer
+("auto" picks the kernel only on a compiled Mosaic backend). ``*_reference``
+twins carry the identical math for parity tests and the off-TPU fallback.
+
+Not for a leaf in its own shape: on the chip's (8, 128) tiling the flatten,
+pad and reshape to ``(rows, 128)`` of a stacked matrix are physical copies,
+and they cost the train step three times what the kernel did (PERF.md §6,
+PR 31). ``optimizers.FusedAdam`` therefore leaves its tail to XLA, one
+fusion a leaf over donated buffers, and no benchmark cell runs this module.
 """
 
 from __future__ import annotations
@@ -58,8 +61,8 @@ _TILE_BIG = _BLOCK_ROWS * _LANES  # large leaves pad to whole row blocks
 
 
 # ---------------------------------------------------------------------------
-# references — the exact math the ZeRO/FusedAdam ``upd`` closures ran
-# before fusion (and still run when the kernel is off)
+# references — the exact math the ZeRO ``upd`` closures ran before fusion
+# (and still run when the kernel is off)
 
 
 def adam_tail_reference(g, m, v, p, c1, c2, *, betas, eps,
@@ -224,7 +227,7 @@ def fused_lamb_tail(g, m, v, p, c1, c2, *, betas, eps,
                         with_norms=True, interpret=interpret)
 
 
-def resolve_fused(mode: str, what: str = "fused_update") -> bool:
+def resolve_fused(mode: str) -> bool:
     """``"auto" | "on" | "off"`` -> whether to run the fused kernels.
     ``auto`` picks them only where they are a win AND can be placed — a
     compiled Mosaic backend, traced inside a ``shard_map`` body or on a
@@ -240,4 +243,4 @@ def resolve_fused(mode: str, what: str = "fused_update") -> bool:
     if mode == "auto":
         return _compiled_backend() and _mosaic_placeable()
     raise ValueError(
-        f"{what} must be 'auto', 'on' or 'off', got {mode!r}")
+        f"fused_update must be 'auto', 'on' or 'off', got {mode!r}")

@@ -39,6 +39,17 @@ class FusedAdamState(NamedTuple):
     nu: Any  # second moments, fp32
 
 
+class FusedAdamTransformation(optax.GradientTransformation):
+    """``(init, update)`` as optax has them, and beside them ``step(grads,
+    state, params) -> (params, state)``: the same update applied, each of g,
+    m, v and p read once and m, v and p written once."""
+
+    def __new__(cls, init, update, step):
+        self = super().__new__(cls, init, update)
+        self.step = step
+        return self
+
+
 def FusedAdam(
     lr: Schedule = 1e-3,
     bias_correction: bool = True,
@@ -48,22 +59,19 @@ def FusedAdam(
     weight_decay: float = 0.0,
     amsgrad: bool = False,
     capturable: bool = True,  # always "capturable": everything lives on device
-    fused_tail: str = "auto",
-) -> optax.GradientTransformation:
+) -> FusedAdamTransformation:
     """Build the transform (ref ``fused_adam.py:4`` constructor signature;
     ``step`` at ``:92``). ``amsgrad`` is unsupported, as in the reference
     (``fused_adam.py:77-78`` raises).
 
-    ``fused_tail``: run the per-leaf update tail as ONE Pallas kernel
-    (``ops/fused_update.py`` — the actual "fused" of the reference's
-    multi_tensor launch, rebuilt for Mosaic) — "auto" on compiled TPU
-    backends, "on" forces (interpret off-TPU), "off" keeps the XLA op
-    chain."""
+    ``update`` keeps optax's contract and returns the updates. ``step`` is
+    the reference's own form, parameters in and parameters out: a leaf's
+    whole tail and ``p - lr*u`` are one elementwise function of g, m, v and
+    p in the shapes and types they have, so that under ``jit`` with params
+    and state donated XLA emits one fusion a leaf that writes m, v and p
+    where they were. The parameter is rounded once, from float32."""
     if amsgrad:
         raise RuntimeError("FusedAdam does not support the AMSGrad variant.")
-    from apex_tpu.ops.fused_update import resolve_fused
-
-    resolve_fused(fused_tail, what="fused_tail")  # validate eagerly
     b1, b2 = betas
 
     def init(params):
@@ -76,7 +84,9 @@ def FusedAdam(
             nu=tree_map(zeros, params),
         )
 
-    def update(grads, state, params):
+    def tails(grads, state, params, applied: bool):
+        """Every leaf's tail: ``-lr*u``, or ``p - lr*u`` where ``applied``,
+        in the leaf's type, and the state with the new moments."""
         if params is None:
             raise ValueError("FusedAdam requires params in update()")
         count = state.count + 1
@@ -86,21 +96,9 @@ def FusedAdam(
         c1 = 1.0 - jnp.power(b1, t) if bias_correction else jnp.asarray(1.0)
         c2 = 1.0 - jnp.power(b2, t) if bias_correction else jnp.asarray(1.0)
 
-        from apex_tpu.ops.fused_update import fused_adam_tail, resolve_fused
-
-        use_fused = resolve_fused(fused_tail, what="fused_tail")
-
         def leaf(g, p, m, v):
             g = g.astype(jnp.float32)
             p32 = p.astype(jnp.float32)
-            if use_fused:
-                # the whole tail as ONE kernel per leaf — the Mosaic
-                # analogue of the reference's chunked multi_tensor_adam
-                upd, m_new, v_new = fused_adam_tail(
-                    g, m, v, p32, c1, c2, betas=betas, eps=eps,
-                    weight_decay=weight_decay, adam_w_mode=adam_w_mode,
-                    use_pallas=True)
-                return (-step_lr * upd).astype(p.dtype), m_new, v_new
             if not adam_w_mode and weight_decay != 0.0:
                 g = g + weight_decay * p32  # ADAM_MODE_1 (multi_tensor_adam.cu:60)
             m_new = b1 * m + (1.0 - b1) * g
@@ -110,12 +108,21 @@ def FusedAdam(
             upd = mhat / (jnp.sqrt(vhat) + eps)
             if adam_w_mode and weight_decay != 0.0:
                 upd = upd + weight_decay * p32  # ADAM_MODE_0 decoupled decay
-            return (-step_lr * upd).astype(p.dtype), m_new, v_new
+            out = -step_lr * upd
+            if applied:
+                out = p32 + out
+            return out.astype(p.dtype), m_new, v_new
 
         flat = tree_map(leaf, grads, params, state.mu, state.nu)
-        updates = tree_map(lambda t3: t3[0], flat, is_leaf=lambda x: isinstance(x, tuple))
-        mu = tree_map(lambda t3: t3[1], flat, is_leaf=lambda x: isinstance(x, tuple))
-        nu = tree_map(lambda t3: t3[2], flat, is_leaf=lambda x: isinstance(x, tuple))
-        return updates, FusedAdamState(count, mu, nu)
+        out, mu, nu = [
+            tree_map(lambda t3: t3[i], flat, is_leaf=lambda x: isinstance(x, tuple))
+            for i in range(3)]
+        return out, FusedAdamState(count, mu, nu)
 
-    return optax.GradientTransformation(init, update)
+    def update(grads, state, params):
+        return tails(grads, state, params, applied=False)
+
+    def step(grads, state, params):
+        return tails(grads, state, params, applied=True)
+
+    return FusedAdamTransformation(init, update, step)

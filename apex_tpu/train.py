@@ -47,9 +47,10 @@ def train_step_fn(model, mesh):
 
     def update(grads, opt_state, params):
         # the optimizer steps each device's own shards inside the mesh
-        # program: jit's partitioner cannot split FusedAdam's Pallas tail
+        # program, in the shapes and types they have: one fusion a leaf
+        # that writes m, v and p where the donated ones were
         state_specs = opt_state._replace(count=P(), mu=specs, nu=specs)
-        return jax.shard_map(opt.update, mesh=mesh,
+        return jax.shard_map(opt.step, mesh=mesh,
                              in_specs=(specs, state_specs, specs),
                              out_specs=(specs, state_specs))(
                                  grads, opt_state, params)
@@ -57,9 +58,13 @@ def train_step_fn(model, mesh):
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def train_step(params, opt_state, tok, tgt):
         loss, grads = jax.value_and_grad(loss_fn)(params, tok, tgt)
+        # the optimizer's pass starts from gradients in memory: without the
+        # barrier XLA fuses some leaves' tails into the products that make
+        # their gradients, under those products' scopes, and what is read
+        # under ``opt`` is no longer the optimizer's whole cost
+        grads = jax.tree.map(jax.lax.optimization_barrier, grads)
         with span("opt"):
-            updates, opt_state = update(grads, opt_state, params)
-            params = jax.tree.map(lambda p, u: p + u, params, updates)
+            params, opt_state = update(grads, opt_state, params)
         return params, opt_state, loss
 
     def lower(rows: int, seq: int):

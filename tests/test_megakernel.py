@@ -11,8 +11,8 @@ Two fused hot paths, each pinned against the per-op program it replaces:
 * ``ops.fused_update`` — the Adam/LAMB tail kernels must match the
   ``upd`` closure math the ZeRO optimizers ran before fusion, including
   the padding edges (leaves far from tile multiples) and the LAMB
-  trust-ratio composition; ``FusedAdam(fused_tail=...)`` steps must agree
-  end-to-end.
+  trust-ratio composition. (``FusedAdam`` itself runs XLA's fusions since
+  PR 31: ``tests/test_fused_adam_step.py``.)
 
 All stock-jax-safe (interpret-mode Pallas, no mesh); the AOT Mosaic
 lowering rows live in ``tests/test_tpu_lowering.py``.
@@ -571,42 +571,13 @@ def test_lamb_tail_kernel_matches_reference_and_trust_composition():
                                rtol=1e-5, atol=1e-6)
 
 
-def test_fused_adam_optimizer_steps_match():
-    """FusedAdam(fused_tail='on') == FusedAdam(fused_tail='off') over
-    multiple steps — params and moments."""
-    from apex_tpu.optimizers.fused_adam import FusedAdam
-
-    k = jax.random.PRNGKey(2)
-    params = {"w": jax.random.normal(k, (13, 7)),
-              "b": jnp.zeros((5,))}
-    grads = {"w": jax.random.normal(jax.random.fold_in(k, 1), (13, 7)),
-             "b": jax.random.normal(jax.random.fold_in(k, 2), (5,))}
-    outs = {}
-    for mode in ("on", "off"):
-        opt = FusedAdam(lr=1e-2, weight_decay=0.01, fused_tail=mode)
-        st = opt.init(params)
-        p = params
-        for _ in range(3):
-            upd, st = opt.update(grads, st, p)
-            p = jax.tree.map(lambda a, u: a + u, p, upd)
-        outs[mode] = (p, st.mu, st.nu)
-    for a, b in zip(jax.tree.leaves(outs["on"]),
-                    jax.tree.leaves(outs["off"])):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=5e-6, atol=5e-7)
-
-
 def test_resolve_fused_modes():
     assert resolve_fused("off") is False
     assert resolve_fused("on") is True  # pallas importable on this box
     # auto off-TPU: interpret mode saves no dispatch -> stays off
     assert resolve_fused("auto") is False
-    with pytest.raises(ValueError, match="fused_tail"):
-        resolve_fused("bogus", what="fused_tail")
-    from apex_tpu.optimizers.fused_adam import FusedAdam
-
-    with pytest.raises(ValueError, match="fused_tail"):
-        FusedAdam(fused_tail="sometimes")
+    with pytest.raises(ValueError, match="fused_update"):
+        resolve_fused("bogus")
 
 
 def test_decode_kernel_field_reports_actual_path():

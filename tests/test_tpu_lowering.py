@@ -18,8 +18,11 @@ kernel fails CI without a GPU; this is the TPU analogue.
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from apex_tpu.ops._pallas_util import force_compiled
@@ -686,3 +689,65 @@ def test_engine_programs_compile_for_tpu(flagship_serve, megakernel, kernel,
         calls = lowered.as_text().count("tpu_custom_call")
         assert calls >= min_calls, (name, calls)
         assert compiled is not None
+
+
+# -- the train step's optimizer pass (ISSUE 31) -------------------------------------
+
+def _gpt():
+    from apex_tpu.transformer.testing import GPTConfig
+    return GPTConfig(vocab_size=50304, max_seq=1024, hidden=1024, num_layers=2, num_heads=16,
+                     dtype=jnp.bfloat16, remat=True, remat_policy="full"), 16, 1024
+
+
+def _hybrid():
+    from apex_tpu.transformer.hybrid import HybridConfig
+    return HybridConfig(vocab_held=1024, hidden=512, ffn_hidden=1408,
+                        layer_types=("linear_attention",) * 3 + ("full_attention",),
+                        num_heads=4, head_dim=128, linear_heads=30, linear_key_dim=96,
+                        linear_value_dim=192, conv_width=4, chunk=64,
+                        dtype=jnp.bfloat16, remat=True), 2, 1024
+
+
+@pytest.mark.parametrize("model,dp", [(_gpt, 1), (_gpt, 4), (_hybrid, 1)],
+                         ids=["gpt2-dp1", "gpt2-dp4", "hybrid-dp1"])
+def test_the_compiled_train_step_moves_no_leaf_under_opt_and_writes_in_place(model, dp):
+    """At the cells' widths and leaf shapes (depth and, at the hybrid, widths
+    cut): under the scope ``opt`` every instruction that is not one leaf's
+    fusion is smaller than the smallest matrix, no fusion there only moves
+    data, no kernel is left, and the compiled step aliases every leaf of
+    params, ``mu`` and ``nu`` to an output."""
+    from apex_tpu.monitor.trace import split_scope
+    from apex_tpu.ops._pallas_util import compile_for_tpu, tpu_topology_devices
+    from apex_tpu.parallel.mesh import build_mesh
+    from apex_tpu.pyprof.prof import _parse_hlo, _parse_shape, instruction_scopes
+    from apex_tpu.train import abstract_train_args, train_step_fn
+
+    cfg, rows, seq = model()
+    mesh = build_mesh(tp=1, pp=1, sp=1, dp=dp, devices=tpu_topology_devices()[:dp])
+    step, opt = train_step_fn(cfg, mesh)
+    args = abstract_train_args(cfg, opt, mesh, rows * dp, seq)
+    _, compiled = compile_for_tpu(step, *args)
+    text = compiled.as_text()
+
+    leaves = jax.tree.leaves(args[0])
+    n_state = 3 * len(leaves) + 1                     # params, mu, nu, count
+    aliased = {int(i) for i in re.findall(r"\{(\d+)\}: \(\d+, \{\}, (?:may|must)-alias\)",
+                                          text.split("\n", 1)[0])}
+    assert set(range(n_state)) <= aliased, sorted(set(range(n_state)) - aliased)
+
+    matrix = min(int(np.prod(a.shape)) for a in leaves if a.ndim >= 2 and a.shape[-1] >= 128
+                 and int(np.prod(a.shape)) >= 128 * 128)
+    table = instruction_scopes(text)
+    types = {i.name: i.type_str for instrs in _parse_hlo(text)[0].values() for i in instrs}
+    under_opt = {name: rec for name, rec in table.items()
+                 if split_scope(rec["op_name"])[1].split("/")[0] == "opt"}
+    tails = [n for n, rec in under_opt.items()
+             if rec["opcode"] == "fusion" and types[n].startswith("(")]
+    assert len(tails) == len(leaves), (len(tails), len(leaves))
+    for name, rec in under_opt.items():
+        assert rec["opcode"] != "custom-call", name
+        assert not (rec["opcode"] == "fusion" and rec["moves_only"]), name
+        if name not in tails and rec["opcode"] != "get-tuple-element":
+            largest = max((int(np.prod(dims)) for _, dims in _parse_shape(types[name])),
+                          default=0)
+            assert largest < matrix, (name, rec["opcode"], types[name])
