@@ -63,6 +63,46 @@ gated-delta-rule linear-attention layers among full ones; the same
     final_norm                  the RMSNorm after the stack
     lm_head_loss                untied head + cross entropy, fused
 
+Device scopes of the block-diffusion decoder's train step
+(``transformer/sdar.py``: grouped-query rotary attention under the
+block-diffusion mask, routed experts; the same step, so ``opt`` and
+``scan_carry`` as above)::
+
+    noise                       the input pipeline's draws applied: the mask
+                                id where a token is masked, the two copies
+                                side by side, a weight 1/t a masked position
+    embed                       token embedding (rows of the vocabulary held)
+    layer                       one layer, and inside it
+    layer/pre_norm              the RMSNorm before each sublayer
+    layer/attn/qkv              q (all heads), k and v (the fewer K/V heads)
+    layer/attn/qk_norm          RMSNorm over each head's width
+    layer/attn/rope             the rotation of q and k
+    layer/attn/core             the flash call and its layout changes; the
+                                kernels below it as ``.../flash_fwd``,
+                                ``.../flash_bwd_dq``, ``.../flash_bwd_dkv``
+    layer/attn/out              merge of heads and output projection
+    layer/moe/route             router product, softmax, top-k, weights
+    layer/moe/dispatch          the pairs sorted by held expert and laid out
+                                on tiles; their positions' rows gathered
+    layer/moe/experts           the held experts' grouped products and the
+                                gate between them (XLA renames the products
+                                ``ragged-dot-*`` and drops their scope: a
+                                reader adds them here by name)
+    layer/moe/combine           the results weighted and added back
+    layer/residual              the two residual adds
+    final_norm                  the RMSNorm after the stack (noised half)
+    lm_head_loss                untied head + weighted cross entropy, fused
+
+**Counters of a routed layer** (``ROUTING_COUNTERS``; a step's, from the
+held experts' loads that the step itself returns, ``train_step_fn``'s fourth
+result, through ``transformer.moe.routing_facts``): ``pairs_held``
+((position, expert) pairs that landed on an expert held here),
+``pairs_uniform`` (what a uniform router would send), ``max_load_over_mean``
+(among the held experts), ``tiled_rows`` (the rows the experts' spans take in
+the grouped product's buffer), ``passes_run`` (the passes over that buffer),
+``padding_rows`` (rows of those passes that hold no pair), and the batch's
+``masked_positions``.
+
 each under one phase: ``fwd``, ``recompute`` (the forward replayed under
 ``jax.checkpoint``), ``bwd``, or the first user scope where no
 differentiation wraps the operation (``opt``, ``comm``, ...).
@@ -111,6 +151,11 @@ import jax
 # pass (split_scope tells it from "fwd" and "bwd").
 PHASES = ("fwd", "recompute", "bwd", "comm", "opt", "ckpt", "prefill",
           "decode", "transfer", "scrape")
+
+# what a routed layer counts a step (the contract above)
+ROUTING_COUNTERS = ("pairs_held", "pairs_uniform", "max_load_over_mean",
+                    "tiled_rows", "passes_run", "padding_rows",
+                    "masked_positions")
 
 
 @contextlib.contextmanager

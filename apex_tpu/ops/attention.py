@@ -29,6 +29,14 @@ Who loops over the tiles is the *tile schedule*, chosen per shape by
   Every unrolled tile body is compiled, lowered and hashed at each set-up,
   so a kernel holds at most ``_RESIDENT_MAX_BODIES`` of them (a 2 x 2
   rectangle): a call over that, or over the VMEM budget, streams.
+* **listed** — the grid walks a *list* of the live tiles (scalar-prefetched
+  tables of q tile, K/V tile and flags), so a tile the mask hides costs no
+  grid step and no DMA, a whole tile pays no mask, and a tile that straddles
+  an edge builds its mask from positions. What a :class:`MaskStructure`
+  other than ``causal`` takes (the block-diffusion mask), and what grouped
+  heads take (K/V with fewer heads than Q: the K/V block's index map reads
+  head ``i // group``, dK and dV are summed over the group inside the
+  kernel, nothing is repeated in HBM).
 
 Layout: (batch, heads, seq, head_dim) — matches the Megatron attention core
 the transformer layer uses.
@@ -36,12 +44,14 @@ the transformer layer uses.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from jax.experimental import pallas as pl
@@ -56,13 +66,122 @@ from apex_tpu.ops._pallas_util import compiled_backend as _compiled_backend
 
 
 # ---------------------------------------------------------------------------
+# The mask as a structure: which (query, key) pairs see each other, stated so
+# that a tile schedule can tell from the tiles' corners alone which tiles to
+# skip, which are whole and which straddle an edge and build a mask.
+
+@dataclasses.dataclass(frozen=True)
+class MaskStructure:
+    """A static rule over (query position, key position), ``sq == sk``.
+
+    ``causal``: key ``j`` is seen by query ``i`` iff ``j <= i``.
+
+    ``block_diffusion`` (block length ``block``): the sequence is a noised
+    copy followed by the clean copy of ``L = sq // 2`` tokens. With
+    ``blk(i) = (i mod L) // block``, query ``i`` sees key ``j`` iff both are
+    noised and ``blk(j) == blk(i)``; or ``i`` is noised, ``j`` clean and
+    ``blk(j) < blk(i)``; or both are clean and ``blk(j) <= blk(i)``. A clean
+    query sees no noised key.
+
+    :meth:`hidden` is the rule element by element (arrays of positions: the
+    kernels' straddling tiles and the dense reference); :meth:`tile_kinds`
+    is the same rule over whole tiles, from their corners."""
+    kind: str = "causal"
+    block: int = 1
+
+    def __post_init__(self):
+        if self.kind not in ("causal", "block_diffusion"):
+            raise ValueError(f"unknown mask structure {self.kind!r}")
+        if self.block < 1:
+            raise ValueError(f"block must be >= 1, got {self.block}")
+
+    def hidden(self, qpos, kpos, sq: int):
+        """True where query ``qpos`` does NOT see key ``kpos`` (arrays that
+        broadcast against each other: numpy or traced)."""
+        if self.kind == "causal":
+            return kpos > qpos
+        half = sq // 2
+        q_noised, k_noised = qpos < half, kpos < half
+        # positions are never negative, so // and % are shifts and masks
+        # where ``block`` and ``half`` are powers of two
+        qb = (qpos - (qpos >= half) * half) // self.block
+        kb = (kpos - (kpos >= half) * half) // self.block
+        seen = ((q_noised & k_noised & (kb == qb))
+                | (q_noised & ~k_noised & (kb < qb))
+                | (~q_noised & ~k_noised & (kb <= qb)))
+        return ~seen
+
+    def tiles(self, sq: int, sk: int, block_q: int, block_k: int) -> bool:
+        """Whether (block_q, block_k) tiles can carry the rule: a tile
+        must lie within one quadrant of (noised | clean) x (noised | clean)."""
+        if sq != sk:
+            return False
+        if self.kind == "causal":
+            return True
+        half = sq // 2
+        return sq % 2 == 0 and half % block_q == 0 and half % block_k == 0
+
+    def tile_kinds(self, sq: int, block_q: int, block_k: int):
+        """``(live, interior)``: boolean (q tiles, K/V tiles) arrays. A live
+        tile holds a pair that is seen; an interior tile holds only such."""
+        i = np.arange(sq // block_q)[:, None]
+        j = np.arange(sq // block_k)[None, :]
+        q0, q1 = i * block_q, i * block_q + block_q - 1
+        k0, k1 = j * block_k, j * block_k + block_k - 1
+        if self.kind == "causal":
+            return k0 <= q1, k1 <= q0
+        half = sq // 2
+        qn, kn = q0 < half, k0 < half
+        qb0, qb1 = (q0 % half) // self.block, (q1 % half) // self.block
+        kb0, kb1 = (k0 % half) // self.block, (k1 % half) // self.block
+        live = np.where(qn, np.where(kn, (kb0 <= qb1) & (qb0 <= kb1), kb0 < qb1),
+                        ~kn & (kb0 <= qb1))
+        interior = np.where(
+            qn, np.where(kn, (qb0 == qb1) & (kb0 == kb1) & (qb0 == kb0),
+                         kb1 < qb0),
+            ~kn & (kb1 <= qb0))
+        return live, interior
+
+
+CAUSAL = MaskStructure("causal")
+
+
+def block_diffusion_mask(block: int) -> MaskStructure:
+    """The block-diffusion training mask over ``[noised copy ; clean copy]``
+    (:class:`MaskStructure`)."""
+    return MaskStructure("block_diffusion", int(block))
+
+
+def _as_structure(causal) -> Optional[MaskStructure]:
+    """``causal`` as callers give it (a bool, or a structure) -> a structure
+    or None."""
+    if isinstance(causal, MaskStructure):
+        return causal
+    return CAUSAL if causal else None
+
+
+def _repeat_kv(q, k, v):
+    """K and V with each head repeated for the query heads that read it
+    (head ``i`` reads K/V head ``i // group``): the reference path only."""
+    group = q.shape[-3] // k.shape[-3]
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=-3), jnp.repeat(v, group, axis=-3)
+
+
+# ---------------------------------------------------------------------------
 # Pure-JAX reference (ground truth for kernel tests; also the fallback path
 # for arbitrary masks / unaligned shapes — XLA fuses it into a few loops).
 
 def attention_reference(q, k, v, mask=None, scale: Optional[float] = None,
                         causal: bool = False, dropout_rate: float = 0.0,
-                        dropout_key=None, bias=None, dropout_keep=None):
+                        dropout_key=None, bias=None, dropout_keep=None,
+                        structure: Optional[MaskStructure] = None):
     """Plain softmax(QKᵀ·scale + bias)V in fp32 accumulation.
+
+    ``structure``: a :class:`MaskStructure`, built here as the dense mask it
+    states. K and V may have fewer heads than Q (a divisor): query head
+    ``i`` reads K/V head ``i // group``.
 
     ``mask``: broadcastable boolean over (..., sq, sk), True = masked OUT
     (the reference convention, ``apex/contrib/fmha/fmha.py`` cu_seqlens
@@ -76,12 +195,19 @@ def attention_reference(q, k, v, mask=None, scale: Optional[float] = None,
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    if q.ndim >= 3 and k.shape[-3] != q.shape[-3]:
+        k, v = _repeat_kv(q, k, v)
     q32 = q.astype(jnp.float32)
     k32 = k.astype(jnp.float32)
     v32 = v.astype(jnp.float32)
     s = jnp.einsum("...qd,...kd->...qk", q32, k32) * scale
     if bias is not None:
         s = s + bias.astype(jnp.float32)
+    if structure is not None:
+        sq, sk = s.shape[-2], s.shape[-1]
+        qpos = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
+        kpos = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
+        s = jnp.where(structure.hidden(qpos, kpos, sq), NEG_INF, s)
     if causal:
         sq, sk = s.shape[-2], s.shape[-1]
         qpos = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
@@ -211,7 +337,7 @@ class TilePlan(NamedTuple):
     """What a call's kernels run, static per shape. ``visited`` / ``masked``
     / ``rectangle`` count (q tile, K/V tile) pairs per head: computed, built
     with the causal mask, and in the whole (sq, sk) rectangle."""
-    schedule: str  # "resident" | "streamed"
+    schedule: str  # "resident" | "streamed" | "listed"
     block_q: int
     block_k: int
     visited: int
@@ -234,13 +360,51 @@ def _tile_kind(causal, q_i, kv_i, block_q, block_k):
             kv_i * block_k + block_k - 1 <= q_i * block_q)
 
 
+@functools.lru_cache(maxsize=None)
+def _listed_tiles(structure: Optional[MaskStructure], sq, sk, bq, bk):
+    """The live tiles of a listed call as two int32 tables of rows (q tile,
+    K/V tile, flags): in the order the forward and dQ walk them (a q tile's
+    K/V tiles together, ascending) and in the order dK/dV does (a K/V tile's
+    q tiles together). Flags: 1 the first tile of its row (or column), 2 the
+    last, 4 the tile straddles an edge of the mask and builds it."""
+    nq, nk = sq // bq, sk // bk
+    if structure is None:
+        live = np.ones((nq, nk), bool)
+        interior = live
+    else:
+        live, interior = structure.tile_kinds(sq, bq, bk)
+        live, interior = (np.broadcast_to(a, (nq, nk)) for a in (live, interior))
+    if not (live.any(axis=1).all() and live.any(axis=0).all()):
+        raise ValueError(f"mask structure {structure} leaves a whole tile row "
+                         f"or column of ({sq}, {sk}) unseen")
+
+    def table(by_column: bool):
+        grid = live.T if by_column else live
+        outer, inner = np.nonzero(grid)
+        first = np.r_[True, outer[1:] != outer[:-1]]
+        last = np.r_[outer[1:] != outer[:-1], True]
+        qi, kj = (inner, outer) if by_column else (outer, inner)
+        flags = first * 1 + last * 2 + ~interior[qi, kj] * 4
+        return np.stack([qi, kj, flags]).astype(np.int32)
+
+    return table(False), table(True)
+
+
 def _tile_plan(sq, sk, d, dtype, causal, block_q=512, block_k=512,
-               has_bias=False) -> TilePlan:
+               has_bias=False, group=1) -> TilePlan:
     """The one place the schedule and the tile are chosen, from what the call
-    can observe. ``block_q`` / ``block_k`` bound the tile from above; the
-    widest divisor under them is taken on either schedule."""
+    can observe. ``causal`` is the mask's structure: a bool as ever, or a
+    :class:`MaskStructure` (``True`` is ``CAUSAL``). ``group``: query heads a
+    K/V head. ``block_q`` / ``block_k`` bound the tile from above; the
+    widest divisor under them is taken on every schedule."""
+    structure = _as_structure(causal)
     bq, bk = _pick_block(sq, block_q), _pick_block(sk, block_k)
     nq, nk = sq // bq, sk // bk
+    if group > 1 or structure not in (None, CAUSAL):
+        by_row, _ = _listed_tiles(structure, sq, sk, bq, bk)
+        return TilePlan("listed", bq, bk, by_row.shape[1],
+                        int(np.sum(by_row[2] & 4 != 0)), nq * nk)
+    causal = structure is not None
     kinds = [_tile_kind(causal, i, j, bq, bk)
              for i in range(nq) for j in range(nk)]
     visited = sum(live for live, _ in kinds)
@@ -973,6 +1137,270 @@ def _fa_bwd(q3, k3, v3, o3, lse, do3, scale, causal, block_q, block_k,
 
 
 # ---------------------------------------------------------------------------
+# The listed schedule: the grid walks the live tiles (``_listed_tiles``).
+# Scalar-prefetched ``tiles`` is (3, n): q tile, K/V tile, flags.
+
+def _listed_scores(tiles_ref, t, q, k, scale, structure, block_q, block_k,
+                   sq, masked):
+    s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32
+                            ) * scale
+    if masked:
+        qpos = tiles_ref[0, t] * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        kpos = tiles_ref[1, t] * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        s = jnp.where(structure.hidden(qpos, kpos, sq), NEG_INF, s)
+    return s
+
+
+def _on_tile_kind(flags, structure, body):
+    """Run ``body(masked)`` once: with the mask on a tile that straddles an
+    edge, without on a whole one."""
+    if structure is None:
+        body(False)
+        return
+    pl.when(flags & 4 != 0)(functools.partial(body, True))
+    pl.when(flags & 4 == 0)(functools.partial(body, False))
+
+
+def _fa_fwd_listed_kernel(tiles_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                          m_scr, l_scr, acc_scr, *, scale, structure,
+                          block_q, block_k, sq):
+    t = pl.program_id(1)
+    flags = tiles_ref[2, t]
+
+    @pl.when(flags & 1 != 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    def body(masked):
+        v = v_ref[0]
+        s = _listed_scores(tiles_ref, t, q_ref[0], k_ref[0], scale,
+                           structure, block_q, block_k, sq, masked)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = corr * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    _on_tile_kind(flags, structure, body)
+
+    @pl.when(flags & 2 != 0)
+    def _finish():
+        # a row's first live tile shows every query a key (its own block, or
+        # the diagonal), so l >= 1 here: no guard as in the streamed _finish
+        o_ref[0] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
+        # lse leaves lane-dense, a row of block_q (the scratch holds it across
+        # its 128 lanes, so its transpose holds it in every row): a column
+        # (sq, 1) in HBM is padded to 128 lanes, 512 MB at 64 x 16,384
+        lse_ref[0] = jnp.transpose(m_scr[:] + jnp.log(l_scr[:]))[:1]
+
+
+def _listed_ds(tiles_ref, t, q, k, v, do, stats, scale, structure,
+               block_q, block_k, sq, masked):
+    """(p, dL/ds x scale) of one tile, scores recomputed from the saved lse.
+    ``stats`` (2, block_q) holds lse and delta as lane-dense rows; one
+    transpose turns both into the columns the scores need."""
+    rows = jnp.concatenate([jnp.broadcast_to(stats[:1], (8, block_q)),
+                            jnp.broadcast_to(stats[1:], (120, block_q))])
+    cols = jnp.transpose(rows)
+    lse, delta = cols[:, :1], cols[:, 8:9]
+    s = _listed_scores(tiles_ref, t, q, k, scale, structure, block_q,
+                       block_k, sq, masked)
+    p = jnp.exp(s - lse)
+    dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
+    return p, p * (dp - delta) * scale
+
+
+def _fa_bwd_dq_listed_kernel(tiles_ref, q_ref, k_ref, v_ref, do_ref,
+                             stats_ref, dq_ref, dq_scr, *, scale, structure,
+                             block_q, block_k, sq):
+    t = pl.program_id(1)
+    flags = tiles_ref[2, t]
+
+    @pl.when(flags & 1 != 0)
+    def _init():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    def body(masked):
+        k = k_ref[0]
+        _, ds = _listed_ds(tiles_ref, t, q_ref[0], k, v_ref[0], do_ref[0],
+                           stats_ref[0], scale, structure, block_q, block_k,
+                           sq, masked)
+        dq_scr[:] += jax.lax.dot(ds.astype(k.dtype), k,
+                                 preferred_element_type=jnp.float32)
+
+    _on_tile_kind(flags, structure, body)
+
+    @pl.when(flags & 2 != 0)
+    def _finish():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+
+def _fa_bwd_dkv_listed_kernel(tiles_ref, q_ref, k_ref, v_ref, do_ref,
+                              stats_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
+                              scale, structure, block_q, block_k, sq, group):
+    """One K/V head a grid row; a K/V tile's live q tiles, and for each the
+    ``group`` query heads that read this K/V head (innermost), all summed
+    into dK and dV before they are written."""
+    t = pl.program_id(1)
+    g = pl.program_id(2)
+    flags = tiles_ref[2, t]
+
+    @pl.when((flags & 1 != 0) & (g == 0))
+    def _init():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    def body(masked):
+        q, do = q_ref[0], do_ref[0]
+        p, ds = _listed_ds(tiles_ref, t, q, k_ref[0], v_ref[0], do,
+                           stats_ref[0], scale, structure, block_q, block_k,
+                           sq, masked)
+        dv_scr[:] += jax.lax.dot_general(p.astype(do.dtype), do, _TN,
+                                         preferred_element_type=jnp.float32)
+        dk_scr[:] += jax.lax.dot_general(ds.astype(q.dtype), q, _TN,
+                                         preferred_element_type=jnp.float32)
+
+    _on_tile_kind(flags, structure, body)
+
+    @pl.when((flags & 2 != 0) & (g == group - 1))
+    def _finish():
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _listed_params(interpret, semantics):
+    return None if interpret else pltpu.CompilerParams(
+        dimension_semantics=semantics)
+
+
+def _fa_fwd_listed(q3, k3, v3, scale, structure, block_q, block_k, interpret):
+    """(o, lse (bh, 1, sq)); ``k3``, ``v3`` hold ``bh // group`` heads."""
+    bh, sq, d = q3.shape
+    sk = k3.shape[1]
+    group = bh // k3.shape[0]
+    by_row, _ = _listed_tiles(structure, sq, sk, block_q, block_k)
+    row = lambda b, t, tiles: (b, tiles[0, t], 0)
+    kv = lambda b, t, tiles: (b // group, tiles[1, t], 0)
+    return pl.pallas_call(
+        functools.partial(_fa_fwd_listed_kernel, scale=scale,
+                          structure=structure, block_q=block_q,
+                          block_k=block_k, sq=sq),
+        name="flash_fwd",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, by_row.shape[1]),
+            in_specs=[pl.BlockSpec((1, block_q, d), row),
+                      pl.BlockSpec((1, block_k, d), kv),
+                      pl.BlockSpec((1, block_k, d), kv)],
+            out_specs=[pl.BlockSpec((1, block_q, d), row),
+                       pl.BlockSpec((1, 1, block_q),
+                                    lambda b, t, tiles: (b, 0, tiles[0, t]))],
+            scratch_shapes=[pltpu.VMEM((block_q, 128), jnp.float32),
+                            pltpu.VMEM((block_q, 128), jnp.float32),
+                            pltpu.VMEM((block_q, d), jnp.float32)]),
+        out_shape=[_sds((bh, sq, d), q3.dtype, q3, k3, v3),
+                   _sds((bh, 1, sq), jnp.float32, q3, k3, v3)],
+        compiler_params=_listed_params(interpret, ("parallel", "arbitrary")),
+        interpret=interpret,
+    )(jnp.asarray(by_row), q3, k3, v3)
+
+
+def _fa_bwd_listed(q3, k3, v3, o3, lse, do3, scale, structure, block_q,
+                   block_k, interpret):
+    """(dq, dk, dv) on the listed schedule; dk, dv in K/V's own head count."""
+    bh, sq, d = q3.shape
+    bkv, sk, _ = k3.shape
+    group = bh // bkv
+    by_row, by_col = _listed_tiles(structure, sq, sk, block_q, block_k)
+    delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
+                    axis=-1)
+    stats = jnp.concatenate([lse, delta[:, None]], axis=1)     # (bh, 2, sq)
+    static = dict(scale=scale, structure=structure, block_q=block_q,
+                  block_k=block_k, sq=sq)
+    row = lambda b, t, tiles: (b, tiles[0, t], 0)
+    kv = lambda b, t, tiles: (b // group, tiles[1, t], 0)
+    dq = pl.pallas_call(
+        functools.partial(_fa_bwd_dq_listed_kernel, **static),
+        name="flash_bwd_dq",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, by_row.shape[1]),
+            in_specs=[pl.BlockSpec((1, block_q, d), row),
+                      pl.BlockSpec((1, block_k, d), kv),
+                      pl.BlockSpec((1, block_k, d), kv),
+                      pl.BlockSpec((1, block_q, d), row),
+                      pl.BlockSpec((1, 2, block_q),
+                                   lambda b, t, tiles: (b, 0, tiles[0, t]))],
+            out_specs=pl.BlockSpec((1, block_q, d), row),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
+        out_shape=_sds((bh, sq, d), q3.dtype, q3, k3, v3, do3),
+        compiler_params=_listed_params(interpret, ("parallel", "arbitrary")),
+        interpret=interpret,
+    )(jnp.asarray(by_row), q3, k3, v3, do3, stats)
+
+    head = lambda c, t, g, tiles: (c * group + g, tiles[0, t], 0)
+    col = lambda c, t, g, tiles: (c, tiles[1, t], 0)
+    dk, dv = pl.pallas_call(
+        functools.partial(_fa_bwd_dkv_listed_kernel, group=group, **static),
+        name="flash_bwd_dkv",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bkv, by_col.shape[1], group),
+            in_specs=[pl.BlockSpec((1, block_q, d), head),
+                      pl.BlockSpec((1, block_k, d), col),
+                      pl.BlockSpec((1, block_k, d), col),
+                      pl.BlockSpec((1, block_q, d), head),
+                      pl.BlockSpec((1, 2, block_q),
+                                   lambda c, t, g, tiles:
+                                   (c * group + g, 0, tiles[0, t]))],
+            out_specs=[pl.BlockSpec((1, block_k, d), col),
+                       pl.BlockSpec((1, block_k, d), col)],
+            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32)]),
+        out_shape=[_sds((bkv, sk, d), k3.dtype, q3, k3, v3, do3),
+                   _sds((bkv, sk, d), v3.dtype, q3, k3, v3, do3)],
+        compiler_params=_listed_params(
+            interpret, ("parallel", "arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(jnp.asarray(by_col), q3, k3, v3, do3, stats)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash3_listed(q3, k3, v3, scale, structure, block_q, block_k, interpret):
+    o, _ = _fa_fwd_listed(q3, k3, v3, scale, structure, block_q, block_k,
+                          interpret)
+    return o
+
+
+def _flash3_listed_fwd(q3, k3, v3, scale, structure, block_q, block_k,
+                       interpret):
+    o, lse = _fa_fwd_listed(q3, k3, v3, scale, structure, block_q, block_k,
+                            interpret)
+    o = checkpoint_name(o, "attn_out")
+    lse = checkpoint_name(lse, "attn_lse")
+    return o, (q3, k3, v3, o, lse)
+
+
+def _flash3_listed_bwd(scale, structure, block_q, block_k, interpret, res,
+                       do3):
+    q3, k3, v3, o3, lse = res
+    return _fa_bwd_listed(q3, k3, v3, o3, lse, do3, scale, structure,
+                          block_q, block_k, interpret)
+
+
+_flash3_listed.defvjp(_flash3_listed_fwd, _flash3_listed_bwd)
+
+
+# ---------------------------------------------------------------------------
 # custom_vjp plumbing over (bh, seq, d) arrays
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
@@ -1054,6 +1482,45 @@ def flash_attention_with_lse(q3, k3, v3, scale, causal, block_q, block_k,
 # ---------------------------------------------------------------------------
 # Public API
 
+def _flash_listed(q, k, v, mask, structure, scale, block_q, block_k,
+                  use_pallas, dropout_rate, bias, interpret):
+    """``flash_attention`` for a structure other than causal, or grouped
+    heads: the listed kernels where the shapes tile, the reference (dense
+    mask, K/V repeated) elsewhere."""
+    b, h, sq, d = q.shape
+    hk, sk = k.shape[1], k.shape[2]
+    if dropout_rate > 0.0 or bias is not None:
+        raise NotImplementedError(
+            "attention dropout and the additive bias are not written for "
+            "the listed schedule (a MaskStructure other than causal, or "
+            "K/V with fewer heads than Q)")
+    bq, bk = _pick_block(sq, block_q), _pick_block(sk, block_k)
+    tiles = (mask is None and _pick_block(sq, 128) is not None
+             and _pick_block(sk, 128) is not None and d % 8 == 0
+             and (structure is None or structure.tiles(sq, sk, bq, bk)))
+    if use_pallas is None:
+        use_pallas = tiles and _compiled_backend()
+    elif use_pallas and not tiles:
+        raise ValueError(
+            f"pallas flash_attention with structure={structure} needs no "
+            f"dense mask, seq divisible by a block size, head_dim % 8 == 0 "
+            f"and tiles that lie within the structure's quadrants "
+            f"(got q {q.shape}, k {k.shape}, tile {bq} x {bk})")
+    if not use_pallas:
+        if interpret is not None:
+            raise ValueError(
+                "interpret= only applies to the Pallas path; this call "
+                "resolved to the reference")
+        return attention_reference(q, k, v, mask=mask, scale=scale,
+                                   structure=structure)
+    if interpret is None:
+        interpret = not _compiled_backend()
+    o3 = _flash3_listed(q.reshape(b * h, sq, d), k.reshape(b * hk, sk, d),
+                        v.reshape(b * hk, sk, d), scale, structure, bq, bk,
+                        interpret)
+    return o3.reshape(b, h, sq, d)
+
+
 def _pick_block(seq: int, want: int) -> Optional[int]:
     for cand in (want, 512, 256, 128, 64, 32, 16, 8):
         if cand <= want and seq % cand == 0:
@@ -1083,13 +1550,24 @@ def flash_attention(
     dropout_seed=None,
     bias=None,
     interpret: Optional[bool] = None,
+    structure: Optional[MaskStructure] = None,
 ):
     """Memory-efficient attention over (batch, heads, seq, head_dim).
 
-    Pallas flash kernel for the causal / no-mask cases on aligned shapes
-    (ref capability: ``fmhalib`` + ``fast_multihead_attn``, without their
-    seqlen ≤ 512 limit); XLA reference path for arbitrary ``mask`` or odd
-    shapes. ``mask`` True = masked out.
+    Pallas flash kernels on aligned shapes (ref capability: ``fmhalib`` +
+    ``fast_multihead_attn``, without their seqlen ≤ 512 limit) for no mask
+    and for a mask given as a **structure** (:class:`MaskStructure`):
+    ``causal=True`` is ``structure=CAUSAL`` and runs the kernels it always
+    ran; :func:`block_diffusion_mask` (or any other structure) runs the
+    listed schedule, which skips the tiles the structure hides and builds a
+    mask only on the tiles that straddle one of its edges. An arbitrary
+    dense ``mask`` (True = masked out) or an odd shape takes the XLA
+    reference path, which materialises the scores.
+
+    ``k`` and ``v`` may have fewer heads than ``q`` (a divisor of its head
+    count): query head ``i`` reads K/V head ``i // group``. On the kernels
+    that is the K/V block's index map (the listed schedule); nothing is
+    repeated in HBM and ``dk``, ``dv`` come back in K/V's own head count.
 
     ``bias``: optional batch-shared additive logit bias of shape
     (heads, sq, sk) — the T5 relative-position-bias contract. It rides the
@@ -1109,10 +1587,23 @@ def flash_attention(
     """
     b, h, sq, d = q.shape
     sk = k.shape[2]
+    hk = k.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if dropout_rate > 0.0 and dropout_seed is None:
         raise ValueError("dropout_rate > 0 needs dropout_seed")
+    if structure is not None and causal and structure != CAUSAL:
+        raise ValueError(f"causal=True and structure={structure} are two "
+                         f"masks; give one")
+    if hk != v.shape[1] or h % hk:
+        raise ValueError(f"K/V heads ({hk}, {v.shape[1]}) must be equal and "
+                         f"divide the query heads ({h})")
+    if structure == CAUSAL:
+        structure, causal = None, True      # the kernels causal always ran
+    if structure is not None or hk != h:
+        return _flash_listed(q, k, v, mask, CAUSAL if causal else structure,
+                             scale, block_q, block_k, use_pallas,
+                             dropout_rate, bias, interpret)
     if bias is not None and bias.shape != (h, sq, sk):
         raise ValueError(
             f"bias must be batch-shared (heads, sq, sk) = {(h, sq, sk)}, "
@@ -1124,8 +1615,10 @@ def flash_attention(
             sq, sk, d, causal, allow_interpret=False)
     elif use_pallas and not pallas_possible:
         raise ValueError(
-            f"pallas flash_attention needs mask=None, seq divisible by a "
-            f"block size, head_dim % 8 == 0, and sq == sk when causal "
+            f"pallas flash_attention needs no dense mask (a mask rides the "
+            f"kernels as a MaskStructure: causal, block_diffusion_mask), "
+            f"seq divisible by a block size, head_dim % 8 == 0, and "
+            f"sq == sk when causal "
             f"(got q {q.shape}, k {k.shape}, causal={causal}, "
             f"mask={'set' if mask is not None else None})")
     if not use_pallas:

@@ -245,9 +245,11 @@ def _run_bwd(x2, w, t_local, lse, g, block_n, block_v, interpret):
     # the (block_n, block_v) score tile stay inside VMEM.
     bn_dw = 512 if block_n > 512 and n % 512 == 0 else block_n
     # only widen the vocab block while the (bv_dw, h) fp32 accumulator stays
-    # within a conservative VMEM budget (cf. layer_norm's _VMEM_BUDGET_BYTES)
+    # within a conservative VMEM budget (cf. layer_norm's _VMEM_BUDGET_BYTES);
+    # strictly: at hidden 2048 the accumulator is the whole 8 MiB and the
+    # kernel's tile set overflows the 32 MiB scoped limit
     bv_dw = block_v
-    if block_v < 1024 <= v and 1024 * h * 4 <= 8 * 1024 * 1024:
+    if block_v < 1024 <= v and 1024 * h * 4 < 8 * 1024 * 1024:
         bv_dw = 1024  # never wider than the vocab shard (caller clamps ≤ v)
     nn_dw, nv_dw = _grids(n, v, bn_dw, bv_dw)
 
@@ -431,6 +433,7 @@ def lm_head_loss(
     block_n: int = DEFAULT_BLOCK_N,
     block_v: int = DEFAULT_BLOCK_V,
     use_pallas: Optional[bool] = None,
+    weights=None,
 ):
     """Per-position CE of the projection ``x @ wᵀ`` without materializing it.
 
@@ -438,6 +441,13 @@ def lm_head_loss(
     int global ids. Returns fp32 loss shaped like ``targets``. Differentiable
     in ``x`` and ``w``; under TP (``axis_name``) ``dx`` is the local partial
     (reduced by the enclosing copy-to-region transpose, Megatron-style).
+
+    ``weights``: (...) a weight a position (0 where a position is not
+    scored); the result is ``weights * CE``. The forward is the one pass it
+    always was, and the backward kernels take the weight through the
+    cotangent a row they already scale ``p - onehot`` by: no second pass
+    over the logits, and a position of weight 0 adds nothing to ``dx`` or
+    ``dw``.
     """
     h = x.shape[-1]
     lead = x.shape[:-1]
@@ -461,4 +471,6 @@ def lm_head_loss(
         impl = "dense"
     loss = _lm_head_loss(x2, w, t1, axis_name, bn, min(block_v, w.shape[0]),
                          impl)
+    if weights is not None:
+        loss = loss * weights.reshape(-1).astype(jnp.float32)
     return loss.reshape(lead)

@@ -10,8 +10,12 @@ A model is any object with three methods:
   ``shard_map`` over the mesh (parameters per ``param_specs()``, the batch
   split over ``dp``).
 
-``transformer.testing.GPTConfig`` and ``transformer.hybrid.HybridConfig``
-both are.
+``transformer.testing.GPTConfig``, ``transformer.hybrid.HybridConfig`` and
+``transformer.sdar.SDARConfig`` are. A model that counts what its step did
+(a routed layer's loads) has a fourth, ``loss_and_counters(params, tokens,
+targets)``: the loss and a pytree of arrays whose leading axis stacks over
+``dp``. Its step returns them fourth, from the step itself and at no pass of
+their own.
 """
 
 from __future__ import annotations
@@ -35,15 +39,20 @@ def train_step_fn(model, mesh):
 
     specs = model.param_specs()
     opt = FusedAdam(lr=1e-4)
+    counted = hasattr(model, "loss_and_counters")
 
     def loss_fn(p, tok, tgt):
         def body(p, tok, tgt):
+            if counted:
+                loss, counters = model.loss_and_counters(p, tok, tgt)
+                return replicate_loss(loss, mesh, masked_axis=None), counters
             return replicate_loss(model.loss(p, tok, tgt), mesh,
                                   masked_axis=None)
 
         return jax.shard_map(body, mesh=mesh,
                              in_specs=(specs, P("dp"), P("dp")),
-                             out_specs=P())(p, tok, tgt)
+                             out_specs=(P(), P("dp")) if counted else P())(
+                                 p, tok, tgt)
 
     def update(grads, opt_state, params):
         # the optimizer steps each device's own shards inside the mesh
@@ -57,7 +66,9 @@ def train_step_fn(model, mesh):
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def train_step(params, opt_state, tok, tgt):
-        loss, grads = jax.value_and_grad(loss_fn)(params, tok, tgt)
+        # ``out`` is the loss, or (loss, counters) of a model that counts
+        out, grads = jax.value_and_grad(loss_fn, has_aux=counted)(
+            params, tok, tgt)
         # the optimizer's pass starts from gradients in memory: without the
         # barrier XLA fuses some leaves' tails into the products that make
         # their gradients, under those products' scopes, and what is read
@@ -65,7 +76,9 @@ def train_step_fn(model, mesh):
         grads = jax.tree.map(jax.lax.optimization_barrier, grads)
         with span("opt"):
             params, opt_state = update(grads, opt_state, params)
-        return params, opt_state, loss
+        if counted:
+            return (params, opt_state, *out)
+        return params, opt_state, out
 
     def lower(rows: int, seq: int):
         """The step lowered at the shapes and shardings a job hands it,

@@ -23,6 +23,10 @@ out in order, each under ``jax.checkpoint``. The model holds
 vocabulary divided by rows over several chips is a smaller vocabulary: ids
 and the loss are over the rows held).
 
+``transformer/sdar.py`` (block-diffusion training through grouped-query rotary
+attention and routed experts) is a sibling that runs the same stack
+(:func:`run_stack`) with a layer and a loss of its own.
+
 Data parallelism works as for GPT (replicated parameters, the batch over
 ``dp``). Tensor parallelism is not written for these mixers and ``tp > 1`` is
 refused. The train step is ``bench.train_step_fn``'s: this config meets the
@@ -257,16 +261,45 @@ def _layer(p, x, cfg: HybridConfig, kind: str):
 
 
 def _refuse_tp():
+    refuse_tp("the hybrid model's mixers are",
+              "the delta rule's heads, its convolutions and the QK-norm over "
+              "the whole projection would each need a split of their own")
+
+
+def run_stack(x, periods, period: Tuple[str, ...], layer, remat: bool):
+    """The stack every model kept by layer kind shares: a ``lax.scan`` over
+    ``periods`` (``{kind: {leaf: (periods, that kind's layers in a period,
+    ...)}}``) whose body writes one period's layers out in the order of
+    ``period``, each ``layer(kind, leaves, h) -> h`` under the scope
+    ``layer`` and, with ``remat``, under ``jax.checkpoint``."""
+    def layer_fn(kind):
+        fn = lambda lp, h: layer(kind, lp, h)
+        return jax.checkpoint(fn) if remat else fn
+
+    fns = {kind: layer_fn(kind) for kind in dict.fromkeys(period)}
+
+    def body(h, leaves):
+        seen = dict.fromkeys(fns, 0)
+        for kind in period:
+            lp = jax.tree.map(lambda a: a[seen[kind]], leaves[kind])
+            seen[kind] += 1
+            with span("layer"):
+                h = fns[kind](lp, h)
+        return h, None
+
+    x, _ = lax.scan(body, x, periods)
+    return x
+
+
+def refuse_tp(what: str, why: str):
     try:
         tp = lax.axis_size(TP_AXIS)
     except NameError:
         return
     if tp > 1:
         raise NotImplementedError(
-            f"the hybrid model's mixers are not written for tensor "
-            f"parallelism (tp = {tp}): the delta rule's heads, its "
-            f"convolutions and the QK-norm over the whole projection would "
-            f"each need a split of their own. Use dp, or tp = 1.")
+            f"{what} not written for tensor parallelism (tp = {tp}): "
+            f"{why}. Use dp, or tp = 1.")
 
 
 def hybrid_hidden(params, tokens, cfg: HybridConfig):
@@ -274,24 +307,8 @@ def hybrid_hidden(params, tokens, cfg: HybridConfig):
     _refuse_tp()
     with span("embed"):
         x = jnp.take(params["embed"]["tok"], tokens, axis=0)
-
-    def layer_fn(kind):
-        fn = lambda lp, h: _layer(lp, h, cfg, kind)
-        return jax.checkpoint(fn) if cfg.remat else fn
-
-    fns = {kind: layer_fn(kind) for kind in dict.fromkeys(cfg.period)}
-
-    def body(h, period):
-        seen = dict.fromkeys(fns, 0)
-        for kind in cfg.period:
-            lp = jax.tree.map(lambda a: a[seen[kind]], period[kind])
-            seen[kind] += 1
-            with span("layer"):
-                h = fns[kind](lp, h)
-        return h, None
-
-    x, _ = lax.scan(body, x, params["periods"])
-    return x
+    return run_stack(x, params["periods"], cfg.period,
+                     lambda kind, lp, h: _layer(lp, h, cfg, kind), cfg.remat)
 
 
 def hybrid_loss(params, tokens, targets, cfg: HybridConfig):

@@ -25,6 +25,15 @@ Routing math (fp32, regardless of model dtype): top-k gates, normalized
 over the selected k (GShard top-2 convention), position-in-expert by
 priority cumsum (all ranks' top-1 choices outrank top-2), load-balance
 auxiliary loss ``E · Σ_e f_e · p̄_e`` (Switch eq. 4) and router z-loss.
+
+That is :func:`moe_mlp` (top-2, GELU experts, a capacity
+(``MoEConfig.capacity``) and drops in ``_route``), which
+``standalone_gpt.py`` runs. Beside it :func:`routed_experts_mlp` is the layer
+of the softmax-routed models with many small experts
+(``transformer/sdar.py``): **no capacity and no drops**, SiLU-gated experts,
+weights renormalised over the chosen, and the layer told which of the
+router's experts it holds (``experts_held``): one chip's share of an
+expert-parallel deployment, computed without the exchange.
 """
 
 from __future__ import annotations
@@ -34,8 +43,11 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
+from apex_tpu.monitor.trace import span
+from apex_tpu.ops._pallas_util import pvary_like
 from apex_tpu.parallel.mesh import DP_AXIS, TP_AXIS
 from apex_tpu.transformer.tensor_parallel.mappings import (
     copy_to_tensor_model_parallel_region,
@@ -256,3 +268,278 @@ def _local_experts(params, ep_axis: str, e_local: int) -> Pytree:
         k: lax.dynamic_slice_in_dim(params[k], start, e_local, 0)
         for k in ("fc1_kernel", "fc1_bias", "fc2_kernel", "fc2_bias")
     }
+
+
+# ---------------------------------------------------------------------------
+# routed experts without drops, for the experts held here
+
+F32 = jnp.float32
+
+
+# A held expert's rows start on a multiple of the tile, so that no tile of
+# the grouped product holds two experts and the product's work changes only
+# when a load crosses a tile's edge. The tile is the largest power of two
+# within half an expert's mean load (the tiles' tails then pad the buffer by
+# a quarter of the pairs on average), and no more than TILE_ROWS: at the
+# benchmark's cell a data token's expert sees 1,533 +- 39 pairs a step, three
+# 512-row tiles to within a standard deviation and two of 1,024 on every seed
+# (my chip runs, PR 33).
+TILE_ROWS = 1024
+# The buffer a pass fills, over what a uniform router would send here.
+BUFFER_OVER_MEAN = 1.5
+
+
+@dataclasses.dataclass(frozen=True)
+class RoutedExpertsConfig:
+    """What :func:`routed_experts_mlp` needs beside its leaves.
+
+    The (position, expert) pairs that land on a held expert are laid out by
+    expert in a buffer of ``rows_per_pass`` rows, **each expert's rows
+    starting on a multiple of ``tile_rows``** (the grouped product's row
+    tile on the chip). The buffer is ``BUFFER_OVER_MEAN`` times what a
+    uniform router would send, so the gathers, the products and the
+    elementwise passes follow the pairs held and not the worst case; pairs
+    beyond it (a router far from uniform) are run by further passes over the
+    same buffer, each skipped by a conditional while there is nothing left:
+    exact at any imbalance."""
+    num_experts: int = 128          # the router's range
+    top_k: int = 8
+
+    def tile_rows(self, tokens: int) -> int:
+        half = tokens * self.top_k // (2 * self.num_experts)
+        return min(TILE_ROWS, max(8, 1 << max(half, 1).bit_length() - 1))
+
+    def rows_per_pass(self, tokens: int, count: int) -> int:
+        tile = self.tile_rows(tokens)
+        mean = tokens * self.top_k * count / self.num_experts
+        want = -(-int(BUFFER_OVER_MEAN * mean) // tile) * tile
+        return max(tile, min(want, self.worst_rows(tokens, count)))
+
+    def worst_rows(self, tokens: int, count: int) -> int:
+        """Every position choosing as many held experts as it can, every
+        expert's last tile holding one pair."""
+        tile = self.tile_rows(tokens)
+        rows = tokens * min(self.top_k, count) + count * (tile - 1)
+        return -(-rows // tile) * tile
+
+    def passes(self, tokens: int, count: int) -> int:
+        return -(-self.worst_rows(tokens, count)
+                 // self.rows_per_pass(tokens, count))
+
+
+def routed_expert_shapes(hidden: int, width: int, num_experts: int,
+                         count: int) -> dict:
+    """The leaves of :func:`routed_experts_mlp`: the router over all
+    ``num_experts``, the ``count`` experts held, stacked."""
+    return {"router": (hidden, num_experts),
+            "w_gate": (count, hidden, width), "w_up": (count, hidden, width),
+            "w_down": (count, width, hidden)}
+
+
+def route_softmax_top_k(x, router, top_k: int):
+    """``(idx, weight)``, both (tokens, top_k): ``s = softmax(x W_r)`` over
+    every expert in float32, the ``top_k`` largest, ``w_i = s_i / sum of the
+    chosen s`` (``norm_topk_prob``)."""
+    logits = jnp.dot(x.astype(F32), router.astype(F32),
+                     precision=lax.Precision.HIGHEST)
+    chosen, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return idx, chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+
+
+def _rows_at(a, rank):
+    """``a[rank]`` where ``0 <= rank < rows of a``, nought elsewhere (a pair
+    of an expert not held, or of another pass, has no row here)."""
+    n = a.shape[0]
+    ok = (rank >= 0) & (rank < n)
+    got = jnp.take(a, jnp.clip(rank, 0, n - 1), axis=0)
+    return jnp.where(ok[..., None], got, jnp.zeros((), a.dtype))
+
+
+@jax.custom_vjp
+def _to_rows(x, pair, rank):
+    """``xs[r] = x[pair[r] // k]``: row ``r`` gets the position of the pair
+    it holds (a row that holds none, ``pair`` -1, gets position 0: computed,
+    never used). ``rank`` (tokens, k) is the row each pair has in this pass,
+    so the cotangent is a gather too and nothing is scattered."""
+    return jnp.take(x, jnp.maximum(pair, 0) // rank.shape[1], axis=0)
+
+
+def _to_rows_fwd(x, pair, rank):
+    return _to_rows(x, pair, rank), rank
+
+
+def _over_places(rank, init, step):
+    """``step(acc, rank[:, j], j)`` for each place ``j`` in turn (a scan, so
+    that one place's gathered rows exist at a time, not ``k`` of them)."""
+    k = rank.shape[1]
+    return lax.scan(lambda acc, rj: step(acc, *rj), init,
+                    (rank.T, jnp.arange(k)))
+
+
+def _to_rows_bwd(rank, dxs):
+    dx, _ = _over_places(
+        rank, pvary_like(jnp.zeros((rank.shape[0], dxs.shape[1]), F32), dxs),
+        lambda acc, r, j: (acc + _rows_at(dxs, r).astype(F32), None))
+    return dx.astype(dxs.dtype), None, None
+
+
+_to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
+
+
+@jax.custom_vjp
+def _from_rows(ys, weight, pair, rank):
+    """``y[t] = sum_j weight[t, j] ys[rank[t, j]]``, summed in float32 and
+    returned in ``ys``' type, over the pairs that have a row in this pass: a
+    gather a place, in both directions. A row that holds no pair takes no
+    cotangent."""
+    y, _ = _over_places(
+        rank, pvary_like(jnp.zeros((rank.shape[0], ys.shape[1]), F32), ys),
+        lambda acc, r, j: (acc + jnp.take(weight, j, axis=1)[:, None]
+                           * _rows_at(ys, r).astype(F32), None))
+    return y.astype(ys.dtype)
+
+
+def _from_rows_fwd(ys, weight, pair, rank):
+    return _from_rows(ys, weight, pair, rank), (ys, weight, pair, rank)
+
+
+def _from_rows_bwd(res, dy):
+    ys, weight, pair, rank = res
+    k = rank.shape[1]
+    at = jnp.maximum(pair, 0)
+    w = jnp.where(pair >= 0, jnp.take(weight.reshape(-1), at), 0.0)
+    dys = (w[:, None] * jnp.take(dy, at // k, axis=0).astype(F32)
+           ).astype(ys.dtype)
+    _, dw = _over_places(
+        rank, None,
+        lambda acc, r, j: (acc, jnp.sum(dy.astype(F32)
+                                        * _rows_at(ys, r).astype(F32), axis=-1)))
+    dw = dw.T
+    return dys, dw, None, None
+
+
+_from_rows.defvjp(_from_rows_fwd, _from_rows_bwd)
+
+
+def grouped_matmul(xs, w, sizes):
+    """Rows of ``xs`` (n, a) in groups of ``sizes`` (one an expert, in
+    order, from the first row on), each times its group's ``w[g]`` (a, b):
+    ``lax.ragged_dot``, which XLA runs on the chip as a grouped product of
+    its own that visits the row tiles the groups fill and no others (its
+    rewrite names the instruction ``ragged-dot-*``). Rows past the groups'
+    end are not read and not relied on."""
+    return lax.ragged_dot(xs, w, sizes)
+
+
+def _layout(key, count: int, tile: int):
+    """Where the (position, place) pairs lie. ``key`` (pairs,) is each
+    pair's held expert, ``count`` for an expert not held. Returns ``(order,
+    rank, sizes, first_place, first_row, spans)``: the pairs sorted by
+    expert (stable), the row each pair has in the tiled layout (a large
+    number for a pair not held), each expert's load, where its pairs start
+    among the sorted ones, the row its span starts on and the span's length
+    (the load rounded up to whole tiles)."""
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    place = jnp.argsort(order).astype(jnp.int32)
+    sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
+                    dtype=jnp.int32)
+    spans = -(-sizes // tile) * tile
+    first_place = jnp.cumsum(sizes) - sizes
+    first_row = jnp.cumsum(spans) - spans
+    shift = jnp.take(first_row - first_place, jnp.minimum(key, count - 1))
+    rank = jnp.where(key < count, place + shift, jnp.iinfo(jnp.int32).max // 2)
+    return order, rank, sizes, first_place, first_row, spans
+
+
+def routed_experts_mlp(p, x, cfg: RoutedExpertsConfig,
+                       experts_held: Tuple[int, int]):
+    """``sum over the chosen experts held here of w_i E_i(x)`` over ``x``
+    (..., hidden), ``E(x) = (SiLU(x W_gate) * (x W_up)) W_down``.
+
+    ``p``: :func:`routed_expert_shapes`' leaves. ``experts_held = (first,
+    count)``: the experts ``first .. first + count - 1`` of the router's
+    range are ``p["w_gate"][0 .. count - 1]`` and so on. Every position is
+    routed over all ``cfg.num_experts`` and its weights are normalised over
+    all ``cfg.top_k`` it chose; what the experts not held would add is left
+    out, and no code stands in for the chips that hold them.
+
+    **No position is dropped, at any imbalance**, shapes are static, and the
+    work follows the pairs held (:class:`RoutedExpertsConfig`): the first
+    pass runs outright; each pass after it is a conditional (the pass under
+    ``jax.checkpoint``, so that it keeps nothing) that runs only while pairs
+    are left.
+
+    Returns ``(y, loads)``: ``loads`` (count,) int32 is how many pairs each
+    held expert took, the layout's own count (:func:`routing_facts` reads a
+    step's counters from it)."""
+    lead, h = x.shape[:-1], x.shape[-1]
+    xf = x.reshape(-1, h)
+    t, k = xf.shape[0], cfg.top_k
+    first, count = experts_held
+    tile = cfg.tile_rows(t)
+    n = cfg.rows_per_pass(t, count)
+    with span("moe/route"):
+        idx, weight = route_softmax_top_k(xf, p["router"], k)
+    with span("moe/dispatch"):
+        local = idx - first
+        key = jnp.where((local >= 0) & (local < count), local, count
+                        ).reshape(-1)
+        order, rank, sizes, first_place, first_row, spans = _layout(
+            key, count, tile)
+        rank = rank.reshape(t, k)
+        total = jnp.sum(spans)
+
+    def one_pass(c):
+        lo = c * n
+        with span("moe/dispatch"):
+            r = lo + jnp.arange(n, dtype=jnp.int32)
+            g = jnp.minimum(jnp.sum(r[:, None] >= (first_row + spans)[None, :],
+                                    axis=1), count - 1)
+            at = r - jnp.take(first_row, g)
+            pair = jnp.where(
+                at < jnp.take(sizes, g),
+                jnp.take(order, jnp.minimum(jnp.take(first_place, g) + at,
+                                            t * k - 1)), -1)
+            rows = (jnp.clip(first_row + spans, lo, lo + n)
+                    - jnp.clip(first_row, lo, lo + n))
+            here = rank - lo
+            xs = _to_rows(xf, pair, here)
+        with span("moe/experts"):
+            gate = grouped_matmul(xs, p["w_gate"], rows)
+            up = grouped_matmul(xs, p["w_up"], rows)
+            act = (jax.nn.silu(gate.astype(F32)) * up.astype(F32)
+                   ).astype(x.dtype)
+            ys = grouped_matmul(act, p["w_down"], rows)
+        with span("moe/combine"):
+            return _from_rows(ys, weight, pair, here)
+
+    y = one_pass(jnp.int32(0))
+    later = jax.checkpoint(one_pass)
+    for c in range(1, cfg.passes(t, count)):
+        with span("moe/combine"):
+            y = y + lax.cond(c * n < total, later,
+                             lambda c: jnp.zeros_like(y), jnp.int32(c))
+    return y.reshape(*lead, h), sizes
+
+
+def routing_facts(loads, tokens: int, cfg: RoutedExpertsConfig) -> dict:
+    """What a layer's routing did in one step, from the held experts' loads
+    (:func:`routed_experts_mlp`'s second result) over ``tokens`` positions,
+    as plain numbers: ``pairs_held`` (position, place) pairs that landed on
+    an expert held here and ``pairs_uniform``, what a uniform router would
+    send; ``max_load_over_mean`` among the held experts; ``tiled_rows``, the
+    rows the experts' spans take (each load rounded up to whole tiles);
+    ``passes_run``, the passes over the buffer the layer ran; and
+    ``padding_rows``, the rows of those passes that hold no pair (the tiles'
+    tails and the room past the last expert)."""
+    loads = np.asarray(loads)
+    tile = cfg.tile_rows(tokens)
+    n = cfg.rows_per_pass(tokens, len(loads))
+    tiled = int((-(-loads // tile) * tile).sum())
+    passes = max(1, -(-tiled // n))
+    return {"pairs_held": int(loads.sum()),
+            "pairs_uniform": tokens * cfg.top_k * len(loads) / cfg.num_experts,
+            "max_load_over_mean": float(loads.max() / max(loads.mean(), 1e-9)),
+            "tiled_rows": tiled,
+            "padding_rows": n * passes - int(loads.sum()),
+            "passes_run": passes}

@@ -1,0 +1,13 @@
+"""Of the routed layer's device time a step (``layer/moe/*``), the share that
+is not the experts' products: routing, sorting and laying out the pairs,
+gathering their rows and adding the results back. What a grouped product of
+the program's own, or a cheaper layout, would have to win back."""
+import scopes_sdar
+
+
+def read(facts, trace):
+    got = scopes_sdar.moe_seconds(facts, trace)
+    if got is None:
+        return None
+    whole = sum(got[p] for p in scopes_sdar.PARTS)
+    return 100.0 * (whole - got["experts"]) / whole if whole > 0 else None

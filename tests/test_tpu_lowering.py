@@ -128,6 +128,40 @@ def test_flash_attention_compiles_on_both_schedules(seq, schedule):
             kernel, calls)
 
 
+@pytest.mark.parametrize("structure,visited", [("blockdiff", 288), ("causal", 528)])
+def test_flash_attention_listed_schedule_compiles_at_the_block_diffusion_cells_shape(
+        structure, visited):
+    """The listed schedule (a ``MaskStructure`` other than causal, or grouped
+    heads) at the block-diffusion cell's attention shape: 32 query heads over
+    4 key/value heads of 128, 16,384 positions, bf16. The three kernels go
+    through Mosaic's own compiler for a described v5e (scalar-prefetched tile
+    tables, the lane-dense lse and its in-kernel transposes, dK and dV summed
+    over a group) under the names the trace and ``blockdiff_attn_roofline``
+    read; lse and delta cost 32 MiB, not the GiB a column layout pads to."""
+    from apex_tpu.ops._pallas_util import compile_for_tpu, mosaic_calls
+    from apex_tpu.ops.attention import (CAUSAL, _tile_plan, block_diffusion_mask,
+                                        flash_attention)
+
+    mask = block_diffusion_mask(4) if structure == "blockdiff" else CAUSAL
+    plan = _tile_plan(16384, 16384, 128, jnp.bfloat16, mask, group=8)
+    assert (plan.schedule, plan.visited) == ("listed", visited)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, structure=mask).astype(jnp.float32) ** 2)
+
+    q = jax.ShapeDtypeStruct((1, 32, 16384, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 4, 16384, 128), jnp.bfloat16)
+    _, compiled = compile_for_tpu(jax.jit(jax.grad(loss, argnums=(0, 1, 2))), q, kv, kv)
+    calls = mosaic_calls(compiled.as_text())
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert sum(n for name, n in calls.items() if kernel in name) == 1, (kernel, calls)
+    dq, dk, dv = compiled.output_shardings and jax.eval_shape(
+        jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert dq.shape == q.shape and dk.shape == dv.shape == kv.shape
+    # q, its gradient, o and do are 128 MiB each; the statistics must stay small
+    assert compiled.memory_analysis().temp_size_in_bytes < 400 * 2**20
+
+
 # Characters of the lowered text (StableHLO with the three kernels' Mosaic
 # payloads) of one ``jax.checkpoint``ed flash forward-and-backward at the
 # benchmark cells' shape (16 x 20 heads, s 1024, d 64, bf16, causal), at the
@@ -708,8 +742,15 @@ def _hybrid():
                         dtype=jnp.bfloat16, remat=True), 2, 1024
 
 
-@pytest.mark.parametrize("model,dp", [(_gpt, 1), (_gpt, 4), (_hybrid, 1)],
-                         ids=["gpt2-dp1", "gpt2-dp4", "hybrid-dp1"])
+def _sdar():
+    from apex_tpu.transformer.sdar import SDARConfig
+    return SDARConfig(vocab_held=1024, hidden=512, num_layers=2, num_heads=8, num_kv_heads=1,
+                      head_dim=128, num_experts=16, experts_held=(0, 4), top_k=2,
+                      expert_hidden=768, mask_id=1023, dtype=jnp.bfloat16), 2, 1024
+
+
+@pytest.mark.parametrize("model,dp", [(_gpt, 1), (_gpt, 4), (_hybrid, 1), (_sdar, 1)],
+                         ids=["gpt2-dp1", "gpt2-dp4", "hybrid-dp1", "sdar-dp1"])
 def test_the_compiled_train_step_moves_no_leaf_under_opt_and_writes_in_place(model, dp):
     """At the cells' widths and leaf shapes (depth and, at the hybrid, widths
     cut): under the scope ``opt`` every instruction that is not one leaf's
