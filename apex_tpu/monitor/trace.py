@@ -84,11 +84,14 @@ block-diffusion mask, routed experts; the same step, so ``opt`` and
     layer/moe/route             router product, softmax, top-k, weights
     layer/moe/dispatch          the pairs sorted by held expert and laid out
                                 on tiles; their positions' rows gathered
+                                (backward: the rows' cotangents summed onto
+                                the positions, a gathered row a pair held)
     layer/moe/experts           the held experts' grouped products and the
                                 gate between them (XLA renames the products
                                 ``ragged-dot-*`` and drops their scope: a
                                 reader adds them here by name)
-    layer/moe/combine           the results weighted and added back
+    layer/moe/combine           the results weighted and added back, a
+                                gathered row a pair held
     layer/residual              the two residual adds
     final_norm                  the RMSNorm after the stack (noised half)
     lm_head_loss                untied head + weighted cross entropy, fused
@@ -101,7 +104,15 @@ result, through ``transformer.moe.routing_facts``): ``pairs_held``
 (among the held experts), ``tiled_rows`` (the rows the experts' spans take in
 the grouped product's buffer), ``passes_run`` (the passes over that buffer),
 ``padding_rows`` (rows of those passes that hold no pair), and the batch's
-``masked_positions``.
+``masked_positions``. The step's fourth result also carries each layer's
+``held_places`` (how many positions hold exactly 0 .. top_k pairs with a row
+in the first pass), and ``routing_facts`` given it adds two counters that no
+kind prints yet and ``ROUTING_COUNTERS`` does not list: ``rows_gathered``
+(the rows one sum of the buffer's rows back onto the positions gathers, the
+restore of the positions' order included: the combine's forward, and as many
+again the dispatch's backward) and ``rows_gathered_over_held`` (that over the
+pairs with a row in the pass; ``positions x top_k`` over them is what a
+gather a place would read).
 
 each under one phase: ``fwd``, ``recompute`` (the forward replayed under
 ``jax.checkpoint``), ``bwd``, or the first user scope where no

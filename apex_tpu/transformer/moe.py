@@ -33,7 +33,10 @@ of the softmax-routed models with many small experts
 (``transformer/sdar.py``): **no capacity and no drops**, SiLU-gated experts,
 weights renormalised over the chosen, and the layer told which of the
 router's experts it holds (``experts_held``): one chip's share of an
-expert-parallel deployment, computed without the exchange.
+expert-parallel deployment, computed without the exchange. Every gather of
+rows it makes, in both directions, is of about a row a pair held here
+(``_to_rows`` by the buffer's rows, ``_sum_rows`` by the places a position
+holds), never of ``positions x top_k``.
 """
 
 from __future__ import annotations
@@ -287,6 +290,12 @@ F32 = jnp.float32
 TILE_ROWS = 1024
 # The buffer a pass fills, over what a uniform router would send here.
 BUFFER_OVER_MEAN = 1.5
+# The most positions `_sum_rows` gathers a row each for at a time. At the
+# benchmark's cell the layer's forward and backward take 55.4-55.8 ms at 512,
+# 1,024, 2,048, 4,096 and 8,192 (a gather costs nothing by itself; the smaller
+# chunk reads 5% fewer rows and takes four times the loop's turns): any of
+# them (my chip runs, PR 34).
+CHUNK_ROWS = 2048
 
 
 @dataclasses.dataclass(frozen=True)
@@ -297,11 +306,14 @@ class RoutedExpertsConfig:
     expert in a buffer of ``rows_per_pass`` rows, **each expert's rows
     starting on a multiple of ``tile_rows``** (the grouped product's row
     tile on the chip). The buffer is ``BUFFER_OVER_MEAN`` times what a
-    uniform router would send, so the gathers, the products and the
-    elementwise passes follow the pairs held and not the worst case; pairs
-    beyond it (a router far from uniform) are run by further passes over the
-    same buffer, each skipped by a conditional while there is nothing left:
-    exact at any imbalance."""
+    uniform router would send, so the products and the elementwise passes
+    follow the pairs held and not the worst case, and so does every gather:
+    the rows into the buffer and their cotangent out of it by the buffer's
+    rows, the sums back onto the positions (the combine, the dispatch's
+    backward) by the places each position holds in the pass
+    (:func:`_sum_rows`). Pairs beyond the buffer (a router far from uniform)
+    are run by further passes over it, each skipped by a conditional while
+    there is nothing left: exact at any imbalance."""
     num_experts: int = 128          # the router's range
     top_k: int = 8
 
@@ -346,13 +358,86 @@ def route_softmax_top_k(x, router, top_k: int):
     return idx, chosen / jnp.sum(chosen, axis=-1, keepdims=True)
 
 
+def _has_row(rank, n: int):
+    """Whether a pair's ``rank`` is a row of a pass's ``n`` (a pair of an
+    expert not held, or of another pass, has none)."""
+    return (rank >= 0) & (rank < n)
+
+
 def _rows_at(a, rank):
-    """``a[rank]`` where ``0 <= rank < rows of a``, nought elsewhere (a pair
-    of an expert not held, or of another pass, has no row here)."""
+    """``a[rank]`` where the pair has a row of ``a``, nought elsewhere."""
     n = a.shape[0]
-    ok = (rank >= 0) & (rank < n)
+    ok = _has_row(rank, n)
     got = jnp.take(a, jnp.clip(rank, 0, n - 1), axis=0)
-    return jnp.where(ok[..., None], got, jnp.zeros((), a.dtype))
+    return jnp.where(ok.reshape(ok.shape + (1,) * (a.ndim - 1)), got,
+                     jnp.zeros((), a.dtype))
+
+
+def _chunk_rows(tokens: int) -> int:
+    """The positions :func:`_sum_rows` gathers a row each for at a time: a
+    sixteenth of them (a power of two, at least 8), so that the staircase of
+    places held is followed to within a chunk a place, and no more than
+    ``CHUNK_ROWS``."""
+    return min(CHUNK_ROWS, max(8, 1 << max(tokens // 16, 1).bit_length() - 1))
+
+
+def _sum_rows(a, rank, weight=None):
+    """``out[t] = sum_j weight[t, j] a[rank[t, j]]`` (``weight`` one where
+    None) over the places ``j`` with ``0 <= rank[t, j] < rows of a``, summed
+    in float32 in the places' order and returned in ``a``'s type: what a
+    gather of every position's row a place and a sum over the places gives,
+    bit for bit, **for about a gathered row a place held**.
+
+    Within a position the places that have a row come first, in their order
+    (``x + 0.0`` is exact, so the sum is the same terms in the same order);
+    the positions are taken by how many they hold, most first, so that the
+    positions whose ``j``-th place has a row are the first ``m_j``. A chunk
+    of ``_chunk_rows`` positions then needs as many gathers as its first
+    position holds places: a loop whose trip count is read from the data.
+    One more gather puts the positions back in order. Nothing is scattered,
+    and one chunk's rows exist at a time."""
+    t, k = rank.shape
+    n, h = a.shape
+    chunk = _chunk_rows(t)
+    ok = _has_row(rank, n)
+    held = jnp.sum(ok, axis=1, dtype=jnp.int32)
+    # the places that have a row first, in their order
+    slot = jnp.cumsum(ok, axis=1, dtype=jnp.int32) - 1
+    hit = ok[:, :, None] & (slot[:, :, None] == jnp.arange(k))
+    first = jnp.argsort(-held, stable=True).astype(jnp.int32)
+    pad = -t % chunk
+
+    def by_place(v):
+        """``v`` (tokens, k) as (k, positions by places held, padded to
+        whole chunks): the ``j``-th place that has a row, nought past the
+        last."""
+        v = jnp.sum(jnp.where(hit, v[:, :, None], jnp.zeros((), v.dtype)),
+                    axis=1)
+        return jnp.pad(jnp.take(v, first, axis=0), ((0, pad), (0, 0))).T
+
+    rows = by_place(rank)
+    scale = None if weight is None else by_place(weight.astype(F32))
+    held = jnp.pad(jnp.take(held, first), (0, pad))
+
+    def one_chunk(q, out):
+        at = q * chunk
+        here = lax.dynamic_slice(held, (at,), (chunk,))
+
+        def one_place(j, acc):
+            r = lax.dynamic_slice(rows, (j, at), (1, chunk))[0]
+            got = jnp.where((j < here)[:, None], jnp.take(a, r, axis=0),
+                            jnp.zeros((), a.dtype)).astype(F32)
+            if scale is not None:
+                got = lax.dynamic_slice(scale, (j, at), (1, chunk))[0][:, None] * got
+            return acc + got
+
+        acc = lax.fori_loop(0, here[0], one_place,
+                            pvary_like(jnp.zeros((chunk, h), F32), a))
+        return lax.dynamic_update_slice(out, acc.astype(a.dtype), (at, 0))
+
+    out = lax.fori_loop(0, (t + pad) // chunk, one_chunk,
+                        pvary_like(jnp.zeros((t + pad, h), a.dtype), a))
+    return jnp.take(out, jnp.argsort(first), axis=0)
 
 
 @jax.custom_vjp
@@ -360,7 +445,8 @@ def _to_rows(x, pair, rank):
     """``xs[r] = x[pair[r] // k]``: row ``r`` gets the position of the pair
     it holds (a row that holds none, ``pair`` -1, gets position 0: computed,
     never used). ``rank`` (tokens, k) is the row each pair has in this pass,
-    so the cotangent is a gather too and nothing is scattered."""
+    so the cotangent is gathers too (:func:`_sum_rows`) and nothing is
+    scattered."""
     return jnp.take(x, jnp.maximum(pair, 0) // rank.shape[1], axis=0)
 
 
@@ -368,19 +454,8 @@ def _to_rows_fwd(x, pair, rank):
     return _to_rows(x, pair, rank), rank
 
 
-def _over_places(rank, init, step):
-    """``step(acc, rank[:, j], j)`` for each place ``j`` in turn (a scan, so
-    that one place's gathered rows exist at a time, not ``k`` of them)."""
-    k = rank.shape[1]
-    return lax.scan(lambda acc, rj: step(acc, *rj), init,
-                    (rank.T, jnp.arange(k)))
-
-
 def _to_rows_bwd(rank, dxs):
-    dx, _ = _over_places(
-        rank, pvary_like(jnp.zeros((rank.shape[0], dxs.shape[1]), F32), dxs),
-        lambda acc, r, j: (acc + _rows_at(dxs, r).astype(F32), None))
-    return dx.astype(dxs.dtype), None, None
+    return _sum_rows(dxs, rank), None, None
 
 
 _to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
@@ -389,14 +464,9 @@ _to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
 @jax.custom_vjp
 def _from_rows(ys, weight, pair, rank):
     """``y[t] = sum_j weight[t, j] ys[rank[t, j]]``, summed in float32 and
-    returned in ``ys``' type, over the pairs that have a row in this pass: a
-    gather a place, in both directions. A row that holds no pair takes no
-    cotangent."""
-    y, _ = _over_places(
-        rank, pvary_like(jnp.zeros((rank.shape[0], ys.shape[1]), F32), ys),
-        lambda acc, r, j: (acc + jnp.take(weight, j, axis=1)[:, None]
-                           * _rows_at(ys, r).astype(F32), None))
-    return y.astype(ys.dtype)
+    returned in ``ys``' type, over the pairs that have a row in this pass
+    (:func:`_sum_rows`). A row that holds no pair takes no cotangent."""
+    return _sum_rows(ys, rank, weight)
 
 
 def _from_rows_fwd(ys, weight, pair, rank):
@@ -404,17 +474,17 @@ def _from_rows_fwd(ys, weight, pair, rank):
 
 
 def _from_rows_bwd(res, dy):
+    """``dys`` by row, and the weights' cotangent from the same rows:
+    ``dw[t, j] = <dy[t], ys[rank[t, j]]>`` is row ``rank[t, j]``'s ``<dy of
+    its position, ys>``, a pass over the two arrays by row and a gather of
+    scalars."""
     ys, weight, pair, rank = res
     k = rank.shape[1]
     at = jnp.maximum(pair, 0)
     w = jnp.where(pair >= 0, jnp.take(weight.reshape(-1), at), 0.0)
-    dys = (w[:, None] * jnp.take(dy, at // k, axis=0).astype(F32)
-           ).astype(ys.dtype)
-    _, dw = _over_places(
-        rank, None,
-        lambda acc, r, j: (acc, jnp.sum(dy.astype(F32)
-                                        * _rows_at(ys, r).astype(F32), axis=-1)))
-    dw = dw.T
+    dy_rows = jnp.take(dy, at // k, axis=0).astype(F32)
+    dys = (w[:, None] * dy_rows).astype(ys.dtype)
+    dw = _rows_at(jnp.sum(dy_rows * ys.astype(F32), axis=-1), rank)
     return dys, dw, None, None
 
 
@@ -469,9 +539,12 @@ def routed_experts_mlp(p, x, cfg: RoutedExpertsConfig,
     ``jax.checkpoint``, so that it keeps nothing) that runs only while pairs
     are left.
 
-    Returns ``(y, loads)``: ``loads`` (count,) int32 is how many pairs each
-    held expert took, the layout's own count (:func:`routing_facts` reads a
-    step's counters from it)."""
+    Returns ``(y, counted)``, what the layout itself counted, int32
+    (:func:`routing_facts` reads a step's counters from them):
+    ``counted["expert_loads"]`` (count,), the pairs each held expert took,
+    and ``counted["held_places"]`` (top_k + 1,), how many positions hold
+    exactly 0 .. top_k pairs with a row in the first pass: what the gathers
+    of :func:`_sum_rows` follow."""
     lead, h = x.shape[:-1], x.shape[-1]
     xf = x.reshape(-1, h)
     t, k = xf.shape[0], cfg.top_k
@@ -479,6 +552,15 @@ def routed_experts_mlp(p, x, cfg: RoutedExpertsConfig,
     tile = cfg.tile_rows(t)
     n = cfg.rows_per_pass(t, count)
     with span("moe/route"):
+        # the cotangents of the leaves and of the input leave the layer
+        # together (the barrier's transpose is a barrier): left free, XLA
+        # puts the weight gradients' products off past the sublayer that
+        # follows in the backward and holds the buffer's rows, their
+        # cotangents and the later passes' results (1.9 GB at the benchmark's
+        # cell) across it, then rematerialises that sublayer to fit
+        p, xf = lax.optimization_barrier((
+            {name: p[name] for name in ("router", "w_gate", "w_up", "w_down")},
+            xf))
         idx, weight = route_softmax_top_k(xf, p["router"], k)
     with span("moe/dispatch"):
         local = idx - first
@@ -488,6 +570,9 @@ def routed_experts_mlp(p, x, cfg: RoutedExpertsConfig,
             key, count, tile)
         rank = rank.reshape(t, k)
         total = jnp.sum(spans)
+        held = jnp.sum(_has_row(rank, n), axis=1)
+        held_places = jnp.sum(held[:, None] == jnp.arange(k + 1)[None, :],
+                              axis=0, dtype=jnp.int32)
 
     def one_pass(c):
         lo = c * n
@@ -519,27 +604,48 @@ def routed_experts_mlp(p, x, cfg: RoutedExpertsConfig,
         with span("moe/combine"):
             y = y + lax.cond(c * n < total, later,
                              lambda c: jnp.zeros_like(y), jnp.int32(c))
-    return y.reshape(*lead, h), sizes
+    return y.reshape(*lead, h), {"expert_loads": sizes,
+                                 "held_places": held_places}
 
 
-def routing_facts(loads, tokens: int, cfg: RoutedExpertsConfig) -> dict:
+def routing_facts(loads, tokens: int, cfg: RoutedExpertsConfig,
+                  held_places=None) -> dict:
     """What a layer's routing did in one step, from the held experts' loads
-    (:func:`routed_experts_mlp`'s second result) over ``tokens`` positions,
-    as plain numbers: ``pairs_held`` (position, place) pairs that landed on
-    an expert held here and ``pairs_uniform``, what a uniform router would
-    send; ``max_load_over_mean`` among the held experts; ``tiled_rows``, the
-    rows the experts' spans take (each load rounded up to whole tiles);
-    ``passes_run``, the passes over the buffer the layer ran; and
-    ``padding_rows``, the rows of those passes that hold no pair (the tiles'
-    tails and the room past the last expert)."""
+    (:func:`routed_experts_mlp`'s ``expert_loads``) over ``tokens``
+    positions, as plain numbers: ``pairs_held`` (position, place) pairs that
+    landed on an expert held here and ``pairs_uniform``, what a uniform
+    router would send; ``max_load_over_mean`` among the held experts;
+    ``tiled_rows``, the rows the experts' spans take (each load rounded up to
+    whole tiles); ``passes_run``, the passes over the buffer the layer ran;
+    and ``padding_rows``, the rows of those passes that hold no pair (the
+    tiles' tails and the room past the last expert).
+
+    With the layer's ``held_places`` also ``rows_gathered``, the rows one
+    :func:`_sum_rows` over the first pass gathers (the combine's forward; the
+    dispatch's backward gathers as many): each chunk of positions as many
+    times as its first position holds places, and every position once to
+    put them back in order; and ``rows_gathered_over_held``, that over the
+    pairs that have a row in the first pass (``positions x top_k`` over them
+    is what a gather a place would read)."""
     loads = np.asarray(loads)
     tile = cfg.tile_rows(tokens)
     n = cfg.rows_per_pass(tokens, len(loads))
     tiled = int((-(-loads // tile) * tile).sum())
     passes = max(1, -(-tiled // n))
-    return {"pairs_held": int(loads.sum()),
-            "pairs_uniform": tokens * cfg.top_k * len(loads) / cfg.num_experts,
-            "max_load_over_mean": float(loads.max() / max(loads.mean(), 1e-9)),
-            "tiled_rows": tiled,
-            "padding_rows": n * passes - int(loads.sum()),
-            "passes_run": passes}
+    facts = {"pairs_held": int(loads.sum()),
+             "pairs_uniform": tokens * cfg.top_k * len(loads) / cfg.num_experts,
+             "max_load_over_mean": float(loads.max() / max(loads.mean(), 1e-9)),
+             "tiled_rows": tiled,
+             "padding_rows": n * passes - int(loads.sum()),
+             "passes_run": passes}
+    if held_places is not None:
+        held_places = np.asarray(held_places)
+        chunk = _chunk_rows(tokens)
+        # positions that hold at least 1, 2, .. places: a chunk that starts
+        # before the i-th of these ends is gathered for its i-th place
+        at_least = np.cumsum(held_places[::-1])[::-1][1:]
+        gathered = int((-(-at_least // chunk) * chunk).sum()) + tokens
+        facts["rows_gathered"] = gathered
+        facts["rows_gathered_over_held"] = gathered / max(
+            int((np.arange(len(held_places)) * held_places).sum()), 1)
+    return facts

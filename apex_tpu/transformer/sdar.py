@@ -121,9 +121,10 @@ class SDARConfig:
 
     def loss_and_counters(self, params, tokens, noise):
         """The step's fourth result (``train_step_fn``): the loss and what
-        the routed layers counted, stacked over ``dp``."""
-        loss, loads = sdar_loss(params, tokens, noise, self)
-        return loss, {"expert_loads": loads[None]}
+        the routed layers counted (``expert_loads``, ``held_places``), stacked
+        over ``dp``."""
+        loss, counted = sdar_loss(params, tokens, noise, self)
+        return loss, jax.tree.map(lambda a: a[None], counted)
 
 
 # ---------------------------------------------------------------------------
@@ -207,56 +208,61 @@ def _attention_sublayer(p, x, cfg: SDARConfig):
 
 
 def _experts_sublayer(p, x, cfg: SDARConfig):
-    """``(x + MoE(RMSNorm(x)), the held experts' loads)``."""
+    """``(x + MoE(RMSNorm(x)), what the routed layer counted)``."""
     with span("pre_norm"):
         m = rms_norm(x, p["norm2"], cfg.norm_eps)
-    m, loads = routed_experts_mlp(p, m, cfg.routed, cfg.experts_held)
+    m, counted = routed_experts_mlp(p, m, cfg.routed, cfg.experts_held)
     with span("residual"):
-        return x + m, loads
+        return x + m, counted
 
 
 def _layer(p, carry, cfg: SDARConfig):
-    """One layer over the stack's carry ``(x, loads)``: ``loads`` (layers,
-    experts held) takes this layer's row at its end and drops its first, so
-    that after the last layer its rows are the layers' in order."""
-    x, loads = carry
+    """One layer over the stack's carry ``(x, counted)``: each array of
+    ``counted`` (layers, ...) takes this layer's row at its end and drops its
+    first, so that after the last layer its rows are the layers' in order."""
+    x, counted = carry
     wrap = jax.checkpoint if cfg.remat == "sublayer" else (lambda f: f)
     x = wrap(lambda p, x: _attention_sublayer(p, x, cfg))(p, x)
     x, here = wrap(lambda p, x: _experts_sublayer(p, x, cfg))(p, x)
-    return x, jnp.concatenate([loads[1:], here[None]])
+    return x, jax.tree.map(lambda all_, row: jnp.concatenate([all_[1:], row[None]]),
+                           counted, here)
 
 
 def sdar_hidden(params, seq2, cfg: SDARConfig):
-    """``[x_t ; x0]`` (rows, 2L) -> ``(the last layer's output, loads)``,
-    ``loads`` (layers, experts held) int32: the pairs each held expert took
-    in each layer, counted by the layers that ran."""
+    """``[x_t ; x0]`` (rows, 2L) -> ``(the last layer's output, counted)``,
+    ``counted`` int32 by the layers that ran: ``expert_loads`` (layers,
+    experts held), the pairs each held expert took in each layer, and
+    ``held_places`` (layers, top_k + 1), the positions that hold 0 .. top_k
+    pairs with a row in the layer's first pass."""
     refuse_tp("the block-diffusion model's layers are",
               "grouped heads and the routed experts would each need a split "
               "of their own")
     with span("embed"):
         x = jnp.take(params["embed"]["tok"], seq2, axis=0)
-    loads = pvary_like(jnp.zeros((cfg.num_layers, cfg.experts_held[1]),
-                                 jnp.int32), x)
-    return run_stack((x, loads), params["periods"], (LAYER,),
+    counted = {name: pvary_like(jnp.zeros((cfg.num_layers, width), jnp.int32), x)
+               for name, width in (("expert_loads", cfg.experts_held[1]),
+                                   ("held_places", cfg.top_k + 1))}
+    return run_stack((x, counted), params["periods"], (LAYER,),
                      lambda kind, lp, carry: _layer(lp, carry, cfg),
                      cfg.remat == "layer")
 
 
 def sdar_loss(params, tokens, noise, cfg: SDARConfig):
-    """``(loss, loads)``: ``sum over masked positions of CE(logits at the
+    """``(loss, counted)``: ``sum over masked positions of CE(logits at the
     position, x0) / t`` over ``rows * L``, the logits taken on the noised
-    half alone and never materialised; and :func:`sdar_hidden`'s loads."""
+    half alone and never materialised; and what :func:`sdar_hidden`'s routed
+    layers counted."""
     rows, length = tokens.shape
     with span("noise"):
         seq2, weight = noised_batch(tokens, noise, cfg)
-    x, loads = sdar_hidden(params, seq2, cfg)
+    x, counted = sdar_hidden(params, seq2, cfg)
     x = x[:, :length]
     with span("final_norm"):
         x = rms_norm(x, params["head"]["norm"], cfg.norm_eps)
     with span("lm_head_loss"):
         per = lm_head_loss(x, pvary_like(params["head"]["lm"], x), tokens,
                            weights=weight)
-        return jnp.sum(per) / (rows * length), loads
+        return jnp.sum(per) / (rows * length), counted
 
 
 def sdar_logits(params, tokens, noise, cfg: SDARConfig):

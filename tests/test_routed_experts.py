@@ -63,10 +63,10 @@ def _loads(p, x, first, count):
 def test_layer_equals_the_dense_loop_over_the_held_experts(first, count):
     p, x = _params(count), _x()
     with jax.default_matmul_precision("highest"):
-        got, loads = routed_experts_mlp(p, x, _cfg(), (first, count))
+        got, counted = routed_experts_mlp(p, x, _cfg(), (first, count))
         want = _dense(p, x, first, count)
     np.testing.assert_allclose(got, want, atol=2e-5)
-    np.testing.assert_array_equal(loads, _loads(p, x, first, count))
+    np.testing.assert_array_equal(counted["expert_loads"], _loads(p, x, first, count))
 
 
 @pytest.mark.parametrize("bias,passes", [(0.0, 1), (3.0, 2), (30.0, 3)])
@@ -95,9 +95,9 @@ def test_no_position_is_dropped_when_every_one_chooses_the_same_held_expert():
     x = _x(6).at[:, 0].set(10.0)
     p["router"] = jnp.zeros((H, E)).at[0, 2].set(5.0).at[0, 5].set(4.0)
     with jax.default_matmul_precision("highest"):
-        got, loads = routed_experts_mlp(p, x, _cfg(), (first, count))
+        got, counted = routed_experts_mlp(p, x, _cfg(), (first, count))
         want = _dense(p, x, first, count)
-    facts = routing_facts(loads, T, _cfg())
+    facts = routing_facts(counted["expert_loads"], T, _cfg())
     assert facts["pairs_held"] == T and facts["passes_run"] == 2
     assert facts["max_load_over_mean"] == pytest.approx(2.0)
     assert float(jnp.abs(want).min(axis=1).max()) > 0      # every position has a result
@@ -192,3 +192,226 @@ def test_each_experts_rows_start_on_a_tile():
         rows = np.sort(rank[key == e])
         np.testing.assert_array_equal(rows, int(first_row[e]) + np.arange(int(sizes[e])))
     assert np.all(rank[key == 4] > 10 ** 8)       # not held: no row
+
+
+# -- the gathers follow the pairs held (ISSUE 34) --------------------------------------------------
+
+def _per_place(a, rank, weight=None):
+    """What ``_sum_rows`` replaces (PR 33's ``_over_places``): a scan over the places, every
+    position's row gathered a place and added in float32."""
+    from apex_tpu.transformer.moe import _rows_at
+
+    def step(acc, place):
+        r, j = place
+        got = _rows_at(a, r).astype(jnp.float32)
+        return acc + (got if weight is None else jnp.take(weight, j, axis=1)[:, None] * got), None
+
+    acc, _ = jax.lax.scan(step, jnp.zeros((rank.shape[0], a.shape[1]), jnp.float32),
+                          (rank.T, jnp.arange(rank.shape[1])))
+    return acc.astype(a.dtype)
+
+
+def _hand_rank(held, k, n, seed=0):
+    """(positions, k) int32: position ``t`` holds ``held[t]`` places with a row among ``n`` (rows
+    drawn anew for every pair), at places drawn at random; the others lie before the buffer or
+    past it."""
+    rng = np.random.default_rng(seed)
+    rank = np.where(rng.random((len(held), k)) < 0.5, -1 - rng.integers(0, 9, (len(held), k)),
+                    n + rng.integers(0, 9, (len(held), k)))
+    for t, c in enumerate(held):
+        places = rng.permutation(k)[:c]
+        rank[t, places] = rng.integers(0, n, c)
+    return jnp.asarray(rank, jnp.int32)
+
+
+def _held_with(m, positions=96, seed=0):
+    """Counts a position such that ``m[j]`` positions hold more than ``j`` places, shuffled."""
+    held = np.zeros(positions, np.int64)
+    for mj in m:
+        held[:mj] += 1
+    return np.random.default_rng(seed).permutation(held)
+
+
+_CHUNK = 8          # _chunk_rows(96)
+_STAIRS = {"every-place": [96] * 4, "no-place": [], "one-position": [96, 1],
+           "a-chunk": [96, _CHUNK], "a-chunk-and-one": [96, _CHUNK + 1], "first-empty": [40, 17, 9, 8]}
+
+
+@pytest.mark.parametrize("weighted", [True, False], ids=["weighted", "unweighted"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("stairs", list(_STAIRS))
+def test_the_sum_over_the_places_held_equals_the_sum_over_every_place_bit_for_bit(
+        stairs, dtype, weighted):
+    from apex_tpu.transformer.moe import _chunk_rows, _sum_rows
+    k, n = 4, 40
+    assert _chunk_rows(96) == _CHUNK
+    rank = _hand_rank(_held_with(_STAIRS[stairs]), k, n, seed=len(stairs))
+    a = jax.random.normal(jax.random.PRNGKey(20), (n, H), jnp.float32).astype(dtype)
+    w = jax.random.uniform(jax.random.PRNGKey(21), (96, k), jnp.float32) if weighted else None
+    got, want = jax.jit(_sum_rows)(a, rank, w), jax.jit(_per_place)(a, rank, w)
+    assert got.dtype == want.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    if stairs == "no-place":
+        assert not np.asarray(got, np.float32).any()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_later_passes_sum_their_own_rows_inside_a_conditional_and_a_checkpoint(dtype):
+    """Every position on one held expert, 96 rows over three passes of a 40-row buffer, each pass
+    as ``routed_experts_mlp`` runs its later ones: the pass's rows are ``rank - pass x 40``."""
+    from apex_tpu.transformer.moe import _from_rows
+    n, passes = 40, 3
+    rank = jnp.stack([jnp.full((T,), -7, jnp.int32), jnp.arange(T, dtype=jnp.int32)], axis=1)
+    ys = jax.random.normal(jax.random.PRNGKey(22), (passes, n, H), jnp.float32).astype(dtype)
+    w = jax.random.uniform(jax.random.PRNGKey(23), (T, 2), jnp.float32)
+
+    def pair(c):        # row r of pass c holds position c * n + r's second place, or nothing
+        at = c * n + jnp.arange(n, dtype=jnp.int32)
+        return jnp.where(at < T, 2 * at + 1, -1)
+
+    def layer(sum_rows):
+        def total(ys, w):
+            one = jax.checkpoint(lambda c: sum_rows(jnp.take(ys, c, axis=0), rank - c * n, w, pair(c)))
+            y = jnp.zeros((T, H), dtype)
+            for c in range(passes):
+                y = y + jax.lax.cond(c * n < T, one, lambda c: jnp.zeros((T, H), dtype), jnp.int32(c))
+            return y
+        return total
+
+    new = layer(lambda ys, r, w, pair: _from_rows(ys, w, pair, r))
+    old = layer(lambda ys, r, w, pair: _per_place(ys, r, w))
+    np.testing.assert_array_equal(np.asarray(jax.jit(new)(ys, w), np.float32),
+                                  np.asarray(jax.jit(old)(ys, w), np.float32))
+    assert float(jnp.abs(jax.jit(new)(ys, w).astype(jnp.float32)).min(axis=1).min()) > 0
+    # both cotangents through the three passes, against autodiff of the scan
+    loss = lambda f: lambda ys, w: jnp.sum(jnp.sin(f(ys, w).astype(jnp.float32)))
+    got, want = (jax.grad(loss(f), argnums=(0, 1))(ys, w) for f in (new, old))
+    np.testing.assert_array_equal(np.asarray(got[0], np.float32), np.asarray(want[0], np.float32))
+    np.testing.assert_allclose(got[1], want[1], rtol=1e-6, atol=1e-6 * float(jnp.abs(want[1]).max()))
+    assert float(jnp.abs(got[1][:, 1]).min()) > 0 and not np.asarray(got[1][:, 0]).any()
+
+
+def test_the_sum_runs_under_shard_map_each_shard_over_its_own_rows():
+    from jax.sharding import PartitionSpec as P
+
+    from apex_tpu.parallel.mesh import build_mesh
+    from apex_tpu.transformer.moe import _sum_rows
+    dp, k, n = 2, 4, 40
+    mesh = build_mesh(tp=1, pp=1, sp=1, dp=dp, devices=jax.devices()[:dp])
+    ranks = jnp.concatenate([_hand_rank(_held_with([96, 30, 9], seed=s), k, n, seed=s)
+                             for s in range(dp)])
+    a = jax.random.normal(jax.random.PRNGKey(24), (dp * n, H), jnp.float32)
+    w = jax.random.uniform(jax.random.PRNGKey(25), (dp * 96, k), jnp.float32)
+    got = jax.jit(jax.shard_map(_sum_rows, mesh=mesh, in_specs=P("dp"), out_specs=P("dp")))(
+        a, ranks, w)
+    for s in range(dp):
+        want = _per_place(a[s * n:(s + 1) * n], ranks[s * 96:(s + 1) * 96], w[s * 96:(s + 1) * 96])
+        np.testing.assert_array_equal(got[s * 96:(s + 1) * 96], want)
+
+
+@pytest.mark.parametrize("first,count", [(0, 8), (0, 2), (2, 3), (3, 1)])
+def test_the_weights_cotangent_from_the_rows_equals_the_dense_loops(first, count):
+    """``dw`` by row and a gather of scalars (``_from_rows_bwd``), through the router's leaf,
+    against ``jax.grad`` of the dense loop: to 1e-6 of the gradient's size."""
+    p, x = _params(count, key=15), _x(16)
+    loss = lambda f: lambda router: jnp.sum(jnp.sin(f({**p, "router": router}, x)))
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(loss(lambda p, x: _layer(p, x, (first, count))))(p["router"])
+        want = jax.grad(loss(lambda p, x: _dense(p, x, first, count)))(p["router"])
+    assert float(jnp.abs(want).max()) > 0
+    assert float(jnp.abs(got - want).max()) <= 1e-6 * float(jnp.abs(want).max())
+
+
+def _rows_visited(rank, n):
+    """The rows ``_sum_rows``' gathers visit, by hand: the positions by places held, most first;
+    a chunk as often as its first position holds places; every position once more."""
+    from apex_tpu.transformer.moe import _chunk_rows
+    rank = np.asarray(rank)
+    chunk = _chunk_rows(len(rank))
+    held = np.sort(((rank >= 0) & (rank < n)).sum(1))[::-1]
+    return int(chunk * held[::chunk].sum()) + len(rank)
+
+
+@pytest.mark.parametrize("stairs", list(_STAIRS))
+def test_rows_gathered_is_the_sums_own_count(stairs):
+    k, n = 4, 40
+    held = _held_with(_STAIRS[stairs])
+    rank = _hand_rank(held, k, n)
+    cfg = RoutedExpertsConfig(num_experts=E, top_k=k)
+    facts = routing_facts(np.asarray([held.sum()]), 96, cfg, np.bincount(held, minlength=k + 1))
+    assert facts["rows_gathered"] == _rows_visited(rank, n)
+    assert facts["rows_gathered"] <= held.sum() + k * _CHUNK + 96
+    if held.sum():
+        assert facts["rows_gathered_over_held"] == facts["rows_gathered"] / held.sum()
+    assert set(routing_facts(np.asarray([held.sum()]), 96, cfg)) == set(facts) - {
+        "rows_gathered", "rows_gathered_over_held"}
+
+
+@pytest.mark.parametrize("first,count", [(0, 8), (0, 2), (2, 3), (6, 2), (3, 1)])
+def test_held_places_counts_the_positions_by_the_pairs_they_hold(first, count):
+    p, x = _params(count), _x()
+    counted = routed_experts_mlp(p, x, _cfg(), (first, count))[1]
+    held_places, loads = np.asarray(counted["held_places"]), np.asarray(counted["expert_loads"])
+    assert held_places.shape == (K + 1,) and held_places.dtype == np.int32
+    assert held_places.sum() == T
+    assert routing_facts(loads, T, _cfg())["passes_run"] == 1       # one pass holds every pair
+    assert (np.arange(K + 1) * held_places).sum() == loads.sum()
+    idx = np.asarray(route_softmax_top_k(x, p["router"], K)[0])
+    by_hand = ((idx >= first) & (idx < first + count)).sum(1)
+    np.testing.assert_array_equal(held_places, np.bincount(by_hand, minlength=K + 1))
+
+
+def _gathers(jaxpr, loops=()):
+    """``(loops round it, output shape)`` of every gather in ``jaxpr``, its sub-programs
+    included; a loop is ``("scan", trips)`` or ``("while", None)``."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            found.append((loops, eqn.outvars[0].aval.shape))
+        inner = loops
+        if eqn.primitive.name == "scan":
+            inner = loops + (("scan", eqn.params["length"]),)
+        elif eqn.primitive.name == "while":
+            inner = loops + (("while", None),)
+        stack = list(eqn.params.values())
+        while stack:
+            v = stack.pop()
+            if isinstance(v, (tuple, list)):
+                stack.extend(v)
+            elif hasattr(v, "eqns"):
+                found += _gathers(v, inner)
+            elif hasattr(v, "jaxpr") and hasattr(v.jaxpr, "eqns"):
+                found += _gathers(v.jaxpr, inner)
+    return found
+
+
+def test_the_work_follows_the_pairs_held_not_positions_times_top_k():
+    """``jax.grad`` of the layer, a quarter of the experts held: no loop of ``top_k`` trips is
+    left; inside a loop a gather reads a chunk's rows, never every position's; and what the
+    counters say was gathered is within a chunk a place of the pairs held and the positions."""
+    from apex_tpu.transformer.moe import _chunk_rows
+    t, e, k, first, count = 512, 32, 8, 8, 8
+    cfg = RoutedExpertsConfig(num_experts=e, top_k=k)
+    ks = jax.random.split(jax.random.PRNGKey(30), 5)
+    shapes = routed_expert_shapes(H, F, e, count)
+    p = {name: 0.3 * jax.random.normal(key, shapes[name], jnp.float32)
+         for name, key in zip(shapes, ks)}
+    x = jax.random.normal(ks[4], (t, H), jnp.float32)
+    layer = lambda p, x: routed_experts_mlp(p, x, cfg, (first, count))
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(jnp.sin(layer(p, x)[0])), argnums=(0, 1)))(p, x)
+    gathers = _gathers(jaxpr.jaxpr)
+    chunk = _chunk_rows(t)
+    assert chunk == 32 and len(gathers) > 10
+    in_loops = [(loops, shape) for loops, shape in gathers if loops]
+    assert in_loops and all(("scan", k) not in loops for loops, _ in gathers)
+    for loops, shape in in_loops:
+        assert shape[0] in (chunk, 1) or len(shape) < 2, (loops, shape)
+    # rows of the hidden width gathered for every position: outside every loop, and one a sum
+    # (the restore) beside the router's and the layout's own
+    whole = [loops for loops, shape in gathers if shape == (t, H)]
+    assert whole and not any(whole)
+    counted = layer(p, x)[1]
+    facts = routing_facts(counted["expert_loads"], t, cfg, counted["held_places"])
+    assert facts["passes_run"] == 1 and 0 < facts["pairs_held"] < t * k // 2
+    assert facts["rows_gathered"] <= facts["pairs_held"] + k * chunk + t
+    assert facts["rows_gathered"] < t * k // 2

@@ -124,7 +124,7 @@ def test_the_loads_are_counted_by_the_layers_that_ran():
     cfg = _cfg()
     params = _params(cfg)
     tokens, noise = _batch()
-    _, loads = sdar_loss(params, tokens, noise, cfg)
+    loads = sdar_loss(params, tokens, noise, cfg)[1]["expert_loads"]
     assert loads.shape == (cfg.num_layers, cfg.experts_held[1]) and loads.dtype == jnp.int32
     x = jnp.take(params["embed"]["tok"], noised_batch(tokens, noise, cfg)[0], axis=0)
     first, count = cfg.experts_held
@@ -138,7 +138,8 @@ def test_the_loads_are_counted_by_the_layers_that_ran():
         assert 0 < want.sum() < idx.size                     # experts that are not held, too
         x, _ = sdar._experts_sublayer(lp, h, cfg)
     for remat in ("none", "layer"):
-        np.testing.assert_array_equal(sdar_loss(params, tokens, noise, _cfg(remat=remat))[1], loads)
+        np.testing.assert_array_equal(
+            sdar_loss(params, tokens, noise, _cfg(remat=remat))[1]["expert_loads"], loads)
 
 
 def test_what_the_config_refuses():
