@@ -96,6 +96,41 @@ block-diffusion mask, routed experts; the same step, so ``opt`` and
     final_norm                  the RMSNorm after the stack (noised half)
     lm_head_loss                untied head + weighted cross entropy, fused
 
+Device scopes of the latent-attention decoder's train step
+(``transformer/deepseek.py``: DeepSeek-V2's block, a leading dense layer
+written out before the scanned expert layers; the same step, so ``opt`` and
+``scan_carry`` as above; ``layer/moe/*`` as in the block-diffusion decoder)::
+
+    embed                       token embedding (rows of the vocabulary held)
+    layer                       one layer (dense or expert), and inside it
+    layer/pre_norm              the RMSNorm before each sublayer
+    layer/attn/q_proj           the query projection and its split into heads
+    layer/attn/kv_down          the latent's down projection ``x W_kva``
+                                (512 + the 64 of the one rotated key)
+    layer/attn/kv_norm          RMSNorm of the latent (``kv_a_layernorm``)
+    layer/attn/kv_up            the latent expanded into each head's key
+                                (unrotated part) and value
+    layer/attn/rope             the rotation of the queries' rotated part and
+                                of the one rotated key (YaRN, interleaved
+                                pairs), its broadcast into every head's key
+    layer/attn/core             the flash call (keys of 192 over values of
+                                128) and its layout changes; the kernels
+                                below it as in the other families
+    layer/attn/out              merge of heads and output projection
+    layer/mlp/gate_up  layer/mlp/act  layer/mlp/down
+                                the dense layer's gated FFN
+    layer/shared/gate_up  layer/shared/act  layer/shared/down
+                                an expert layer's shared expert (not under
+                                ``layer/moe``: a reader of the routed layer
+                                files an unknown part of it under dispatch)
+    layer/moe/route  layer/moe/dispatch  layer/moe/experts  layer/moe/combine
+                                the routed experts, as above
+    layer/aux_loss              the balance loss a sequence, from the
+                                router's scores and choices
+    layer/residual              the residual adds
+    final_norm                  the RMSNorm after the stack
+    lm_head_loss                untied head + cross entropy, fused
+
 **Counters of a routed layer** (``ROUTING_COUNTERS``; a step's, from the
 held experts' loads that the step itself returns, ``train_step_fn``'s fourth
 result, through ``transformer.moe.routing_facts``): ``pairs_held``
@@ -106,13 +141,19 @@ the grouped product's buffer), ``passes_run`` (the passes over that buffer),
 ``padding_rows`` (rows of those passes that hold no pair), and the batch's
 ``masked_positions``. The step's fourth result also carries each layer's
 ``held_places`` (how many positions hold exactly 0 .. top_k pairs with a row
-in the first pass), and ``routing_facts`` given it adds two counters that no
-kind prints yet and ``ROUTING_COUNTERS`` does not list: ``rows_gathered``
-(the rows one sum of the buffer's rows back onto the positions gathers, the
-restore of the positions' order included: the combine's forward, and as many
-again the dispatch's backward) and ``rows_gathered_over_held`` (that over the
-pairs with a row in the pass; ``positions x top_k`` over them is what a
-gather a place would read).
+in the first pass), and ``routing_facts`` given it adds two counters:
+``rows_gathered`` (the rows one sum of the buffer's rows back onto the
+positions gathers, the restore of the positions' order included: the
+combine's forward, and as many again the dispatch's backward) and
+``rows_gathered_over_held`` (that over the pairs with a row in the pass;
+``positions x top_k`` over them is what a gather a place would read). A kind
+that prints these two and the step's ``aux_loss`` (the latent-attention
+decoder's balance loss, summed over its expert layers: the step's fourth
+result carries each layer's) lists them in a second tuple,
+``ROUTING_COUNTERS_MORE``: the latent-attention cell's kind does
+(``perfbench/kinds/train_dsv2.py``); the block-diffusion cell's prints
+``ROUTING_COUNTERS`` alone, and its test holds its line to that tuple as it
+is.
 
 each under one phase: ``fwd``, ``recompute`` (the forward replayed under
 ``jax.checkpoint``), ``bwd``, or the first user scope where no
@@ -167,6 +208,9 @@ PHASES = ("fwd", "recompute", "bwd", "comm", "opt", "ckpt", "prefill",
 ROUTING_COUNTERS = ("pairs_held", "pairs_uniform", "max_load_over_mean",
                     "tiled_rows", "passes_run", "padding_rows",
                     "masked_positions")
+# and what a kind that reads ``held_places`` and the balance loss adds
+ROUTING_COUNTERS_MORE = ("rows_gathered", "rows_gathered_over_held",
+                         "aux_loss")
 
 
 @contextlib.contextmanager

@@ -640,7 +640,7 @@ def _fa_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret,
     ``block_q`` / ``block_k`` bound the tile; ``_tile_plan`` picks it and
     the schedule."""
     bh, sq, d = q3.shape
-    sk = k3.shape[1]
+    sk, d_v = k3.shape[1], v3.shape[2]
     seed = _seed3(seed)
     has_bias = bias is not None
     plan = _tile_plan(sq, sk, d, q3.dtype, causal, block_q, block_k, has_bias)
@@ -648,7 +648,7 @@ def _fa_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret,
     nq = sq // block_q
     nk = sk // block_k
     out_shape = [
-        _sds((bh, sq, d), q3.dtype, q3, k3, v3),
+        _sds((bh, sq, d_v), q3.dtype, q3, k3, v3),
         _sds((bh, sq, 1), jnp.float32, q3, k3, v3),
     ]
     if plan.schedule == "resident":
@@ -659,8 +659,8 @@ def _fa_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret,
             name="flash_fwd",
             grid=(bh,),
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                      _head_spec(sq, d), _head_spec(sk, d), _head_spec(sk, d)],
-            out_specs=[_head_spec(sq, d), _head_spec(sq, 1)],
+                      _head_spec(sq, d), _head_spec(sk, d), _head_spec(sk, d_v)],
+            out_specs=[_head_spec(sq, d_v), _head_spec(sq, 1)],
             out_shape=out_shape,
             compiler_params=None if interpret else pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
@@ -686,7 +686,7 @@ def _fa_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret,
         pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_k, d), kv_index),
-        pl.BlockSpec((1, block_k, d), kv_index),
+        pl.BlockSpec((1, block_k, d_v), kv_index),
     ]
     inputs = [seed, q3, k3, v3]
     if has_bias:
@@ -698,14 +698,14 @@ def _fa_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret,
         grid=(bh, nq, nk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
         ],
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -961,7 +961,7 @@ def _fa_bwd(q3, k3, v3, o3, lse, do3, scale, causal, block_q, block_k,
     """(dq, dk, dv, dbias or None) on the schedule ``_tile_plan`` gives the
     shape — the forward's."""
     bh, sq, d = q3.shape
-    sk = k3.shape[1]
+    sk, d_v = k3.shape[1], v3.shape[2]
     seed = _seed3(seed)
     has_bias = bias is not None
     plan = _tile_plan(sq, sk, d, q3.dtype, causal, block_q, block_k, has_bias)
@@ -973,7 +973,7 @@ def _fa_bwd(q3, k3, v3, o3, lse, do3, scale, causal, block_q, block_k,
     dq_shape = _sds((bh, sq, d), q3.dtype, q3, k3, v3, do3)
     dkv_shape = [
         _sds((bh, sk, d), k3.dtype, q3, k3, v3, do3),
-        _sds((bh, sk, d), v3.dtype, q3, k3, v3, do3),
+        _sds((bh, sk, d_v), v3.dtype, q3, k3, v3, do3),
     ]
 
     if plan.schedule == "resident":
@@ -982,8 +982,8 @@ def _fa_bwd(q3, k3, v3, o3, lse, do3, scale, causal, block_q, block_k,
         call = dict(
             grid=(bh,),
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                      _head_spec(sq, d), _head_spec(sk, d), _head_spec(sk, d),
-                      _head_spec(sq, d), _head_spec(sq, 1), _head_spec(sq, 1)],
+                      _head_spec(sq, d), _head_spec(sk, d), _head_spec(sk, d_v),
+                      _head_spec(sq, d_v), _head_spec(sq, 1), _head_spec(sq, 1)],
             compiler_params=None if interpret else pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
             interpret=interpret)
@@ -995,7 +995,7 @@ def _fa_bwd(q3, k3, v3, o3, lse, do3, scale, causal, block_q, block_k,
         dk, dv = pl.pallas_call(
             functools.partial(_fa_bwd_dkv_resident_kernel, **static),
             name="flash_bwd_dkv",
-            out_specs=[_head_spec(sk, d), _head_spec(sk, d)],
+            out_specs=[_head_spec(sk, d), _head_spec(sk, d_v)],
             out_shape=dkv_shape, **call)(*inputs)
         return dq, dk, dv, None
 
@@ -1013,8 +1013,8 @@ def _fa_bwd(q3, k3, v3, o3, lse, do3, scale, causal, block_q, block_k,
         pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_k, d), kv_index),
-        pl.BlockSpec((1, block_k, d), kv_index),
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        pl.BlockSpec((1, block_k, d_v), kv_index),
+        pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
     ]
@@ -1049,8 +1049,8 @@ def _fa_bwd(q3, k3, v3, o3, lse, do3, scale, causal, block_q, block_k,
         pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, q_clamp(i, j), 0)),
         pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, q_clamp(i, j), 0)),
+        pl.BlockSpec((1, block_k, d_v), lambda b, j, i: (b, j, 0)),
+        pl.BlockSpec((1, block_q, d_v), lambda b, j, i: (b, q_clamp(i, j), 0)),
         pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, q_clamp(i, j), 0)),
         pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, q_clamp(i, j), 0)),
     ]
@@ -1068,12 +1068,12 @@ def _fa_bwd(q3, k3, v3, o3, lse, do3, scale, causal, block_q, block_k,
         in_specs=dkv_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d_v), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=dkv_shape,
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -1110,10 +1110,10 @@ def _fa_bwd(q3, k3, v3, o3, lse, do3, scale, causal, block_q, block_k,
             pl.BlockSpec((1, block_k, d),
                          lambda h, i, j, b: (b_live(i, j, b) * num_heads + h,
                                              j, 0)),
-            pl.BlockSpec((1, block_k, d),
+            pl.BlockSpec((1, block_k, d_v),
                          lambda h, i, j, b: (b_live(i, j, b) * num_heads + h,
                                              j, 0)),
-            pl.BlockSpec((1, block_q, d),
+            pl.BlockSpec((1, block_q, d_v),
                          lambda h, i, j, b: (b_live(i, j, b) * num_heads + h,
                                              i, 0)),
             pl.BlockSpec((1, block_q, 1),
@@ -1284,7 +1284,7 @@ def _listed_params(interpret, semantics):
 def _fa_fwd_listed(q3, k3, v3, scale, structure, block_q, block_k, interpret):
     """(o, lse (bh, 1, sq)); ``k3``, ``v3`` hold ``bh // group`` heads."""
     bh, sq, d = q3.shape
-    sk = k3.shape[1]
+    sk, d_v = k3.shape[1], v3.shape[2]
     group = bh // k3.shape[0]
     by_row, _ = _listed_tiles(structure, sq, sk, block_q, block_k)
     row = lambda b, t, tiles: (b, tiles[0, t], 0)
@@ -1299,14 +1299,14 @@ def _fa_fwd_listed(q3, k3, v3, scale, structure, block_q, block_k, interpret):
             grid=(bh, by_row.shape[1]),
             in_specs=[pl.BlockSpec((1, block_q, d), row),
                       pl.BlockSpec((1, block_k, d), kv),
-                      pl.BlockSpec((1, block_k, d), kv)],
-            out_specs=[pl.BlockSpec((1, block_q, d), row),
+                      pl.BlockSpec((1, block_k, d_v), kv)],
+            out_specs=[pl.BlockSpec((1, block_q, d_v), row),
                        pl.BlockSpec((1, 1, block_q),
                                     lambda b, t, tiles: (b, 0, tiles[0, t]))],
             scratch_shapes=[pltpu.VMEM((block_q, 128), jnp.float32),
                             pltpu.VMEM((block_q, 128), jnp.float32),
-                            pltpu.VMEM((block_q, d), jnp.float32)]),
-        out_shape=[_sds((bh, sq, d), q3.dtype, q3, k3, v3),
+                            pltpu.VMEM((block_q, d_v), jnp.float32)]),
+        out_shape=[_sds((bh, sq, d_v), q3.dtype, q3, k3, v3),
                    _sds((bh, 1, sq), jnp.float32, q3, k3, v3)],
         compiler_params=_listed_params(interpret, ("parallel", "arbitrary")),
         interpret=interpret,
@@ -1318,6 +1318,7 @@ def _fa_bwd_listed(q3, k3, v3, o3, lse, do3, scale, structure, block_q,
     """(dq, dk, dv) on the listed schedule; dk, dv in K/V's own head count."""
     bh, sq, d = q3.shape
     bkv, sk, _ = k3.shape
+    d_v = v3.shape[2]
     group = bh // bkv
     by_row, by_col = _listed_tiles(structure, sq, sk, block_q, block_k)
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
@@ -1335,8 +1336,8 @@ def _fa_bwd_listed(q3, k3, v3, o3, lse, do3, scale, structure, block_q,
             grid=(bh, by_row.shape[1]),
             in_specs=[pl.BlockSpec((1, block_q, d), row),
                       pl.BlockSpec((1, block_k, d), kv),
-                      pl.BlockSpec((1, block_k, d), kv),
-                      pl.BlockSpec((1, block_q, d), row),
+                      pl.BlockSpec((1, block_k, d_v), kv),
+                      pl.BlockSpec((1, block_q, d_v), row),
                       pl.BlockSpec((1, 2, block_q),
                                    lambda b, t, tiles: (b, 0, tiles[0, t]))],
             out_specs=pl.BlockSpec((1, block_q, d), row),
@@ -1356,17 +1357,17 @@ def _fa_bwd_listed(q3, k3, v3, o3, lse, do3, scale, structure, block_q,
             grid=(bkv, by_col.shape[1], group),
             in_specs=[pl.BlockSpec((1, block_q, d), head),
                       pl.BlockSpec((1, block_k, d), col),
-                      pl.BlockSpec((1, block_k, d), col),
-                      pl.BlockSpec((1, block_q, d), head),
+                      pl.BlockSpec((1, block_k, d_v), col),
+                      pl.BlockSpec((1, block_q, d_v), head),
                       pl.BlockSpec((1, 2, block_q),
                                    lambda c, t, g, tiles:
                                    (c * group + g, 0, tiles[0, t]))],
             out_specs=[pl.BlockSpec((1, block_k, d), col),
-                       pl.BlockSpec((1, block_k, d), col)],
+                       pl.BlockSpec((1, block_k, d_v), col)],
             scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)]),
+                            pltpu.VMEM((block_k, d_v), jnp.float32)]),
         out_shape=[_sds((bkv, sk, d), k3.dtype, q3, k3, v3, do3),
-                   _sds((bkv, sk, d), v3.dtype, q3, k3, v3, do3)],
+                   _sds((bkv, sk, d_v), v3.dtype, q3, k3, v3, do3)],
         compiler_params=_listed_params(
             interpret, ("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
@@ -1495,8 +1496,10 @@ def _flash_listed(q, k, v, mask, structure, scale, block_q, block_k,
             "the listed schedule (a MaskStructure other than causal, or "
             "K/V with fewer heads than Q)")
     bq, bk = _pick_block(sq, block_q), _pick_block(sk, block_k)
+    dv = v.shape[3]
     tiles = (mask is None and _pick_block(sq, 128) is not None
              and _pick_block(sk, 128) is not None and d % 8 == 0
+             and dv % 8 == 0
              and (structure is None or structure.tiles(sq, sk, bq, bk)))
     if use_pallas is None:
         use_pallas = tiles and _compiled_backend()
@@ -1516,9 +1519,9 @@ def _flash_listed(q, k, v, mask, structure, scale, block_q, block_k,
     if interpret is None:
         interpret = not _compiled_backend()
     o3 = _flash3_listed(q.reshape(b * h, sq, d), k.reshape(b * hk, sk, d),
-                        v.reshape(b * hk, sk, d), scale, structure, bq, bk,
+                        v.reshape(b * hk, sk, dv), scale, structure, bq, bk,
                         interpret)
-    return o3.reshape(b, h, sq, d)
+    return o3.reshape(b, h, sq, dv)
 
 
 def _pick_block(seq: int, want: int) -> Optional[int]:
@@ -1528,10 +1531,10 @@ def _pick_block(seq: int, want: int) -> Optional[int]:
     return None
 
 
-def _pallas_ok(sq, sk, d, causal, allow_interpret):
+def _pallas_ok(sq, sk, d, causal, allow_interpret, dv=None):
     if _pick_block(sq, 128) is None or _pick_block(sk, 128) is None:
         return False
-    if d % 8 != 0:
+    if d % 8 != 0 or (dv or d) % 8 != 0:
         return False
     if causal and sq != sk:
         return False
@@ -1553,6 +1556,12 @@ def flash_attention(
     structure: Optional[MaskStructure] = None,
 ):
     """Memory-efficient attention over (batch, heads, seq, head_dim).
+
+    ``v`` may have another width than ``q`` and ``k`` (keys of 192 over
+    values of 128: latent attention's expanded heads); the output has
+    ``v``'s. Every schedule takes it: the score's products run over
+    ``q``'s width, ``p @ v`` and ``do @ v.T`` over ``v``'s, and nothing is
+    padded to make them equal.
 
     Pallas flash kernels on aligned shapes (ref capability: ``fmhalib`` +
     ``fast_multihead_attn``, without their seqlen ≤ 512 limit) for no mask
@@ -1588,6 +1597,10 @@ def flash_attention(
     b, h, sq, d = q.shape
     sk = k.shape[2]
     hk = k.shape[1]
+    dv = v.shape[3]
+    if k.shape[3] != d:
+        raise ValueError(f"q and k must have one width ({d}, {k.shape[3]}); "
+                         f"v may have its own")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if dropout_rate > 0.0 and dropout_seed is None:
@@ -1609,10 +1622,10 @@ def flash_attention(
             f"bias must be batch-shared (heads, sq, sk) = {(h, sq, sk)}, "
             f"got {bias.shape}")
     pallas_possible = mask is None and _pallas_ok(
-        sq, sk, d, causal, allow_interpret=True)
+        sq, sk, d, causal, allow_interpret=True, dv=dv)
     if use_pallas is None:
         use_pallas = mask is None and _pallas_ok(
-            sq, sk, d, causal, allow_interpret=False)
+            sq, sk, d, causal, allow_interpret=False, dv=dv)
     elif use_pallas and not pallas_possible:
         raise ValueError(
             f"pallas flash_attention needs no dense mask (a mask rides the "
@@ -1648,11 +1661,11 @@ def flash_attention(
     if bias is not None:
         o3 = _flash3_bias(
             q.reshape(b * h, sq, d), k.reshape(b * h, sk, d),
-            v.reshape(b * h, sk, d), bias, seed, scale, causal, bq, bk,
+            v.reshape(b * h, sk, dv), bias, seed, scale, causal, bq, bk,
             interpret, float(dropout_rate))
-        return o3.reshape(b, h, sq, d)
+        return o3.reshape(b, h, sq, dv)
     o3 = _flash3(
         q.reshape(b * h, sq, d), k.reshape(b * h, sk, d),
-        v.reshape(b * h, sk, d), seed, scale, causal, bq, bk, interpret,
+        v.reshape(b * h, sk, dv), seed, scale, causal, bq, bk, interpret,
         float(dropout_rate))
-    return o3.reshape(b, h, sq, d)
+    return o3.reshape(b, h, sq, dv)

@@ -10,12 +10,13 @@ A model is any object with three methods:
   ``shard_map`` over the mesh (parameters per ``param_specs()``, the batch
   split over ``dp``).
 
-``transformer.testing.GPTConfig``, ``transformer.hybrid.HybridConfig`` and
-``transformer.sdar.SDARConfig`` are. A model that counts what its step did
-(a routed layer's loads) has a fourth, ``loss_and_counters(params, tokens,
-targets)``: the loss and a pytree of arrays whose leading axis stacks over
-``dp``. Its step returns them fourth, from the step itself and at no pass of
-their own.
+``transformer.testing.GPTConfig``, ``transformer.hybrid.HybridConfig``,
+``transformer.sdar.SDARConfig`` and ``transformer.deepseek.DeepSeekConfig``
+are. A model that counts what its step did (a routed layer's loads, its
+balance loss) has a fourth, ``loss_and_counters(params, tokens, targets)``:
+the loss and a pytree of arrays whose leading axis stacks over ``dp`` (the
+block-diffusion and the latent-attention decoders have). Its step returns
+them fourth, from the step itself and at no pass of their own.
 """
 
 from __future__ import annotations

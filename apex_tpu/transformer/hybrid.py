@@ -237,13 +237,19 @@ def _linear_attention(p, x, cfg: HybridConfig):
         return y.reshape(b, s, -1) @ p["wo"]
 
 
-def _mlp(p, x):
-    with span("mlp/gate_up"):
-        gate, up = x @ p["w_gate"], x @ p["w_up"]
-    with span("mlp/act"):
+def gated_ffn(x, w_gate, w_up, w_down, scope: str = "mlp"):
+    """``(SiLU(x W_gate) * x W_up) W_down``, the gate in float32, under the
+    scopes ``<scope>/gate_up``, ``<scope>/act`` and ``<scope>/down``."""
+    with span(scope + "/gate_up"):
+        gate, up = x @ w_gate, x @ w_up
+    with span(scope + "/act"):
         y = (jax.nn.silu(gate.astype(F32)) * up.astype(F32)).astype(x.dtype)
-    with span("mlp/down"):
-        return y @ p["w_down"]
+    with span(scope + "/down"):
+        return y @ w_down
+
+
+def _mlp(p, x):
+    return gated_ffn(x, p["w_gate"], p["w_up"], p["w_down"])
 
 
 def _layer(p, x, cfg: HybridConfig, kind: str):

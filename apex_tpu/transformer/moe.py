@@ -30,13 +30,20 @@ That is :func:`moe_mlp` (top-2, GELU experts, a capacity
 (``MoEConfig.capacity``) and drops in ``_route``), which
 ``standalone_gpt.py`` runs. Beside it :func:`routed_experts_mlp` is the layer
 of the softmax-routed models with many small experts
-(``transformer/sdar.py``): **no capacity and no drops**, SiLU-gated experts,
-weights renormalised over the chosen, and the layer told which of the
-router's experts it holds (``experts_held``): one chip's share of an
-expert-parallel deployment, computed without the exchange. Every gather of
-rows it makes, in both directions, is of about a row a pair held here
-(``_to_rows`` by the buffer's rows, ``_sum_rows`` by the places a position
-holds), never of ``positions x top_k``.
+(``transformer/sdar.py``, ``transformer/deepseek.py``): **no capacity and no
+drops**, SiLU-gated experts, and the layer told which of the router's experts
+it holds (``experts_held``): one chip's share of an expert-parallel
+deployment, computed without the exchange. Two fields of
+:class:`RoutedExpertsConfig` say what a chosen expert's weight is, each what
+the published key of its name says: ``norm_topk_prob`` (true: the chosen
+scores divided by their sum, the block-diffusion decoder's Qwen3-style
+router; false: the scores as they are, DeepSeek-V2) and
+``routed_scaling_factor`` (the weights times it; DeepSeek-V2-Lite publishes
+1). The layer also hands back the router's float32 scores and choices, which
+:func:`sequence_balance_loss` (DeepSeek-V2's ``seq_aux``) reads. Every gather
+of rows the layer makes, in both directions, is of about a row a pair held
+here (``_to_rows`` by the buffer's rows, ``_sum_rows`` by the places a
+position holds), never of ``positions x top_k``.
 """
 
 from __future__ import annotations
@@ -316,6 +323,9 @@ class RoutedExpertsConfig:
     there is nothing left: exact at any imbalance."""
     num_experts: int = 128          # the router's range
     top_k: int = 8
+    # the published keys of these names (:func:`route_softmax_top_k`)
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
 
     def tile_rows(self, tokens: int) -> int:
         half = tokens * self.top_k // (2 * self.num_experts)
@@ -348,14 +358,41 @@ def routed_expert_shapes(hidden: int, width: int, num_experts: int,
             "w_down": (count, width, hidden)}
 
 
-def route_softmax_top_k(x, router, top_k: int):
-    """``(idx, weight)``, both (tokens, top_k): ``s = softmax(x W_r)`` over
-    every expert in float32, the ``top_k`` largest, ``w_i = s_i / sum of the
-    chosen s`` (``norm_topk_prob``)."""
+def route_softmax_top_k(x, router, top_k: int, norm_topk_prob: bool = True,
+                        routed_scaling_factor: float = 1.0):
+    """``(idx, weight, scores)``: ``scores = softmax(x W_r)`` (tokens,
+    experts) over every expert in float32; ``idx`` (tokens, top_k) its
+    ``top_k`` largest; ``weight`` (tokens, top_k) what each chosen expert's
+    result is multiplied by: with ``norm_topk_prob`` ``s_i / sum of the
+    chosen s`` (the block-diffusion decoder), without it ``s_i`` as scored
+    (DeepSeek-V2: the chosen weights then sum to less than one), either
+    times ``routed_scaling_factor``."""
     logits = jnp.dot(x.astype(F32), router.astype(F32),
                      precision=lax.Precision.HIGHEST)
-    chosen, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
-    return idx, chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    scores = jax.nn.softmax(logits, axis=-1)
+    chosen, idx = lax.top_k(scores, top_k)
+    if norm_topk_prob:
+        chosen = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    if routed_scaling_factor != 1.0:
+        chosen = chosen * routed_scaling_factor
+    return idx, chosen, scores
+
+
+def sequence_balance_loss(scores, idx, rows: int, alpha: float):
+    """DeepSeek-V2's expert-level balance loss a sequence (``seq_aux``), one
+    layer's: ``scores`` (rows x length, experts) float32 and ``idx`` (rows x
+    length, top_k) as :func:`route_softmax_top_k` hands them out, the
+    positions of a row together. A row: ``f_e = (times e is among a
+    position's top_k) x experts / (top_k x length)``, ``P_e = mean over the
+    row of s_e``, ``alpha x sum_e f_e P_e``; the mean over rows. ``f`` is a
+    count, so the gradient goes through ``P`` alone."""
+    e, k = scores.shape[-1], idx.shape[-1]
+    length = scores.shape[0] // rows
+    picked = jnp.sum(idx.reshape(rows, length * k)[:, :, None]
+                     == jnp.arange(e)[None, None, :], axis=1, dtype=F32)
+    f = picked * (e / (k * length))
+    p = jnp.mean(scores.reshape(rows, length, e), axis=1)
+    return alpha * jnp.mean(jnp.sum(f * p, axis=-1))
 
 
 def _has_row(rank, n: int):
@@ -529,9 +566,11 @@ def routed_experts_mlp(p, x, cfg: RoutedExpertsConfig,
     ``p``: :func:`routed_expert_shapes`' leaves. ``experts_held = (first,
     count)``: the experts ``first .. first + count - 1`` of the router's
     range are ``p["w_gate"][0 .. count - 1]`` and so on. Every position is
-    routed over all ``cfg.num_experts`` and its weights are normalised over
-    all ``cfg.top_k`` it chose; what the experts not held would add is left
-    out, and no code stands in for the chips that hold them.
+    routed over all ``cfg.num_experts`` and its weights are those of all
+    ``cfg.top_k`` it chose (normalised over them or as scored, by
+    ``cfg.norm_topk_prob``; times ``cfg.routed_scaling_factor``); what the
+    experts not held would add is left out, and no code stands in for the
+    chips that hold them.
 
     **No position is dropped, at any imbalance**, shapes are static, and the
     work follows the pairs held (:class:`RoutedExpertsConfig`): the first
@@ -539,12 +578,15 @@ def routed_experts_mlp(p, x, cfg: RoutedExpertsConfig,
     ``jax.checkpoint``, so that it keeps nothing) that runs only while pairs
     are left.
 
-    Returns ``(y, counted)``, what the layout itself counted, int32
-    (:func:`routing_facts` reads a step's counters from them):
-    ``counted["expert_loads"]`` (count,), the pairs each held expert took,
-    and ``counted["held_places"]`` (top_k + 1,), how many positions hold
-    exactly 0 .. top_k pairs with a row in the first pass: what the gathers
-    of :func:`_sum_rows` follow."""
+    Returns ``(y, counted, routed)``. ``counted`` is what the layout itself
+    counted, int32 (:func:`routing_facts` reads a step's counters from
+    them): ``counted["expert_loads"]`` (count,), the pairs each held expert
+    took, and ``counted["held_places"]`` (top_k + 1,), how many positions
+    hold exactly 0 .. top_k pairs with a row in the first pass: what the
+    gathers of :func:`_sum_rows` follow. ``routed`` is the router's own
+    result over the flattened positions: ``routed["scores"]`` (tokens,
+    num_experts) float32 and ``routed["idx"]`` (tokens, top_k), for a
+    balance loss (:func:`sequence_balance_loss`)."""
     lead, h = x.shape[:-1], x.shape[-1]
     xf = x.reshape(-1, h)
     t, k = xf.shape[0], cfg.top_k
@@ -561,7 +603,8 @@ def routed_experts_mlp(p, x, cfg: RoutedExpertsConfig,
         p, xf = lax.optimization_barrier((
             {name: p[name] for name in ("router", "w_gate", "w_up", "w_down")},
             xf))
-        idx, weight = route_softmax_top_k(xf, p["router"], k)
+        idx, weight, scores = route_softmax_top_k(
+            xf, p["router"], k, cfg.norm_topk_prob, cfg.routed_scaling_factor)
     with span("moe/dispatch"):
         local = idx - first
         key = jnp.where((local >= 0) & (local < count), local, count
@@ -604,8 +647,9 @@ def routed_experts_mlp(p, x, cfg: RoutedExpertsConfig,
         with span("moe/combine"):
             y = y + lax.cond(c * n < total, later,
                              lambda c: jnp.zeros_like(y), jnp.int32(c))
-    return y.reshape(*lead, h), {"expert_loads": sizes,
-                                 "held_places": held_places}
+    return (y.reshape(*lead, h),
+            {"expert_loads": sizes, "held_places": held_places},
+            {"scores": scores, "idx": idx})
 
 
 def routing_facts(loads, tokens: int, cfg: RoutedExpertsConfig,

@@ -211,7 +211,7 @@ def _experts_sublayer(p, x, cfg: SDARConfig):
     """``(x + MoE(RMSNorm(x)), what the routed layer counted)``."""
     with span("pre_norm"):
         m = rms_norm(x, p["norm2"], cfg.norm_eps)
-    m, counted = routed_experts_mlp(p, m, cfg.routed, cfg.experts_held)
+    m, counted, _ = routed_experts_mlp(p, m, cfg.routed, cfg.experts_held)
     with span("residual"):
         return x + m, counted
 

@@ -421,3 +421,74 @@ def test_tile_plan_vmem_budget_and_tiling(monkeypatch, d, dtype, block, want):
     _force_schedule(monkeypatch, "resident")
     s = 1024 if block >= 512 else 16
     assert _tile_plan(s, s, d, dtype, True, block, block).schedule == want
+
+
+# ---------------------------------------------------------------------------
+# a value width of its own (latent attention's expanded heads: keys of
+# 128 + 64 over values of 128)
+
+def _qkv_widths(key, b, h, s, d, dv, dtype=jnp.float32):
+    ks = jax.random.split(key, 3)
+    return (jax.random.normal(ks[0], (b, h, s, d), dtype),
+            jax.random.normal(ks[1], (b, h, s, d), dtype),
+            jax.random.normal(ks[2], (b, h, s, dv), dtype))
+
+
+@pytest.mark.parametrize("d,dv", [(192, 128), (24, 16), (16, 24)])
+@pytest.mark.parametrize("schedule", ["resident", "streamed"])
+def test_value_width_of_its_own_matches_reference(monkeypatch, schedule, d, dv):
+    """Causal, forward and all three gradients against the reference: the
+    score's products run over q's width, ``p @ v`` and ``do @ v.T`` over
+    v's, on either schedule; the output and dv have v's width."""
+    from apex_tpu.ops.attention import _tile_plan
+
+    _force_schedule(monkeypatch, schedule)
+    b, h, s = 1, 2, 128
+    q, k, v = _qkv_widths(jax.random.PRNGKey(d + dv), b, h, s, d, dv)
+    assert _tile_plan(s, s, d, q.dtype, True, 64, 64).schedule == schedule
+    scale = 0.114721 if d == 192 else None        # the cell's: 192^-1/2 m^2
+
+    def loss_flash(q, k, v):
+        o = flash_attention(q, k, v, causal=True, use_pallas=True, scale=scale,
+                            block_q=64, block_k=64)
+        return jnp.sum(jnp.sin(o)), o
+
+    def loss_ref(q, k, v):
+        o = attention_reference(q, k, v, causal=True, scale=scale)
+        return jnp.sum(jnp.sin(o)), o
+
+    g1, o1 = jax.grad(loss_flash, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    g2, o2 = jax.grad(loss_ref, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    assert o1.shape == (b, h, s, dv)
+    assert [g.shape for g in g1] == [q.shape, k.shape, v.shape]
+    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=2e-5)
+    for a, e, name in zip(g1, g2, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(e), atol=2e-4,
+                                   err_msg=name)
+
+
+def test_value_width_of_its_own_in_bfloat16_and_on_the_listed_schedule():
+    """bf16 at the cell's widths; and grouped heads (the listed schedule)
+    take a value width of their own too."""
+    q, k, v = _qkv_widths(jax.random.PRNGKey(5), 1, 4, 128, 192, 128, jnp.bfloat16)
+    o = flash_attention(q, k, v, causal=True, use_pallas=True, block_q=64, block_k=64)
+    want = attention_reference(q, k, v, causal=True)
+    assert o.dtype == jnp.bfloat16 and o.shape == (1, 4, 128, 128)
+    np.testing.assert_allclose(np.asarray(o, np.float32), np.asarray(want, np.float32),
+                               atol=3e-2)
+    q, k, v = _qkv_widths(jax.random.PRNGKey(6), 1, 4, 128, 24, 16)
+    k, v = k[:, :2], v[:, :2]           # two K/V heads under four query heads
+    loss = lambda f: lambda q, k, v: jnp.sum(jnp.sin(f(q, k, v)))
+    got = jax.grad(loss(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, use_pallas=True, block_q=64, block_k=64)), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: attention_reference(q, k, v, causal=True)),
+                    argnums=(0, 1, 2))(q, k, v)
+    for a, e in zip(got, want):
+        assert a.shape == e.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(e), atol=2e-4)
+
+
+def test_keys_of_another_width_than_queries_are_refused():
+    q, k, v = _qkv_widths(jax.random.PRNGKey(7), 1, 2, 64, 32, 32)
+    with pytest.raises(ValueError, match="one width"):
+        flash_attention(q, k[..., :16], v)

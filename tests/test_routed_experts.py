@@ -55,7 +55,7 @@ def _layer(p, x, held):
 
 
 def _loads(p, x, first, count):
-    idx, _ = route_softmax_top_k(x, p["router"], K)
+    idx, _, _ = route_softmax_top_k(x, p["router"], K)
     return np.bincount(np.asarray(idx).reshape(-1), minlength=E)[first:first + count]
 
 
@@ -63,7 +63,7 @@ def _loads(p, x, first, count):
 def test_layer_equals_the_dense_loop_over_the_held_experts(first, count):
     p, x = _params(count), _x()
     with jax.default_matmul_precision("highest"):
-        got, counted = routed_experts_mlp(p, x, _cfg(), (first, count))
+        got, counted, _ = routed_experts_mlp(p, x, _cfg(), (first, count))
         want = _dense(p, x, first, count)
     np.testing.assert_allclose(got, want, atol=2e-5)
     np.testing.assert_array_equal(counted["expert_loads"], _loads(p, x, first, count))
@@ -95,7 +95,7 @@ def test_no_position_is_dropped_when_every_one_chooses_the_same_held_expert():
     x = _x(6).at[:, 0].set(10.0)
     p["router"] = jnp.zeros((H, E)).at[0, 2].set(5.0).at[0, 5].set(4.0)
     with jax.default_matmul_precision("highest"):
-        got, counted = routed_experts_mlp(p, x, _cfg(), (first, count))
+        got, counted, _ = routed_experts_mlp(p, x, _cfg(), (first, count))
         want = _dense(p, x, first, count)
     facts = routing_facts(counted["expert_loads"], T, _cfg())
     assert facts["pairs_held"] == T and facts["passes_run"] == 2
@@ -123,7 +123,7 @@ def test_the_shares_of_four_chips_add_up_to_the_uncut_layer():
 
 def test_weights_are_normalised_over_all_the_chosen_not_over_the_held():
     p, x = _params(1, key=9), _x(10)
-    idx, w = route_softmax_top_k(x, p["router"], K)
+    idx, w, _ = route_softmax_top_k(x, p["router"], K)
     np.testing.assert_allclose(w.sum(-1), 1.0, atol=1e-6)
     held_only = jnp.sum(jnp.where(idx == 3, w, 0.0), -1)
     assert float(held_only.max()) < 1.0           # the other choice keeps its part
@@ -135,7 +135,7 @@ def test_the_router_is_float32_whatever_the_models_type():
     benchmark's limits do not hold (PERF.md §7) and this test does."""
     x = _x(13, n=512).astype(jnp.bfloat16)
     router = _params(1, key=14)["router"].astype(jnp.bfloat16)
-    idx, w = route_softmax_top_k(x, router, K)
+    idx, w, _ = route_softmax_top_k(x, router, K)
     assert w.dtype == jnp.float32
     logits = np.asarray(x, np.float64) @ np.asarray(router, np.float64)
     s = np.exp(logits - logits.max(-1, keepdims=True))
@@ -415,3 +415,89 @@ def test_the_work_follows_the_pairs_held_not_positions_times_top_k():
     assert facts["passes_run"] == 1 and 0 < facts["pairs_held"] < t * k // 2
     assert facts["rows_gathered"] <= facts["pairs_held"] + k * chunk + t
     assert facts["rows_gathered"] < t * k // 2
+
+
+# ---------------------------------------------------------------------------
+# the two published keys: norm_topk_prob and routed_scaling_factor; the scores
+# handed out and the balance loss a sequence
+
+from apex_tpu.transformer.moe import sequence_balance_loss
+
+
+def test_the_renormalised_path_is_bit_for_bit_the_parents():
+    """``norm_topk_prob`` true, factor 1 (the defaults, the block-diffusion
+    decoder's): the parent's two lines, evaluated as the parent did."""
+    p, x = _params(1, key=40), _x(41)
+    idx, w, scores = route_softmax_top_k(x, p["router"], K)
+    logits = jnp.dot(x.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    chosen, want_idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)
+    np.testing.assert_array_equal(idx, want_idx)
+    np.testing.assert_array_equal(w, chosen / jnp.sum(chosen, axis=-1, keepdims=True))
+    np.testing.assert_array_equal(scores, jax.nn.softmax(logits, axis=-1))
+    same = route_softmax_top_k(x, p["router"], K, True, 1.0)
+    np.testing.assert_array_equal(w, same[1])
+    assert RoutedExpertsConfig(E, K) == RoutedExpertsConfig(E, K, True, 1.0)
+
+
+@pytest.mark.parametrize("factor", [1.0, 2.5])
+def test_weights_as_scored_are_the_scores_times_the_factor(factor):
+    p, x = _params(1, key=42), _x(43)
+    idx, w, scores = route_softmax_top_k(x, p["router"], K, False, factor)
+    np.testing.assert_allclose(w, factor * jnp.take_along_axis(scores, idx, axis=-1), rtol=1e-6)
+    assert float((w.sum(-1) / factor).max()) < 1.0        # not renormalised
+    np.testing.assert_allclose(scores.sum(-1), 1.0, atol=1e-6)
+
+
+@pytest.mark.parametrize("factor", [1.0, 2.5])
+def test_the_layer_as_scored_against_the_dense_loop(factor):
+    """The layer with ``norm_topk_prob`` false: each held expert's result
+    times its score as it is (times the factor), forward and gradients."""
+    first, count = 2, 4
+    p, x = _params(count, key=44), _x(45)
+    cfg = RoutedExpertsConfig(E, K, norm_topk_prob=False, routed_scaling_factor=factor)
+
+    def dense(p, x):
+        s = jax.nn.softmax(jnp.dot(x, p["router"], precision="highest"), axis=-1)
+        chosen, idx = jax.lax.top_k(s, K)
+        y = jnp.zeros_like(x)
+        for e in range(count):
+            we = factor * jnp.sum(jnp.where(idx == first + e, chosen, 0.0), axis=-1)
+            out = (jax.nn.silu(x @ p["w_gate"][e]) * (x @ p["w_up"][e])) @ p["w_down"][e]
+            y = y + we[:, None] * out
+        return y
+
+    with jax.default_matmul_precision("highest"):
+        got, _, routed = routed_experts_mlp(p, x, cfg, (first, count))
+        np.testing.assert_allclose(got, dense(p, x), atol=3e-5)
+        loss = lambda f: lambda p, x: jnp.sum(jnp.sin(f(p, x)))
+        g = jax.grad(loss(lambda p, x: routed_experts_mlp(p, x, cfg, (first, count))[0]),
+                     argnums=(0, 1))(p, x)
+        want = jax.grad(loss(dense), argnums=(0, 1))(p, x)
+    for a, e in zip(jax.tree.leaves(g), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, e, atol=1e-4)
+    assert routed["scores"].shape == (T, E) and routed["scores"].dtype == jnp.float32
+    assert routed["idx"].shape == (T, K)
+
+
+def test_the_balance_loss_by_hand_and_its_gradient_goes_through_p_only():
+    rows, length, alpha = 2, 48, 0.01
+    p, x = _params(1, key=46), _x(47)
+    idx, _, scores = route_softmax_top_k(x, p["router"], K, False)
+    got = sequence_balance_loss(scores, idx, rows, alpha)
+    s, i = np.asarray(scores, np.float64).reshape(rows, length, E), np.asarray(idx).reshape(rows, -1)
+    want = 0.0
+    for r in range(rows):
+        f = np.bincount(i[r], minlength=E) * E / (K * length)
+        want += alpha * float(np.sum(f * s[r].mean(0))) / rows
+    assert float(got) == pytest.approx(want, rel=1e-5)
+    # a uniform router reads alpha; a collapsed one more
+    flat = jnp.full((T, E), 1.0 / E)
+    even = jnp.tile(jnp.arange(E).reshape(-1, K), (T * K // E, 1))
+    assert float(sequence_balance_loss(flat, even, rows, alpha)) == pytest.approx(alpha)
+    # the gradient: d/ds of alpha mean_rows sum_e f_e mean_t s_e, f held still
+    g = jax.grad(lambda s: sequence_balance_loss(s, idx, rows, alpha))(scores)
+    f = np.stack([np.bincount(i[r], minlength=E) * E / (K * length) for r in range(rows)])
+    np.testing.assert_allclose(np.asarray(g).reshape(rows, length, E),
+                               np.broadcast_to(alpha * f[:, None, :] / (length * rows),
+                                               (rows, length, E)), rtol=1e-5)

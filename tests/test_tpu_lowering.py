@@ -162,6 +162,36 @@ def test_flash_attention_listed_schedule_compiles_at_the_block_diffusion_cells_s
     assert compiled.memory_analysis().temp_size_in_bytes < 400 * 2**20
 
 
+@pytest.mark.parametrize("heads,seq,d,dv,schedule", [
+    (16, 16384, 192, 128, "streamed"), (4, 1024, 24, 16, "resident")])
+def test_flash_attention_compiles_with_a_value_width_of_its_own(heads, seq, d, dv, schedule):
+    """Keys of 192 over values of 128 at the latent-attention cell's shape
+    (16 heads, 16,384 positions, causal, bf16: the streamed schedule), and a
+    small pair on the resident one, through Mosaic's own compiler for a
+    described v5e: 192 is not a multiple of the 128 lanes, and nothing is
+    padded in HBM to make it one (q, k and their gradients keep 192, v, o and
+    their gradients 128). All three kernels are in the compiled program once,
+    under the names the trace and ``mla_attn_roofline`` read."""
+    from apex_tpu.ops._pallas_util import compile_for_tpu, mosaic_calls
+    from apex_tpu.ops.attention import _tile_plan, flash_attention
+
+    assert _tile_plan(seq, seq, d, jnp.bfloat16, True).schedule == schedule
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, causal=True, scale=0.114721)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    q = jax.ShapeDtypeStruct((1, heads, seq, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, heads, seq, dv), jnp.bfloat16)
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    _, compiled = compile_for_tpu(jax.jit(grad), q, q, v)
+    calls = mosaic_calls(compiled.as_text())
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert sum(n for name, n in calls.items() if kernel in name) == 1, (kernel, calls)
+    dq, dk, dvv = jax.eval_shape(grad, q, q, v)
+    assert dq.shape == dk.shape == q.shape and dvv.shape == v.shape
+
+
 # Characters of the lowered text (StableHLO with the three kernels' Mosaic
 # payloads) of one ``jax.checkpoint``ed flash forward-and-backward at the
 # benchmark cells' shape (16 x 20 heads, s 1024, d 64, bf16, causal), at the
