@@ -38,8 +38,8 @@ Who loops over the tiles is the *tile schedule*, chosen per shape by
   head ``i // group``, dK and dV are summed over the group inside the
   kernel, nothing is repeated in HBM).
 
-Layout: (batch, heads, seq, head_dim) — matches the Megatron attention core
-the transformer layer uses.
+Layout: ``flash_attention`` takes (batch, heads, seq, head_dim); at head_dim 64
+(half a lane tile) ``flash_attention_packed`` takes the QKV product: file's end.
 """
 
 from __future__ import annotations
@@ -1669,3 +1669,337 @@ def flash_attention(
         v.reshape(b * h, sk, dv), seed, scale, causal, bq, bk, interpret,
         float(dropout_rate))
     return o3.reshape(b, h, sq, dv)
+
+
+# ---------------------------------------------------------------------------
+# The packed entry: head size 64, half of the 128 lanes.
+#
+# (batch, heads, seq, head_dim) at head_dim 64 fills half of each 128-lane
+# tile: in HBM every operand is padded to twice its bytes, and XLA keeps the
+# projections on either side in other layouts and copies between them (17
+# copies a GPT-2 layer, forward, replay and backward; PERF.md section 6, PR
+# 36). Here the operands keep the layout the projections give and take: the
+# QKV product as it stands, (batch, seq, heads x [q k v] x 64), and o as
+# (batch, seq, heads x 64). A pair of heads fills whole lanes: 384 of the
+# product, three blocks of 128, [q0 k0] [v0 q1] [k1 v1], and 128 of o,
+# [o0 o1]. A grid step runs one head of a pair on the resident schedule's
+# kernel bodies as they are, on 128-lane operands: the head's q with its
+# neighbour's half zeroed and the halves swapped (one lane rotation), so it
+# meets k in k's half and the other half adds exact zeros to every score;
+# k's and v's blocks whole. The MXU contracts over 128 lanes at head size 64
+# either way, and its results are 128 lanes wide either way: the half of
+# p @ [v0 q1] that is o0 lands in o0's lanes, and so do dk and dv in theirs
+# (dq lands in k's and is rotated back), so nothing is sliced or stored by
+# halves: a result is written over its half of the pair's block, the other
+# half kept. lse is lane-dense, (batch, pairs, 2, seq); delta is summed in
+# the kernels from do and o. dq leaves its kernel in o's layout and the
+# dK/dV kernel writes the three gradients as [dq0 dk0] [dv0 dq1] [dk1 dv1]:
+# the product's cotangent, as the weight-gradient product reads it. What it
+# costs in the kernels: an ``a @ b.T`` product's right operand (k, v) is
+# turned at 128 lanes where (rows, 64) turned at 64 (PERF.md section 6).
+
+_PACKED_D = 64
+
+
+def packed_plan(seq, heads, d, dtype, causal, block_q=512,
+                block_k=512) -> Optional[TilePlan]:
+    """The plan of a packed call, or None where the shape is not one the
+    packed kernels take: head size 64 (half the lanes: a smaller divisor of
+    128 would want a rotation that differs by head), whole pairs of heads,
+    and a call the plan holds resident."""
+    if d != _PACKED_D or heads % 2 or _pick_block(seq, 128) is None:
+        return None
+    plan = _tile_plan(seq, seq, d, dtype, causal, block_q, block_k)
+    return plan if plan.schedule == "resident" else None
+
+
+def _half_of_head(p, rows):
+    """(rows, 128) mask of the lanes that are head ``p``'s (the head of the
+    pair, traced or not) in a block that holds a pair's operand side by
+    side."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 1)
+    return (lane >= _PACKED_D) ^ (p == 0)
+
+
+def _pick_half(half, x, other=None):
+    """``x`` on the lanes of ``half`` and ``other`` (zeros if None) on the
+    rest."""
+    return jax.lax.select(half, x, jnp.zeros_like(x) if other is None
+                          else other)
+
+
+def _swap_halves(x):
+    """The two 64-lane halves of a (rows, 128) tile swapped: one lane
+    rotation, on 32-bit words (Mosaic rotates nothing narrower; a bfloat16
+    tile is rotated as the words that hold two of its rows, and a lane is a
+    lane either way)."""
+    if x.dtype.itemsize == 4:
+        return pltpu.roll(x, _PACKED_D, 1)
+    words = pltpu.roll(pltpu.bitcast(x, jnp.uint32), _PACKED_D, 1)
+    return pltpu.bitcast(words, x.dtype)
+
+
+class _HeadOf:
+    """A head of a (1, rows, 128) block that holds a pair's operand, read
+    as the resident kernels read a head's block: the other head's half
+    zeroed (``half``: the head's lanes), so a product over the 128 lanes is
+    the head's own. ``swap``: the halves swapped, which brings q into k's
+    half."""
+
+    def __init__(self, ref, half, swap=False):
+        self.ref, self.half, self.swap = ref, half, swap
+        self.shape = ref.shape
+
+    def __getitem__(self, at):
+        x = _pick_half(self.half, self.ref[at])
+        return _swap_halves(x) if self.swap else x
+
+
+class _IntoHalf:
+    """A pair's result block (1, rows, 128), written as the resident
+    kernels write a head's: the 128-lane result's half ``half`` is the
+    head's, the other is left as it is. ``swap``: the result's halves
+    swapped first (dq comes out in k's half)."""
+
+    def __init__(self, ref, half, swap=False):
+        self.ref, self.half, self.swap = ref, half, swap
+        self.shape, self.dtype = ref.shape, ref.dtype
+
+    def __setitem__(self, at, value):
+        if self.swap:
+            value = _swap_halves(value)
+        self.ref[at] = _pick_half(self.half, value, self.ref[at])
+
+
+class _Head:
+    """Head ``p`` (traced) of a scratch ref (2, rows, 128), written as the
+    one-head block (1, rows, 128) the resident kernels write."""
+
+    def __init__(self, ref, p):
+        self.ref, self.p = ref, p
+        self.shape, self.dtype = (1,) + ref.shape[1:], ref.dtype
+
+    def __setitem__(self, at, value):
+        self.ref[(self.p,) + at[1:]] = value
+
+
+class _HeadStats:
+    """Row ``p`` (traced) of a lane-dense statistics block (1, 1, 2, seq),
+    indexed as the (1, seq, 1) column the resident kernels take: a tile's
+    rows fill a (128, rows) tile's sublanes and one transpose turns them
+    (the listed schedule's way)."""
+
+    def __init__(self, ref, p):
+        self.ref, self.p = ref, p
+
+    def __getitem__(self, at):
+        row = self.ref[0, 0, pl.ds(self.p, 1), at[1]]
+        return jnp.transpose(jnp.broadcast_to(row, (128, row.shape[1])))[:, :1]
+
+    def __setitem__(self, at, col):
+        tile = jnp.broadcast_to(col, (col.shape[0], 128))
+        self.ref[0, 0, pl.ds(self.p, 1), at[1]] = jnp.transpose(tile)[:1]
+
+
+class _HeadDelta:
+    """delta = rowsum(do x o) over a head's half of the pair's blocks, as
+    the (1, seq, 1) column the resident kernels take. ``do``: the head's
+    ``_HeadOf``, the other half zeroed."""
+
+    def __init__(self, do, o_ref):
+        self.do, self.o_ref = do, o_ref
+
+    def __getitem__(self, at):
+        return jnp.sum(self.do[at].astype(jnp.float32)
+                       * self.o_ref[at].astype(jnp.float32),
+                       axis=1, keepdims=True)
+
+
+def _head_and_half(block_q):
+    """(head of the pair this grid step runs, the mask of its lanes over a
+    q tile)."""
+    p = pl.program_id(2)
+    return p, _half_of_head(p, block_q)
+
+
+def _fa_fwd_packed_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                          **static):
+    p, half = _head_and_half(static["block_q"])
+    _fa_fwd_resident_kernel(
+        seed_ref, _HeadOf(q_ref, half, swap=True), k_ref, v_ref,
+        _IntoHalf(o_ref, half), _HeadStats(lse_ref, p), **static)
+
+
+def _fa_bwd_dq_packed_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
+                             lse_ref, dq_ref, **static):
+    p, half = _head_and_half(static["block_q"])
+    do = _HeadOf(do_ref, half)
+    _fa_bwd_dq_resident_kernel(
+        seed_ref, _HeadOf(q_ref, half, swap=True), k_ref, v_ref, do,
+        _HeadStats(lse_ref, p), _HeadDelta(do, o_ref),
+        _IntoHalf(dq_ref, half, swap=True), **static)
+
+
+def _fa_bwd_dkv_packed_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
+                              lse_ref, dq_ref, dqkv_ref, dk_scr, dv_scr,
+                              **static):
+    """dk and dv of the pair's heads (each in its own half: dk in k's, where
+    the swapped q put it, dv in v's), written with the dq kernel's result as
+    the packed product's cotangent."""
+    p, half = _head_and_half(static["block_q"])
+    do = _HeadOf(do_ref, half)
+    _fa_bwd_dkv_resident_kernel(
+        seed_ref, _HeadOf(q_ref, half, swap=True), k_ref, v_ref, do,
+        _HeadStats(lse_ref, p), _HeadDelta(do, o_ref), _Head(dk_scr, p),
+        _Head(dv_scr, p), **static)
+
+    @pl.when(p == 1)
+    def _pack():
+        dq = dq_ref[0]
+        low = _half_of_head(0, dq.shape[0])
+        dqkv_ref[0] = jnp.concatenate(
+            [_pick_half(low, dq, dk_scr[0]), _pick_half(low, dv_scr[0], dq),
+             _pick_half(low, dk_scr[1], dv_scr[1])], axis=-1)
+
+
+def _fa_packed_specs(b, seq, heads, dtype, like):
+    """What the three packed kernels share: the grid (a step a row, a pair
+    of heads and a head of the pair; over the last no result's block moves
+    and Mosaic elides the repeated DMA), the specs of the three 128-lane
+    blocks of the product that hold the head's q, k and v, of a pair's
+    block of o (do, dq), of the product's whole 384 lanes and of the
+    lane-dense statistics."""
+    pairs, d = heads // 2, _PACKED_D
+    lanes = lambda index: pl.BlockSpec((1, seq, 128), index)
+    return dict(
+        grid=(b, pairs, 2),
+        q=lanes(lambda i, g, p: (i, 0, 3 * g + p)),
+        k=lanes(lambda i, g, p: (i, 0, 3 * g + 2 * p)),
+        v=lanes(lambda i, g, p: (i, 0, 3 * g + 1 + p)),
+        o=lanes(lambda i, g, p: (i, 0, g)),
+        qkv=pl.BlockSpec((1, seq, 384), lambda i, g, p: (i, 0, g)),
+        stats=pl.BlockSpec((1, 1, 2, seq), lambda i, g, p: (i, g, 0, 0)),
+        smem=pl.BlockSpec(memory_space=pltpu.SMEM),
+        params=("parallel", "parallel", "arbitrary"),
+        o_shape=_sds((b, seq, heads * d), dtype, *like),
+        stats_shape=_sds((b, pairs, 2, seq), jnp.float32, *like))
+
+
+def _fa_fwd_packed(qkv, heads, scale, causal, block_q, block_k, interpret):
+    """(o (b, s, heads x 64), lse (b, pairs, 2, s)) of the packed product
+    (b, s, heads x 3 x 64) on the resident schedule."""
+    b, seq, _ = qkv.shape
+    at = _fa_packed_specs(b, seq, heads, qkv.dtype, (qkv,))
+    return pl.pallas_call(
+        functools.partial(
+            _fa_fwd_packed_kernel, scale=scale, causal=causal,
+            block_q=block_q, block_k=block_k, dropout_rate=0.0),
+        name="flash_fwd",
+        grid=at["grid"],
+        in_specs=[at["smem"], at["q"], at["k"], at["v"]],
+        out_specs=[at["o"], at["stats"]],
+        out_shape=[at["o_shape"], at["stats_shape"]],
+        compiler_params=_listed_params(interpret, at["params"]),
+        interpret=interpret,
+    )(_seed3(None), qkv, qkv, qkv)
+
+
+def _fa_bwd_packed(qkv, o, lse, do, heads, scale, causal, block_q, block_k,
+                   interpret):
+    """The packed product's cotangent (b, s, heads x 3 x 64)."""
+    b, seq, _ = qkv.shape
+    at = _fa_packed_specs(b, seq, heads, qkv.dtype, (qkv, do))
+    static = dict(scale=scale, causal=causal, block_q=block_q,
+                  block_k=block_k, dropout_rate=0.0)
+    call = dict(grid=at["grid"], interpret=interpret,
+                compiler_params=_listed_params(interpret, at["params"]))
+    inputs = (_seed3(None), qkv, qkv, qkv, do, o, lse)
+    in_specs = [at["smem"], at["q"], at["k"], at["v"], at["o"], at["o"],
+                at["stats"]]
+    dq = pl.pallas_call(
+        functools.partial(_fa_bwd_dq_packed_kernel, **static),
+        name="flash_bwd_dq", in_specs=in_specs, out_specs=at["o"],
+        out_shape=at["o_shape"], **call)(*inputs)
+    return pl.pallas_call(
+        functools.partial(_fa_bwd_dkv_packed_kernel, **static),
+        name="flash_bwd_dkv", in_specs=in_specs + [at["o"]],
+        out_specs=at["qkv"], out_shape=_sds(qkv.shape, qkv.dtype, qkv, do),
+        scratch_shapes=[pltpu.VMEM((2, seq, 128), qkv.dtype)] * 2,
+        **call)(*inputs, dq)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
+def _flash_packed(qkv, heads, scale, causal, block_q, block_k, interpret):
+    return _fa_fwd_packed(qkv, heads, scale, causal, block_q, block_k,
+                          interpret)[0]
+
+
+def _flash_packed_fwd(qkv, heads, scale, causal, block_q, block_k, interpret):
+    o, lse = _fa_fwd_packed(qkv, heads, scale, causal, block_q, block_k,
+                            interpret)
+    # the backward's residuals by name, as in ``_flash3_fwd``
+    o = checkpoint_name(o, "attn_out")
+    lse = checkpoint_name(lse, "attn_lse")
+    return o, (qkv, o, lse)
+
+
+def _flash_packed_bwd(heads, scale, causal, block_q, block_k, interpret, res,
+                      do):
+    qkv, o, lse = res
+    return (_fa_bwd_packed(qkv, o, lse, do, heads, scale, causal, block_q,
+                           block_k, interpret),)
+
+
+_flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
+
+
+def unpack_qkv(qkv, heads):
+    """The packed product (b, s, heads x [q k v] x d) -> q, k, v in
+    :func:`flash_attention`'s layout, (b, heads, s, d)."""
+    b, seq, width = qkv.shape
+    qkv = qkv.reshape(b, seq, heads, 3, width // (3 * heads))
+    return tuple(qkv[:, :, :, i].transpose(0, 2, 1, 3) for i in range(3))
+
+
+def flash_attention_packed(qkv, heads: int, causal: bool = False,
+                           scale: Optional[float] = None, block_q: int = 512,
+                           block_k: int = 512,
+                           use_pallas: Optional[bool] = None,
+                           interpret: Optional[bool] = None):
+    """Attention over the QKV product as the projection leaves it:
+    ``qkv`` (batch, seq, heads x [q k v] x head_dim), a head's q, k and v
+    side by side; returns o as (batch, seq, heads x head_dim), which the
+    output projection reads as it stands. The same function as
+    :func:`flash_attention` on :func:`unpack_qkv`'s operands, bit for bit
+    where both run the kernels: the same tiles and tile bodies on the
+    resident schedule, only the operands' indexing differs.
+
+    The packed kernels run where :func:`packed_plan` gives a plan (head
+    size 64, whole pairs of heads, a call the resident schedule holds) on a
+    compiled backend; any other call goes through :func:`flash_attention`
+    and its layout. Dropout, a dense mask and the bias are
+    :func:`flash_attention`'s."""
+    b, seq, width = qkv.shape
+    if width % (3 * heads):
+        raise ValueError(f"qkv's width {width} is not heads ({heads}) x 3 x "
+                         f"head_dim")
+    d = width // (3 * heads)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    plan = packed_plan(seq, heads, d, qkv.dtype, causal, block_q, block_k)
+    if use_pallas is None:
+        use_pallas = plan is not None and _compiled_backend()
+    elif use_pallas and plan is None:
+        raise ValueError(
+            f"the packed flash kernels need head size {_PACKED_D}, whole "
+            f"pairs of heads and a call the resident schedule holds (got "
+            f"qkv {qkv.shape}, heads {heads}, causal={causal})")
+    if not use_pallas:
+        o = flash_attention(*unpack_qkv(qkv, heads), causal=causal,
+                            scale=scale, block_q=block_q, block_k=block_k,
+                            interpret=interpret)
+        return o.transpose(0, 2, 1, 3).reshape(b, seq, heads * d)
+    if interpret is None:
+        interpret = not _compiled_backend()
+    return _flash_packed(qkv, heads, scale, causal, plan.block_q,
+                         plan.block_k, interpret)

@@ -42,7 +42,13 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.monitor.trace import span, span_function
-from apex_tpu.ops.attention import flash_attention
+from apex_tpu.ops._pallas_util import compiled_backend
+from apex_tpu.ops.attention import (
+    flash_attention,
+    flash_attention_packed,
+    packed_plan,
+    unpack_qkv,
+)
 from apex_tpu.ops.layer_norm import layer_norm
 from apex_tpu.parallel.mesh import SP_AXIS, TP_AXIS
 from apex_tpu.transformer.pipeline_parallel.schedules import PipelineSpec
@@ -339,13 +345,19 @@ def _attention(p, x, cfg, heads_local: int, causal: bool = True, mask=None,
         # heads, head_dim) order would make a tp split hand rank 0 "q of
         # heads 0..H/2 but k of heads H/2..H", silently mixing regions
         # across degrees.
-        qkv = qkv.reshape(b, s, heads_local, 3, cfg.head_dim)
-        q, k, v = (qkv[:, :, :, i].transpose(0, 2, 1, 3) for i in range(3))
+        packed = _core_takes_packed(cfg, qkv, heads_local, causal, mask,
+                                    dropout_key)
+        if not packed:
+            q, k, v = unpack_qkv(qkv, heads_local)
     with span("attn/core"):
-        ctx = _attention_core(q, k, v, cfg, causal, mask, dropout_key)
+        if packed:
+            ctx = flash_attention_packed(qkv, heads_local, causal=causal)
+        else:
+            ctx = _attention_core(q, k, v, cfg, causal, mask, dropout_key)
     with span("attn/out"):
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(
-            b, s, heads_local * cfg.head_dim)
+        if not packed:
+            ctx = ctx.transpose(0, 2, 1, 3).reshape(
+                b, s, heads_local * cfg.head_dim)
         # (the dots_attn remat names live INSIDE the flash custom_vjp
         # forward — ops/attention.py tags o and lse, the exact backward
         # residuals; tagging here would save the output without lse and
@@ -356,13 +368,35 @@ def _attention(p, x, cfg, heads_local: int, causal: bool = True, mask=None,
                                    overlap_comm=cfg.overlap_comm)
 
 
+def _sp_size() -> int:
+    try:
+        return lax.axis_size(SP_AXIS)
+    except NameError:
+        return 1
+
+
+def _core_takes_packed(cfg, qkv, heads_local, causal, mask, dropout_key):
+    """Whether the attention core reads the QKV product as it stands and
+    writes o as the output projection reads it
+    (``ops.attention.flash_attention_packed``: head size 64, where
+    (b, heads, s, head_dim) fills half of every 128-lane tile and cost 17
+    layout copies a layer). Decided from what the call can observe: the
+    sequence whole on this device, no dense mask, no attention dropout, a
+    shape the packed kernels hold (``packed_plan``) and a backend that runs
+    kernels; any other call unpacks to (b, heads, s, head_dim) as ever."""
+    if mask is not None or _sp_size() > 1:
+        return False
+    if dropout_key is not None and cfg.attention_dropout > 0.0:
+        return False
+    return compiled_backend() and packed_plan(
+        qkv.shape[1], heads_local, cfg.head_dim, qkv.dtype,
+        causal) is not None
+
+
 def _attention_core(q, k, v, cfg, causal, mask, dropout_key):
     """The attention core on (b, heads, s, head_dim): the flash kernel, or
     the K/V ring where the sequence is sharded over sp."""
-    try:
-        sp = lax.axis_size(SP_AXIS)
-    except NameError:
-        sp = 1
+    sp = _sp_size()
     rate = cfg.attention_dropout if dropout_key is not None else 0.0
     if sp > 1:
         # sequence sharded over sp: exact attention via the K/V ring
@@ -454,11 +488,7 @@ def _hidden_key(key, cfg):
     sharding layouts."""
     if key is None:
         return key
-    try:
-        sp = lax.axis_size(SP_AXIS)
-    except NameError:
-        sp = 1
-    if sp > 1:
+    if _sp_size() > 1:
         key = jax.random.fold_in(key, lax.axis_index(SP_AXIS))
     if not cfg.megatron_sp:
         return key

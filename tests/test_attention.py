@@ -492,3 +492,146 @@ def test_keys_of_another_width_than_queries_are_refused():
     q, k, v = _qkv_widths(jax.random.PRNGKey(7), 1, 2, 64, 32, 32)
     with pytest.raises(ValueError, match="one width"):
         flash_attention(q, k[..., :16], v)
+
+
+# -- the packed entry: head size 64, half of the 128 lanes (ISSUE 36) --------------
+
+def _packed_pair(heads, seq, causal, d=64):
+    """(packed loss, unpacked loss) over one QKV product (1, seq, heads x 3
+    x d) in bfloat16: the packed kernels, and ``flash_attention`` on the
+    transposed operands with its result turned back."""
+    from apex_tpu.ops.attention import flash_attention_packed, unpack_qkv
+
+    qkv = jax.random.normal(jax.random.PRNGKey(heads + seq),
+                            (1, seq, heads * 3 * d), jnp.bfloat16)
+    w = jax.random.normal(jax.random.PRNGKey(7), (1, seq, heads * d))
+
+    def packed(qkv):
+        o = flash_attention_packed(qkv, heads, causal=causal, use_pallas=True,
+                                   interpret=True)
+        return jnp.sum(o.astype(jnp.float32) * w), o
+
+    def unpacked(qkv):
+        o = flash_attention(*unpack_qkv(qkv, heads), causal=causal,
+                            use_pallas=True, interpret=True)
+        o = o.transpose(0, 2, 1, 3).reshape(1, seq, heads * d)
+        return jnp.sum(o.astype(jnp.float32) * w), o
+
+    return qkv, packed, unpacked
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("seq", [512, 1024])
+@pytest.mark.parametrize("heads", [16, 20])
+def test_packed_entry_equals_flash_attention_bit_for_bit(heads, seq, causal):
+    """The packed kernels run the resident schedule's tile bodies on the
+    product's 128-lane blocks: o and the gradient of the product (dq, dk and
+    dv side by side a head) equal ``flash_attention``'s on the transposed
+    operands to the bit, at the GPT-2 cells' head counts and head size."""
+    from apex_tpu.ops.attention import packed_plan
+
+    assert packed_plan(seq, heads, 64, jnp.bfloat16, causal) is not None
+    qkv, packed, unpacked = _packed_pair(heads, seq, causal)
+    got_g, got_o = jax.grad(packed, has_aux=True)(qkv)
+    want_g, want_o = jax.grad(unpacked, has_aux=True)(qkv)
+    assert got_o.dtype == want_o.dtype == got_g.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(got_o, np.float32),
+                                  np.asarray(want_o, np.float32))
+    np.testing.assert_array_equal(np.asarray(got_g, np.float32),
+                                  np.asarray(want_g, np.float32))
+
+
+@pytest.mark.parametrize("heads,seq,d,causal,why", [
+    (4, 256, 128, True, "a head fills the lanes"),
+    (3, 256, 64, True, "half a pair of heads"),
+    (4, 2048, 64, True, "ten causal tiles stream"),
+    (4, 256, 32, True, "a quarter of the lanes: a rotation by head"),
+])
+def test_packed_plan_refuses_what_the_packed_kernels_do_not_hold(
+        heads, seq, d, causal, why):
+    """No plan, so the entry goes through ``flash_attention``'s layout (and
+    says so when the kernels are forced)."""
+    from apex_tpu.ops.attention import flash_attention_packed, packed_plan
+
+    assert packed_plan(seq, heads, d, jnp.bfloat16, causal) is None, why
+    qkv = jnp.zeros((1, seq, heads * 3 * d), jnp.bfloat16)
+    with pytest.raises(ValueError, match="packed flash kernels need"):
+        flash_attention_packed(qkv, heads, causal=causal, use_pallas=True)
+    if seq <= 256:
+        out = flash_attention_packed(qkv, heads, causal=causal)
+        assert out.shape == (1, seq, heads * d)
+
+
+def _attention_lowered(heads, seq, head_dim, mask=None, sp=1, dropout=0.0):
+    """``standalone_gpt._attention``'s forward lowered for the TPU with the
+    kernels on, as text."""
+    from jax.sharding import PartitionSpec as P
+
+    from apex_tpu.ops._pallas_util import force_compiled
+    from apex_tpu.parallel.mesh import build_mesh
+    from apex_tpu.transformer.testing import GPTConfig
+    from apex_tpu.transformer.testing.standalone_gpt import _attention
+
+    hidden = heads * head_dim
+    cfg = GPTConfig(vocab_size=128, max_seq=seq * sp, hidden=hidden,
+                    num_layers=1, num_heads=heads, dtype=jnp.bfloat16,
+                    attention_dropout=dropout)
+    p = {"qkv_kernel": jnp.zeros((hidden, 3 * hidden), jnp.bfloat16),
+         "qkv_bias": jnp.zeros((3 * hidden,), jnp.bfloat16),
+         "out_kernel": jnp.zeros((hidden, hidden), jnp.bfloat16),
+         "out_bias": jnp.zeros((hidden,), jnp.bfloat16)}
+    x = jnp.zeros((1, seq * sp, hidden), jnp.bfloat16)
+    key = jax.random.PRNGKey(0) if dropout else None
+    mesh = build_mesh(tp=1, pp=1, sp=sp, dp=1, devices=jax.devices()[:sp])
+
+    def fn(p, x):
+        body = lambda p, x: _attention(p, x, cfg, heads, True, mask,
+                                       dropout_key=key)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(P(), P(None, "sp", None)),
+                             out_specs=P(None, "sp", None),
+                             check_vma=False)(p, x)
+
+    with force_compiled():
+        return jax.jit(fn).trace(p, x).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+
+_OLD_PATH = {
+    "packed": dict(heads=4, seq=256, head_dim=64),
+    "head-128": dict(heads=2, seq=256, head_dim=128),
+    "odd-heads": dict(heads=3, seq=256, head_dim=64),
+    "mask": dict(heads=4, seq=256, head_dim=64,
+                 mask=jnp.zeros((256, 256), bool)),
+    "sp-2": dict(heads=4, seq=256, head_dim=64, sp=2),
+    "streams": dict(heads=4, seq=2048, head_dim=64),
+    "dropout": dict(heads=4, seq=256, head_dim=64, dropout=0.1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_OLD_PATH))
+def test_attention_takes_the_packed_entry_from_what_it_observes(case):
+    """``_attention`` hands the product to the packed kernels only where
+    every condition holds; a head of 128, an odd local head count, a dense
+    mask, the sequence sharded over ``sp``, a sequence that streams and
+    attention dropout each keep the (b, heads, s, head_dim) path: its
+    transposes are in the lowered text and the kernels (where that path
+    has them) take one head's (rows, head_dim) operands."""
+    if case == "sp-2" and len(jax.devices()) < 2:
+        pytest.skip("needs two virtual devices")
+    kw = _OLD_PATH[case]
+    text = _attention_lowered(**kw)
+    heads, seq, d = kw["heads"], kw["seq"], kw["head_dim"]
+    packed_operand = f"tensor<1x{seq}x{heads * 3 * d}xbf16>"
+    kernels = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    if case == "packed":
+        assert "stablehlo.transpose" not in text
+        assert len(kernels) == 1 and packed_operand in kernels[0], kernels
+        return
+    assert "stablehlo.transpose" in text
+    assert not any(packed_operand in line for line in kernels), kernels
+    if case in ("head-128", "odd-heads", "streams", "dropout"):
+        assert len(kernels) == 1
+        assert f"tensor<{heads}x{seq}x{d}xbf16>" in kernels[0], kernels
+    if case == "mask":
+        assert not kernels

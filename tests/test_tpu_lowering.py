@@ -200,29 +200,81 @@ def test_flash_attention_compiles_with_a_value_width_of_its_own(heads, seq, d, d
 # is in the payloads' locations and moves either by a few hundred.
 _FLASH_LOWERED_CHARS_STREAMED = 26_047
 _FLASH_LOWERED_CHARS_RESIDENT = 22_947
+# The packed entry's kernels (PR 36: the same three bodies a kernel, called
+# through refs that zero the neighbour head's half of a 128-lane block and
+# write a result over its own), the same 16 x 20 heads as one (16, 1024, 3840)
+# product.
+_FLASH_LOWERED_CHARS_PACKED = 30_842
+
+# products a tile body holds: s and p @ v; s, dp and ds @ k; s, dp, p.T @ do and ds.T @ q
+_DOTS_A_TILE = {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkv": 4}
 
 
-def test_flash_program_size_at_the_cells_shape():
+def _packed_sq_loss(qkv):
+    from apex_tpu.ops.attention import flash_attention_packed
+
+    o = flash_attention_packed(qkv, 20, causal=True, use_pallas=True, interpret=False)
+    return jnp.sum(o.astype(jnp.float32) ** 2)
+
+
+def _tile_bodies(fn, *args):
+    """{kernel name: tile bodies its program holds}, counted as the matrix
+    products in each ``pallas_call``'s own jaxpr over the products a tile."""
+    def dots(jaxpr):
+        n = 0
+        for eqn in jaxpr.eqns:
+            n += eqn.primitive.name == "dot_general"
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                n += dots(sub)
+        return n
+
+    bodies = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                name = eqn.params["name"]
+                bodies[name] = dots(eqn.params["jaxpr"]) / _DOTS_A_TILE[name]
+            else:
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+    with force_compiled():
+        walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return bodies
+
+
+@pytest.mark.parametrize("entry", ["heads-major", "packed"])
+def test_flash_program_size_at_the_cells_shape(entry):
     """Every unrolled tile body is traced, lowered, hashed into the compile
     cache's key and loaded at each warm set-up, and compiled at each cold
     one: PR 26's 20 bodies a kernel cost ``setup_s`` 12% at gpt2-medium and
     the PR with it. The program's size is countable here: the lowered text
     may not pass 1.25 times the streamed program's, so a later PR that
-    unrolls more is told before a chip is."""
-    from apex_tpu.ops.attention import _RESIDENT_MAX_BODIES, _tile_plan
+    unrolls more is told before a chip is; and no kernel holds more tile
+    bodies than ``_RESIDENT_MAX_BODIES``: the packed kernels hold a pair of
+    heads a block and still one set of bodies."""
+    from apex_tpu.ops.attention import _RESIDENT_MAX_BODIES, _tile_plan, packed_plan
 
     plan = _tile_plan(S, S, D, jnp.bfloat16, True)
     assert plan.schedule == "resident"
     assert plan.bodies == 3 <= _RESIDENT_MAX_BODIES
-    q = jax.ShapeDtypeStruct((16, 20, S, D), jnp.bfloat16)
+    if entry == "packed":
+        assert packed_plan(S, 20, D, jnp.bfloat16, True) == plan
+        loss, args = _packed_sq_loss, (jax.ShapeDtypeStruct((16, S, 20 * 3 * D), jnp.bfloat16),)
+        argnums, recorded = 0, _FLASH_LOWERED_CHARS_PACKED
+    else:
+        loss, args = _flash_sq_loss, (jax.ShapeDtypeStruct((16, 20, S, D), jnp.bfloat16),) * 3
+        argnums, recorded = (0, 1, 2), _FLASH_LOWERED_CHARS_RESIDENT
+    grad = jax.grad(jax.checkpoint(loss), argnums=argnums)
     with force_compiled():
-        text = _lower_tpu(
-            jax.grad(jax.checkpoint(_flash_sq_loss), argnums=(0, 1, 2)),
-            q, q, q).as_text()
+        text = _lower_tpu(grad, *args).as_text()
     assert text.count("tpu_custom_call") >= 3
     assert len(text) <= 1.25 * _FLASH_LOWERED_CHARS_STREAMED, (
-        len(text), _FLASH_LOWERED_CHARS_STREAMED,
-        _FLASH_LOWERED_CHARS_RESIDENT)
+        len(text), _FLASH_LOWERED_CHARS_STREAMED, recorded)
+    bodies = _tile_bodies(grad, *args)
+    assert bodies == {"flash_fwd": 3, "flash_bwd_dq": 3, "flash_bwd_dkv": 3}, bodies
+    assert max(bodies.values()) <= _RESIDENT_MAX_BODIES
 
 
 def test_varlen_fwd_bwd(qkv):
@@ -757,9 +809,9 @@ def test_engine_programs_compile_for_tpu(flagship_serve, megakernel, kernel,
 
 # -- the train step's optimizer pass (ISSUE 31) -------------------------------------
 
-def _gpt():
+def _gpt(hidden=1024, heads=16):
     from apex_tpu.transformer.testing import GPTConfig
-    return GPTConfig(vocab_size=50304, max_seq=1024, hidden=1024, num_layers=2, num_heads=16,
+    return GPTConfig(vocab_size=50304, max_seq=1024, hidden=hidden, num_layers=2, num_heads=heads,
                      dtype=jnp.bfloat16, remat=True, remat_policy="full"), 16, 1024
 
 
@@ -822,3 +874,56 @@ def test_the_compiled_train_step_moves_no_leaf_under_opt_and_writes_in_place(mod
             largest = max((int(np.prod(dims)) for _, dims in _parse_shape(types[name])),
                           default=0)
             assert largest < matrix, (name, rec["opcode"], types[name])
+
+
+# -- the GPT-2 attention sublayer's layouts (ISSUE 36) ---------------------------------
+
+def _gpt_large():
+    return _gpt(hidden=1280, heads=20)
+
+
+@pytest.mark.parametrize("model,dp", [(_gpt, 1), (_gpt, 4), (_gpt_large, 1), (_gpt_large, 4)],
+                         ids=["medium-dp1", "medium-dp4", "large-dp1", "large-dp4"])
+def test_the_compiled_gpt2_train_step_changes_no_layout_round_attention(model, dp):
+    """At both GPT-2 cells' widths (two layers: the layer body is scanned),
+    remat full: the flash kernels at head size 64 read the packed QKV product
+    as it stands and write o as the output product reads it, so under a scope
+    with ``attn/`` the compiled step holds no ``copy`` and no fusion that only
+    moves data of 1 MiB or more in the forward and the replay and at most one
+    in the backward (the parent held 17 copies and two such fusions a layer),
+    and its Mosaic calls are the ones it had: ``flash_fwd`` twice (forward and
+    replay), ``flash_bwd_dq`` and ``flash_bwd_dkv`` once."""
+    from apex_tpu.monitor.trace import split_scope
+    from apex_tpu.ops._pallas_util import compile_for_tpu, mosaic_calls, tpu_topology_devices
+    from apex_tpu.parallel.mesh import build_mesh
+    from apex_tpu.pyprof.prof import _nbytes, _parse_hlo, instruction_scopes
+    from apex_tpu.train import abstract_train_args, train_step_fn
+
+    cfg, rows, seq = model()
+    mesh = build_mesh(tp=1, pp=1, sp=1, dp=dp, devices=tpu_topology_devices()[:dp])
+    step, opt = train_step_fn(cfg, mesh)
+    _, compiled = compile_for_tpu(step, *abstract_train_args(cfg, opt, mesh, rows * dp, seq))
+    text = compiled.as_text()
+
+    calls = mosaic_calls(text)
+    assert set(calls) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "layer_norm_fwd",
+                          "layer_norm_bwd", "lm_head_fwd", "lm_head_bwd_dx", "lm_head_bwd_dw"}
+    assert (calls["flash_fwd"], calls["flash_bwd_dq"], calls["flash_bwd_dkv"]) == (2, 1, 1)
+
+    table = instruction_scopes(text)
+    types = {i.name: i.type_str for instrs in _parse_hlo(text)[0].values() for i in instrs}
+    moved = {"fwd": [], "recompute": [], "bwd": []}
+    for name, rec in table.items():
+        phase, scope = split_scope(rec["op_name"])
+        if "attn/" not in scope + "/" or phase not in moved:
+            continue
+        moves = rec["opcode"] == "copy" or (rec["opcode"] == "fusion" and rec["moves_only"])
+        if moves and _nbytes(types[name]) >= 2**20:
+            moved[phase].append((name, types[name], scope))
+    assert not moved["fwd"] and not moved["recompute"], moved
+    assert len(moved["bwd"]) <= 1, moved
+    # every flash kernel is under the scope the trace joins on
+    flash = sorted(split_scope(rec["op_name"]) for rec in table.values()
+                   if rec["opcode"] == "custom-call" and "flash_" in rec["op_name"])
+    assert flash == [("bwd", "layer/attn/core/flash_bwd_dkv"), ("bwd", "layer/attn/core/flash_bwd_dq"),
+                     ("fwd", "layer/attn/core/flash_fwd"), ("recompute", "layer/attn/core/flash_fwd")]
