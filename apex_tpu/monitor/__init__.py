@@ -19,10 +19,11 @@ the step:
 * :mod:`~apex_tpu.monitor.trace` — :func:`span` named ranges
   (``jax.named_scope`` + host ``TraceAnnotation``: one marker, visible in
   the trace viewer and in the compiled HLO's metadata), :func:`split_scope`
-  (an instruction's ``op_name`` → phase and model scope) and the program
+  (an instruction's ``op_name`` → phase and model scope), the program
   registry behind :func:`scope_table` (which scope each instruction of a
-  compiled program belongs to). Its docstring lists the scope and host
-  span names. The pipeline schedules emit ``pp_stage`` /
+  compiled program belongs to) and :func:`host_log` (host spans and JAX's
+  compile path on one clock, in memory). Its docstring lists the scope and
+  host span names. The pipeline schedules emit ``pp_stage`` /
   ``pp_ring_shift`` spans for bubble attribution.
 * :mod:`~apex_tpu.monitor.sink` — :class:`JsonlSink`, the process-0-gated,
   versioned, buffered, crash-safe JSONL writer; :func:`json_record` is the
@@ -169,7 +170,7 @@ from apex_tpu.monitor.slo import (  # noqa: F401
     SloTracker,
 )
 from apex_tpu.monitor.trace import (  # noqa: F401
-    PHASES,
+    host_log,
     register_program,
     scope_table,
     span,
@@ -207,7 +208,6 @@ __all__ = [
     "Meter",
     "Metrics",
     "MetricsRegistry",
-    "PHASES",
     "RateRule",
     "SCHEMA_VERSION",
     "SloSpec",
@@ -229,6 +229,7 @@ __all__ = [
     "hist_from_metrics",
     "hist_metric_names",
     "hlo_stats",
+    "host_log",
     "json_record",
     "load_record",
     "mfu_check",

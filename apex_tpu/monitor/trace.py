@@ -3,17 +3,19 @@
 Reference: ``apex.pyprof.nvtx`` ranges / ad-hoc ``torch.cuda.nvtx`` in hot
 paths — host-side markers a profiler joins with kernel launches.
 
-TPU design: one :func:`span` plants BOTH kinds of marker at once:
+TPU design: one :func:`span`, which plants what fits where it runs:
 
-* ``jax.named_scope`` — attaches the name to every op traced inside, so it
-  rides the compiled HLO's op metadata (``op_name``). It costs nothing at
-  run time and does not enter the compile-cache key. This is the marker
-  that survives jit.
-* ``jax.profiler.TraceAnnotation`` — a host-side range on the profiler's
-  clock, for eager and dispatch work. With no profiler session open it is
-  a disabled annotation.
+* inside jitted code, at trace time: ``jax.named_scope`` — attaches the
+  name to every op traced inside, so it rides the compiled HLO's op
+  metadata (``op_name``). It costs nothing at run time and does not enter
+  the compile-cache key. This is the marker that survives jit. (A
+  ``TraceAnnotation`` there times the tracing.)
+* on the host: ``jax.profiler.TraceAnnotation`` — a range on the
+  profiler's clock, for eager and dispatch work (a disabled annotation
+  with no profiler session open) — and a record in the host log
+  (:func:`host_log`), which is always kept.
 
-No flag, environment variable or config field switches either of them.
+No flag, environment variable or config field switches any of them.
 
 **The operator's contract: the names.** Readers (``perfbench/scopes.py``
 and its metrics, ``PERF.md`` §3 and §5) match these letter for letter.
@@ -164,14 +166,34 @@ Device scopes of the serving programs (``serve/decode.py``): ``embed``,
 attention call), ``attn/out``, ``ln2``, ``mlp/fc``, ``mlp/act``,
 ``mlp/proj``, ``residual``; then ``final_ln`` and ``lm_head``.
 
-Host spans (``TraceAnnotation``; the narrowest one that covers an idle gap
-names it): the engine's ``prefill`` (one chunk's dispatch, and the fence on
-a prompt's last chunk), ``prefill.admit`` (``_try_admit``), ``decode`` with
-``decode.dispatch`` (the call of the decode or verify program) and
-``decode.fence`` (``np.asarray(toks)``) inside it, and ``decode.retire``
-(per-slot bookkeeping to the end of ``step()``); ``comm``, ``fwd_bwd``,
-``pp_stage`` / ``pp_ring_shift``, ``ckpt``, ``transfer`` and ``scrape``
-elsewhere in the package.
+Host spans (``TraceAnnotation`` and a record; the narrowest one that
+covers an idle gap names it): the train step's ``train_step``, one a call of
+the step with the call's index from 1 as ``call`` (``apex_tpu.train``), and
+``scope_table`` (:func:`scope_table`'s own compile); the engine's
+``prefill`` (one chunk's dispatch, and the fence on a prompt's last chunk),
+``prefill.admit`` (``_try_admit``), ``decode`` with ``decode.dispatch`` (the
+call of the decode or verify program) and ``decode.fence``
+(``np.asarray(toks)``) inside it, and ``decode.retire`` (per-slot
+bookkeeping to the end of ``step()``); ``comm``, ``fwd_bwd``, ``pp_stage`` /
+``pp_ring_shift``, ``transfer`` and ``scrape`` elsewhere in the package.
+
+**The host log** (:func:`host_log`: a copy, as :class:`HostRecord` s in the
+order they closed). Its records are host spans (``kind`` ``"span"``) and
+JAX's compile path, ``kind`` ``"trace"`` (``jaxpr_trace_duration``),
+``"lower"`` (``jaxpr_to_mlir_module_duration``) and ``"compile"``
+(``backend_compile_duration``: compiled, or ``cached`` where a persistent
+cache hit fell inside), one a pass of a program (``program``: JAX's
+``fun_name`` less ``jit(...)``, so ``train_step``); a pass nested in another
+of its kind (the primitives a step's trace traces) is folded into the
+outermost one's ``count``. Each carries the innermost host span open around
+it (``parent``) and the call index of the innermost ``train_step`` open
+around it (``call``): a compilation names the step call it held up. All
+times are ``time.time()``'s, the clock JAX stamps these events with. The
+listener is installed when this module is imported (``apex_tpu.train``
+imports it when it builds the step); what runs before that is not in the
+log. Bounded: the first ``HOST_LOG_HEAD`` records (16,384: a job's set-up)
+are kept whole, then a ring of the newest ``HOST_LOG_RING`` (16,384), so a
+job of any length holds at most 32,768.
 
 **Which scope a compiled instruction belongs to.** A program registers a
 thunk under the name its module has in a device trace
@@ -183,26 +205,17 @@ asks.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import re
-from typing import Callable, Dict, Iterator, Optional, Tuple
+import threading
+import time
+from typing import Callable, Dict, Iterator, List, NamedTuple, Optional, Tuple
 
 import jax
-
-# canonical phases. "ckpt" is the host-side checkpoint phase
-# (resilience.CheckpointManager's device_get + serialization) — it appears
-# in trace-viewer host rows, not in the compiled step. "prefill"/"decode"
-# are the serving phases the apex_tpu.serve engine traces its two jitted
-# programs under; "transfer" is the disaggregated cluster's KV-block
-# handoff between hosts (serve.cluster — pack/ship/unpack around the
-# SimTransport or ICI hop). "scrape" is the fleet-observability tier's
-# host-side phase: the FleetScraper pulling worker snapshots on the
-# cluster clock (its cost is itself measured: scrape_ms).
-# "recompute" is the forward replayed in the backward
-# pass (split_scope tells it from "fwd" and "bwd").
-PHASES = ("fwd", "recompute", "bwd", "comm", "opt", "ckpt", "prefill",
-          "decode", "transfer", "scrape")
+from jax._src import core as _core
+from jax._src import monitoring as _monitoring
 
 # what a routed layer counts a step (the contract above)
 ROUTING_COUNTERS = ("pairs_held", "pairs_uniform", "max_load_over_mean",
@@ -214,12 +227,23 @@ ROUTING_COUNTERS_MORE = ("rows_gathered", "rows_gathered_over_held",
 
 
 @contextlib.contextmanager
-def span(name: str) -> Iterator[None]:
-    """Named range: in-graph (``named_scope`` → HLO op metadata → the scope
-    table) and host-side (``TraceAnnotation`` → trace-viewer host row).
-    Nesting composes into ``outer/inner`` scope paths."""
-    with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
-        yield
+def span(name: str, *, call: Optional[int] = None) -> Iterator[None]:
+    """Named range. Inside jitted code, at trace time: ``named_scope`` (→ HLO
+    op metadata → the scope table; nesting composes into ``outer/inner``)
+    and a ``TraceAnnotation``. On the host: a ``TraceAnnotation`` (the
+    trace viewer's host row, with ``call`` as its argument where given) and
+    a record in the host log (:func:`host_log`)."""
+    if not _core.trace_state_clean():
+        with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
+            yield
+        return
+    note = {} if call is None else {"call": call}
+    with jax.profiler.TraceAnnotation(name, **note):
+        start = _LOG.opened(name, call)
+        try:
+            yield
+        finally:
+            _LOG.closed(name, call, start)
 
 
 def span_function(fn: Callable = None, *, name: Optional[str] = None):
@@ -324,7 +348,153 @@ def scope_table(name: str, **shape) -> Optional[Dict[str, Dict]]:
     before = getattr(jax.config, flag)
     jax.config.update(flag, True)
     try:
-        compiled = lower(**shape).compile()
+        with span("scope_table"):
+            compiled = lower(**shape).compile()
     finally:
         jax.config.update(flag, before)
     return instruction_scopes(compiled.as_text())
+
+
+# -- the host log ------------------------------------------------------------------
+
+HOST_LOG_HEAD = 16384       # the first records, kept whole: a job's set-up
+HOST_LOG_RING = 16384       # then the newest ones
+
+# JAX's compile path: one event a pass, timed with ``time.time()``
+_COMPILE_PATH = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_WRAPPED = re.compile(r"^(?:jit|pjit|pmap|xla_pmap)\((.*)\)$")
+
+
+class HostRecord(NamedTuple):
+    name: str                   # the span's name, or JAX's ``fun_name``
+    kind: str                   # "span", "trace", "lower" or "compile"
+    start: float                # seconds on ``time.time()``'s clock
+    end: float
+    parent: Optional[str]       # the innermost host span open around it
+    program: Optional[str]      # compile path: ``fun_name`` less ``jit(...)``
+    call: Optional[int]         # the dispatch's call index, its own or the
+                                # innermost one open around it
+    count: int = 1              # passes of this kind folded in: itself and
+                                # those nested inside it
+    cached: Optional[bool] = None   # compile: read from the persistent cache
+
+
+class _Thread(threading.local):
+    def __init__(self):
+        self.open: List[Tuple[str, Optional[int]]] = []    # host spans, innermost last
+        self.depth = dict.fromkeys(_COMPILE_PATH.values(), 0)
+        self.folded = dict.fromkeys(_COMPILE_PATH.values(), 0)
+        self.hit = False
+
+
+class HostLog:
+    """The records of one process: the first ``head`` whole, then a ring of
+    the newest ``ring``. A pass of the compile path nested in another of its
+    kind (the primitives a step's trace traces) is folded into the
+    outermost one's ``count``."""
+
+    def __init__(self, head: int = HOST_LOG_HEAD, ring: int = HOST_LOG_RING):
+        self._head_size = head
+        self._head: List[HostRecord] = []
+        self._ring = collections.deque(maxlen=ring)
+        self._lock = threading.Lock()
+        self._thread = _Thread()
+
+    def records(self) -> List[HostRecord]:
+        with self._lock:
+            return self._head + list(self._ring)
+
+    def _add(self, rec: HostRecord) -> None:
+        with self._lock:
+            if len(self._head) < self._head_size:
+                self._head.append(rec)
+            else:
+                self._ring.append(rec)
+
+    def _around(self) -> Tuple[Optional[str], Optional[int]]:
+        """(innermost open span, innermost call index open) on this thread."""
+        spans = self._thread.open
+        call = next((c for _, c in reversed(spans) if c is not None), None)
+        return (spans[-1][0] if spans else None), call
+
+    def opened(self, name: str, call: Optional[int]) -> float:
+        self._thread.open.append((name, call))
+        return time.time()
+
+    def closed(self, name: str, call: Optional[int], start: float) -> None:
+        end = time.time()
+        self._thread.open.pop()
+        parent, outer = self._around()
+        self._add(HostRecord(name, "span", start, end, parent, None,
+                             outer if call is None else call))
+
+    def entered(self, kind: str) -> None:
+        t = self._thread
+        t.depth[kind] += 1
+        if kind == "compile" and t.depth[kind] == 1:
+            t.hit = False
+
+    def left(self, kind: str, fun_name: str, start: float, end: float) -> None:
+        t = self._thread
+        t.depth[kind] = max(t.depth[kind] - 1, 0)
+        if t.depth[kind]:
+            t.folded[kind] += 1
+            return
+        count, t.folded[kind] = 1 + t.folded[kind], 0
+        m = _WRAPPED.match(fun_name)
+        parent, call = self._around()
+        self._add(HostRecord(fun_name, kind, start, end, parent,
+                             m.group(1) if m else fun_name, call, count,
+                             t.hit if kind == "compile" else None))
+
+    def cache_hit(self) -> None:
+        self._thread.hit = True
+
+
+_LOG = HostLog()
+
+
+def host_log() -> List[HostRecord]:
+    """A copy of this process's host log, in the order records closed."""
+    return _LOG.records()
+
+
+def _on_start(event: str, _value, **_kw) -> None:
+    kind = _COMPILE_PATH.get(event)
+    if kind:
+        _LOG.entered(kind)
+
+
+def _on_time_span(event: str, start: float, end: float, fun_name: str = "",
+                  **_kw) -> None:
+    kind = _COMPILE_PATH.get(event)
+    if kind:
+        _LOG.left(kind, fun_name, start, end)
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == _CACHE_HIT:
+        _LOG.cache_hit()
+
+
+def _install() -> None:
+    """Subscribe the log to JAX's compile path; a second call adds nothing.
+    A pass announces its start as a scalar (its ``time.time()``) and its end
+    as a time span."""
+    for listeners, register, fn in (
+            (_monitoring.get_scalar_listeners, _monitoring.register_scalar_listener,
+             _on_start),
+            (_monitoring.get_event_time_span_listeners,
+             _monitoring.register_event_time_span_listener, _on_time_span),
+            (_monitoring.get_event_listeners, _monitoring.register_event_listener,
+             _on_event)):
+        if fn not in listeners():
+            register(fn)
+
+
+_install()

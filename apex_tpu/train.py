@@ -17,6 +17,10 @@ balance loss) has a fourth, ``loss_and_counters(params, tokens, targets)``:
 the loss and a pytree of arrays whose leading axis stacks over ``dp`` (the
 block-diffusion and the latent-attention decoders have). Its step returns
 them fourth, from the step itself and at no pass of their own.
+
+Each call of the step is a host span ``train_step`` carrying its call index
+(``monitor.trace.host_log``), so that a compilation is a record that names
+the call it held up.
 """
 
 from __future__ import annotations
@@ -29,9 +33,32 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 
+class _Dispatched:
+    """A jitted step whose every call is a host span named after it, with
+    the call's index from 1; ``lower``, ``trace`` and the rest are the jitted
+    function's own."""
+
+    def __init__(self, jitted, name: str):
+        from apex_tpu.monitor.trace import span
+
+        self._jitted = jitted
+        self._name = name
+        self._span = span
+        self.calls = 0
+
+    def __call__(self, *args):
+        self.calls += 1
+        with self._span(self._name, call=self.calls):
+            return self._jitted(*args)
+
+    def __getattr__(self, attr):
+        return getattr(self._jitted, attr)
+
+
 def train_step_fn(model, mesh):
     """The jitted fwd+bwd+FusedAdam step of ``model`` over ``mesh`` (params
-    and optimizer state donated), plus the optimizer it steps."""
+    and optimizer state donated), each call a host span ``train_step``
+    (:class:`_Dispatched`), plus the optimizer it steps."""
     from apex_tpu.monitor.trace import register_program, span
     from apex_tpu.optimizers import FusedAdam
     from apex_tpu.transformer.pipeline_parallel.schedules.common import (
@@ -88,7 +115,7 @@ def train_step_fn(model, mesh):
             *abstract_train_args(model, opt, mesh, rows, seq))
 
     register_program("jit_train_step", lower)
-    return train_step, opt
+    return _Dispatched(train_step, "train_step"), opt
 
 
 def abstract_train_args(model, opt, mesh, rows: int, seq: int):
