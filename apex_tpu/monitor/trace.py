@@ -89,9 +89,11 @@ block-diffusion mask, routed experts; the same step, so ``opt`` and
                                 (backward: the rows' cotangents summed onto
                                 the positions, a gathered row a pair held)
     layer/moe/experts           the held experts' grouped products and the
-                                gate between them (XLA renames the products
-                                ``ragged-dot-*`` and drops their scope: a
-                                reader adds them here by name)
+                                gate between them; the kernels below it as
+                                ``.../grouped_fwd``, ``.../grouped_dx``,
+                                ``.../grouped_dw`` (where no kernel runs,
+                                XLA's ``ragged-dot-*`` carries no scope: a
+                                reader adds it here by name)
     layer/moe/combine           the results weighted and added back, a
                                 gathered row a pair held
     layer/residual              the two residual adds

@@ -58,6 +58,7 @@ from jax import lax
 
 from apex_tpu.monitor.trace import span
 from apex_tpu.ops._pallas_util import pvary_like
+from apex_tpu.ops.grouped_matmul import grouped_matmul
 from apex_tpu.parallel.mesh import DP_AXIS, TP_AXIS
 from apex_tpu.transformer.tensor_parallel.mappings import (
     copy_to_tensor_model_parallel_region,
@@ -311,13 +312,14 @@ class RoutedExpertsConfig:
 
     The (position, expert) pairs that land on a held expert are laid out by
     expert in a buffer of ``rows_per_pass`` rows, **each expert's rows
-    starting on a multiple of ``tile_rows``** (the grouped product's row
-    tile on the chip). The buffer is ``BUFFER_OVER_MEAN`` times what a
-    uniform router would send, so the products and the elementwise passes
-    follow the pairs held and not the worst case, and so does every gather:
-    the rows into the buffer and their cotangent out of it by the buffer's
-    rows, the sums back onto the positions (the combine, the dispatch's
-    backward) by the places each position holds in the pass
+    starting on a multiple of ``tile_rows``** (the grouped kernels' row tile,
+    ``ops.grouped_matmul``: no tile holds two experts). The buffer is
+    ``BUFFER_OVER_MEAN`` times what a uniform router would send, so the
+    products and the elementwise passes follow the pairs held and not the
+    worst case, and so does every gather: the rows into the buffer and their
+    cotangent out of it by the buffer's rows, the sums back onto the
+    positions (the combine, the dispatch's backward) by the places each
+    position holds in the pass
     (:func:`_sum_rows`). Pairs beyond the buffer (a router far from uniform)
     are run by further passes over it, each skipped by a conditional while
     there is nothing left: exact at any imbalance."""
@@ -528,16 +530,6 @@ def _from_rows_bwd(res, dy):
 _from_rows.defvjp(_from_rows_fwd, _from_rows_bwd)
 
 
-def grouped_matmul(xs, w, sizes):
-    """Rows of ``xs`` (n, a) in groups of ``sizes`` (one an expert, in
-    order, from the first row on), each times its group's ``w[g]`` (a, b):
-    ``lax.ragged_dot``, which XLA runs on the chip as a grouped product of
-    its own that visits the row tiles the groups fill and no others (its
-    rewrite names the instruction ``ragged-dot-*``). Rows past the groups'
-    end are not read and not relied on."""
-    return lax.ragged_dot(xs, w, sizes)
-
-
 def _layout(key, count: int, tile: int):
     """Where the (position, place) pairs lie. ``key`` (pairs,) is each
     pair's held expert, ``count`` for an expert not held. Returns ``(order,
@@ -633,11 +625,11 @@ def routed_experts_mlp(p, x, cfg: RoutedExpertsConfig,
             here = rank - lo
             xs = _to_rows(xf, pair, here)
         with span("moe/experts"):
-            gate = grouped_matmul(xs, p["w_gate"], rows)
-            up = grouped_matmul(xs, p["w_up"], rows)
+            gate = grouped_matmul(xs, p["w_gate"], rows, tile)
+            up = grouped_matmul(xs, p["w_up"], rows, tile)
             act = (jax.nn.silu(gate.astype(F32)) * up.astype(F32)
                    ).astype(x.dtype)
-            ys = grouped_matmul(act, p["w_down"], rows)
+            ys = grouped_matmul(act, p["w_down"], rows, tile)
         with span("moe/combine"):
             return _from_rows(ys, weight, pair, here)
 

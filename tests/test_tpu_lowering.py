@@ -927,3 +927,41 @@ def test_the_compiled_gpt2_train_step_changes_no_layout_round_attention(model, d
                    if rec["opcode"] == "custom-call" and "flash_" in rec["op_name"])
     assert flash == [("bwd", "layer/attn/core/flash_bwd_dkv"), ("bwd", "layer/attn/core/flash_bwd_dq"),
                      ("fwd", "layer/attn/core/flash_fwd"), ("recompute", "layer/attn/core/flash_fwd")]
+
+
+# -- the routed experts' grouped products ------------------------------------------
+
+def _dsv2():
+    from apex_tpu.transformer.deepseek import DeepSeekConfig
+    return DeepSeekConfig(vocab_held=1024, hidden=512, num_layers=2, num_heads=4, dense_hidden=1408,
+                          num_experts=16, experts_held=(0, 4), shared_hidden=1408), 1, 2048
+
+
+@pytest.mark.parametrize("model", [_sdar, _dsv2], ids=["block-diffusion", "latent-attention"])
+def test_the_routed_layers_grouped_products_are_the_programs_kernels(model):
+    """At the cells' expert widths (depth, hidden and experts cut): the
+    compiled step holds no XLA ``ragged-dot`` and runs the experts' products
+    as ``grouped_fwd`` (forward and replay), ``grouped_dx`` and ``grouped_dw``
+    (backward), the first pass's under ``layer/moe/experts``, the scope the
+    trace joins on (a later pass, run only where the first pass's buffer
+    overflows, sits inside ``layer/moe/combine``'s conditional)."""
+    from apex_tpu.monitor.trace import split_scope
+    from apex_tpu.ops._pallas_util import compile_for_tpu, tpu_topology_devices
+    from apex_tpu.parallel.mesh import build_mesh
+    from apex_tpu.pyprof.prof import instruction_scopes
+    from apex_tpu.train import abstract_train_args, train_step_fn
+
+    cfg, rows, seq = model()
+    mesh = build_mesh(tp=1, pp=1, sp=1, dp=1, devices=tpu_topology_devices()[:1])
+    step, opt = train_step_fn(cfg, mesh)
+    _, compiled = compile_for_tpu(step, *abstract_train_args(cfg, opt, mesh, rows, seq))
+    text = compiled.as_text()
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+
+    grouped = {split_scope(rec["op_name"]) for rec in instruction_scopes(text).values()
+               if rec["opcode"] == "custom-call" and "grouped_" in rec["op_name"]}
+    experts = "layer/moe/experts/"
+    assert all(scope.split("/")[-3:-1] == ["moe", "experts"] for _, scope in grouped), grouped
+    grouped = {(phase, scope) for phase, scope in grouped if scope.startswith(experts)}
+    assert grouped == {("fwd", experts + "grouped_fwd"), ("recompute", experts + "grouped_fwd"),
+                       ("bwd", experts + "grouped_dx"), ("bwd", experts + "grouped_dw")}, grouped
