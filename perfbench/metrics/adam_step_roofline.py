@@ -9,6 +9,7 @@ err low; above 100, time under ``opt`` went missing."""
 import counts
 import counts_hybrid
 import scopes
+import scopes_hybrid
 
 SCOPE = "opt"
 BYTES_PER_PARAM = 2 + 4 + 4 + 2 + 4 + 4 + 2     # read g, m, v, p; write m, v, p
@@ -24,7 +25,6 @@ def read(facts, trace):
                   if scope == SCOPE or scope.startswith(SCOPE + "/"))
     if seconds <= 0:
         return None
-    model = facts["model"]
-    family = counts_hybrid if "layer_types" in model else counts
-    least = family.n_params(model) * BYTES_PER_PARAM / facts["peaks"].hbm_bytes_per_s
+    family = counts_hybrid if scopes_hybrid.is_hybrid(facts) else counts
+    least = family.n_params(facts["model"]) * BYTES_PER_PARAM / facts["peaks"].hbm_bytes_per_s
     return 100.0 * least / seconds

@@ -8,6 +8,7 @@ fusions, read alike; the core replayed under remat costs time and earns no
 credit)."""
 import counts_hybrid
 import scopes
+import scopes_hybrid
 
 SCOPE = "layer/linattn/core"
 
@@ -15,7 +16,7 @@ SCOPE = "layer/linattn/core"
 def read(facts, trace):
     if facts.get("peaks") is None:     # no chip, no share of a peak
         return None
-    if "layer_types" not in facts.get("model", {}):
+    if not scopes_hybrid.is_hybrid(facts):
         return None
     got = scopes.train_step_scopes(facts, trace)
     if got is None:

@@ -6,6 +6,7 @@ the summed device time of ``flash_fwd``, ``flash_bwd_dq`` and
 ``flash_bwd_dkv`` a step (the forward replayed under remat costs time and
 earns no credit)."""
 import counts_hybrid
+import scopes_hybrid
 import xplane
 
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
@@ -14,7 +15,7 @@ KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 def read(facts, trace):
     if facts.get("peaks") is None:     # no chip, no share of a peak
         return None
-    if trace is None or facts.get("kind") != "train" or "layer_types" not in facts["model"]:
+    if trace is None or not scopes_hybrid.is_hybrid(facts):
         return None
     seconds = xplane.kernel_seconds_per_run(trace, "jit_train_step", KERNELS)
     if not seconds:

@@ -5,13 +5,14 @@ x ``counts_hybrid.train_flops_per_token`` (6 a weight in a multiply-add, 6·h·s
 a full layer, the delta rule in recurrent form) over the peak. Recomputation
 is not credited."""
 import counts_hybrid
+import scopes_hybrid
 import xplane
 
 
 def read(facts, trace):
     if facts.get("peaks") is None:     # no chip, no share of a peak
         return None
-    if trace is None or facts.get("kind") != "train" or "layer_types" not in facts["model"]:
+    if trace is None or not scopes_hybrid.is_hybrid(facts):
         return None
     shares = []
     for dev in trace.devices.values():

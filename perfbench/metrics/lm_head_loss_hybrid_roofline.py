@@ -4,6 +4,7 @@ decoder's train step, over the rows of the vocabulary held. Compute-bound:
 the bf16 peak, divided by the summed device time of ``lm_head_fwd``,
 ``lm_head_bwd_dx`` and ``lm_head_bwd_dw`` a step."""
 import counts_hybrid
+import scopes_hybrid
 import xplane
 
 KERNELS = ("lm_head_fwd", "lm_head_bwd_dx", "lm_head_bwd_dw")
@@ -12,7 +13,7 @@ KERNELS = ("lm_head_fwd", "lm_head_bwd_dx", "lm_head_bwd_dw")
 def read(facts, trace):
     if facts.get("peaks") is None:     # no chip, no share of a peak
         return None
-    if trace is None or facts.get("kind") != "train" or "layer_types" not in facts["model"]:
+    if trace is None or not scopes_hybrid.is_hybrid(facts):
         return None
     seconds = xplane.kernel_seconds_per_run(trace, "jit_train_step", KERNELS)
     if not seconds:

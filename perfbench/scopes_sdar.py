@@ -1,5 +1,6 @@
 """What the block-diffusion cell's readers share: whether a run is of that
-family, its counters, and the device time of the routed layer's parts.
+family (by the configuration's ``model_type``, which no other family states),
+its counters, and the device time of the routed layer's parts.
 
 XLA rewrites ``lax.ragged_dot`` on the chip into a grouped product of its own
 and names the instruction ``ragged-dot-*`` with no scope (``op_name`` is the
@@ -20,10 +21,11 @@ import xplane
 MOE = "layer/moe"
 PARTS = ("route", "dispatch", "experts", "combine")
 GROUPED = "ragged-dot"
+MODEL_TYPE = "sdar_moe"
 
 
 def is_sdar(facts: Dict) -> bool:
-    return facts.get("kind") == "train" and "experts_held" in facts.get("model", {})
+    return facts.get("kind") == "train" and facts.get("model", {}).get("model_type") == MODEL_TYPE
 
 
 def pairs_held(facts: Dict) -> Optional[float]:

@@ -273,13 +273,35 @@ def test_the_tiles_the_roofline_credits_are_the_tiles_the_plan_visits(real):
         1.031, abs=1e-3)
 
 
+def check_benchmark(bench):
+    """This family's entries in a ``BENCHMARK.json``, found by name: a later
+    PR appends its own configuration, cell and metrics, and adds its cell to
+    the shared metrics' lists, and these checks still hold."""
+    row = _load(_paths.DATA, "catalog_row.deepseek-v2-lite.json")
+    entry = next(c for c in bench["configs"] if c["name"] == REAL)
+    assert entry["source"] == row["source_url"]
+    assert entry["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
+    cell = next(w for w in bench["workloads"] if w["name"] == REAL_CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (REAL, "train-s16k", 1)
+    assert len(cell["why"]) <= 200
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    for name in NEW_METRICS:
+        assert by_name[name]["workloads"] == [REAL_CELL] and by_name[name]["moves"] == "train_tokens_per_s"
+        assert os.path.exists(os.path.join(_paths.PERFBENCH, "metrics", name + ".py"))
+    for name in SHARED_METRICS:
+        assert REAL_CELL in by_name[name]["workloads"]
+    tokens = next(m for m in bench["end_to_end"] if m["name"] == "train_tokens_per_s")
+    assert REAL_CELL in tokens["workloads"]
+    # a quarter of the cells, rounded down, may take four chips, and one always may
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) <= max(1, len(bench["workloads"]) // 4)
+
+
 def test_the_configuration_holds_every_catalog_key_but_the_reduced_ones(real):
     row = _load(_paths.DATA, "catalog_row.deepseek-v2-lite.json")
     bench = _load(_paths.ROOT, "BENCHMARK.json")
+    check_benchmark(bench)
     entry = next(c for c in bench["configs"] if c["name"] == REAL)
-    assert entry["source"] == real["source"] == row["source_url"]
-    assert entry["reduced"] == real["reduced"] == ["num_hidden_layers", "n_routed_experts",
-                                                   "vocab_size"]
+    assert (real["source"], real["reduced"]) == (entry["source"], entry["reduced"])
     for key, value in row["config"].items():
         if key in real["reduced"]:
             assert real["reduced_from"][key] == value and real[key] < value
@@ -293,23 +315,10 @@ def test_the_configuration_holds_every_catalog_key_but_the_reduced_ones(real):
     assert real["assumed"]["aux_loss_alpha"] == 0.001 and real["assumed"]["sequence"] == 16_384
     for key in ("reduced_why", "deployment"):
         assert key in real
-    cell = next(w for w in bench["workloads"] if w["name"] == REAL_CELL)
-    assert (cell["config"], cell["traffic"], cell["chips"]) == (REAL, "train-s16k", 1)
-    assert len(cell["why"]) <= 200 and bench["workloads"][-1] is cell
     mix = _load(_paths.PERFBENCH, "traffic", "train-s16k.json")
     assert (mix["kind"], mix["rows_per_chip"], mix["seq"], mix["in_flight_steps"]) == (
         "train_dsv2", 1, 16_384, 8)
     assert (mix["mesh"], mix["check"]["steps"]) == ({"dp": 1, "tp": 1}, 3)
-    by_name = {m["name"]: m for m in bench["per_layer"]}
-    for name in NEW_METRICS:
-        assert by_name[name]["workloads"] == [REAL_CELL] and by_name[name]["moves"] == "train_tokens_per_s"
-        assert os.path.exists(os.path.join(_paths.PERFBENCH, "metrics", name + ".py"))
-    assert [m["name"] for m in bench["per_layer"]][-8:] == list(NEW_METRICS)
-    for name in SHARED_METRICS:
-        assert by_name[name]["workloads"][-1] == REAL_CELL
-    tokens = next(m for m in bench["end_to_end"] if m["name"] == "train_tokens_per_s")
-    assert tokens["workloads"][-1] == REAL_CELL and len(bench["workloads"]) == 6
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
 
 
 def test_the_kind_builds_the_model_the_configuration_publishes(real, kind):

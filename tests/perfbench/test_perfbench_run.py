@@ -33,23 +33,32 @@ def test_run_py_refuses_to_print_a_result_without_a_tpu(tmp_path):
         assert "correct" not in json.loads(line)               # facts only, no result
 
 
-def test_benchmark_json_names_files_that_exist():
-    with open(os.path.join(_paths.ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+def check_names_files_that_exist(bench, roots=(_paths.ROOT,)):
+    """Every file ``bench`` names exists under one of ``roots``, each laid
+    out as a checkout (``perfbench/traffic``, ``perfbench/limits``,
+    ``perfbench/metrics``), and every cell of a per-layer metric reports the
+    end-to-end metric it moves."""
+    def found(*parts):
+        return any(os.path.isfile(os.path.join(root, *parts)) for root in roots)
+
     for c in bench["configs"]:
-        assert os.path.isfile(os.path.join(_paths.ROOT, c["file"]))
+        assert found(c["file"]), c["file"]
     for w in bench["workloads"]:
-        assert os.path.isfile(os.path.join(_paths.PERFBENCH, "traffic", w["traffic"] + ".json"))
-        assert os.path.isfile(os.path.join(_paths.PERFBENCH, "limits", w["name"] + ".json"))
+        assert found("perfbench", "traffic", w["traffic"] + ".json"), w["traffic"]
+        assert found("perfbench", "limits", w["name"] + ".json"), w["name"]
         assert len(w["why"]) <= 200
     cells = {w["name"] for w in bench["workloads"]}
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     for m in bench["per_layer"]:
-        assert os.path.isfile(os.path.join(_paths.PERFBENCH, "metrics", m["name"] + ".py"))
-        # every cell of a per-layer metric reports the end-to-end metric it moves
+        assert found("perfbench", "metrics", m["name"] + ".py"), m["name"]
         moved = e2e[m["moves"]]
         for w in m["workloads"]:
             assert w in cells and w in moved.get("workloads", cells)
+
+
+def test_benchmark_json_names_files_that_exist():
+    with open(os.path.join(_paths.ROOT, "BENCHMARK.json")) as f:
+        check_names_files_that_exist(json.load(f))
 
 
 @pytest.fixture(scope="module")

@@ -1,5 +1,6 @@
-"""The five set-up readers (``perfbench/metrics/setup_*.py``) on a hand-made
-host log, and on the toy train cell's own log run on the CPU."""
+"""The five set-up readers (``perfbench/metrics/setup_*.py``): their entries
+in ``BENCHMARK.json``, their readings on a hand-made host log, and on the toy
+train cell's own log run on the CPU."""
 import json
 import os
 
@@ -13,7 +14,31 @@ from apex_tpu.monitor.trace import HostLog, HostRecord
 
 READERS = ("setup_compile_s", "setup_lower_s", "setup_steps_s", "setup_step_compiles",
            "setup_cache_misses")
+UNITS = ("s", "s", "s", "count", "count")
+CELLS = ("gpt2-large.train-b16", "gpt2-medium.train-b16", "gpt2-large.train-dp4",
+         "olmo-hybrid-7b.train-s8k", "sdar-30b-a3b.train-blockdiff-s8k",
+         "deepseek-v2-lite.train-s16k")
 FACTS = {"model": {"name": "toy"}}
+
+
+def check_benchmark(bench):
+    """The five readers are per-layer metrics of the set-up in each of the
+    six cells, found by name: a later PR appends its own cell to their lists,
+    and these checks still hold."""
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    for name, unit in zip(READERS, UNITS):
+        m = by_name[name]
+        assert (m["unit"], m["better"], m["source"]) == (unit, "lower", "program_span"), name
+        assert (m["layer"], m["moves"]) == ("set-up", "setup_s"), name
+        assert set(CELLS) <= set(m["workloads"]), name
+        assert os.path.isfile(os.path.join(_paths.PERFBENCH, "metrics", name + ".py"))
+
+
+def test_the_five_readers_are_per_layer_metrics_of_the_six_cells():
+    with open(os.path.join(_paths.ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    check_benchmark(bench)
+    assert {w["name"] for w in bench["workloads"]} >= set(CELLS)
 
 
 def _reader(name):
