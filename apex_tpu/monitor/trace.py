@@ -135,6 +135,38 @@ written out before the scanned expert layers; the same step, so ``opt`` and
     final_norm                  the RMSNorm after the stack
     lm_head_loss                untied head + cross entropy, fused
 
+Device scopes of the windowed decoder's train step (``transformer/laguna.py``:
+sliding-window layers among full ones, a gate a head, a leading dense layer
+written out before the scanned expert layers; the same step, so ``opt`` and
+``scan_carry`` as above; ``layer/moe/*`` as in the block-diffusion decoder)::
+
+    embed                       token embedding (rows of the vocabulary held)
+    layer                       one layer (dense, sliding or full), and in it
+    layer/pre_norm              the RMSNorm before each sublayer
+    layer/swa/qkv               a sliding layer's q (its 72 heads), k and v
+    layer/swa/qk_norm           RMSNorm over each head's width
+    layer/swa/rope              the rotation of q and k (the whole head)
+    layer/swa/core              the flash call on the window's listed tiles
+                                and its layout changes; the kernels below it
+                                as ``.../flash_fwd``, ``.../flash_bwd_dq``,
+                                ``.../flash_bwd_dkv``
+    layer/swa/gate              sigmoid(a W_g) a head times the head's output
+    layer/swa/out               merge of heads and output projection
+    layer/attn/qkv  layer/attn/qk_norm  layer/attn/rope  layer/attn/core
+    layer/attn/gate  layer/attn/out
+                                the same on a full layer (48 heads, causal,
+                                YaRN on the first half of a head)
+    layer/mlp/gate_up  layer/mlp/act  layer/mlp/down
+                                the dense layer's gated FFN
+    layer/shared/gate_up  layer/shared/act  layer/shared/down
+                                an expert layer's shared expert
+    layer/shared/gate           its sigmoid gate a position
+    layer/moe/route  layer/moe/dispatch  layer/moe/experts  layer/moe/combine
+                                the routed experts, as above
+    layer/residual              the residual adds
+    final_norm                  the RMSNorm after the stack
+    lm_head_loss                untied head + cross entropy, fused
+
 **Counters of a routed layer** (``ROUTING_COUNTERS``; a step's, from the
 held experts' loads that the step itself returns, ``train_step_fn``'s fourth
 result, through ``transformer.moe.routing_facts``): ``pairs_held``

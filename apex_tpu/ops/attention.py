@@ -83,6 +83,10 @@ class MaskStructure:
     ``blk(j) < blk(i)``; or both are clean and ``blk(j) <= blk(i)``. A clean
     query sees no noised key.
 
+    ``window`` (``block`` the window's length ``W``): key ``j`` is seen by
+    query ``i`` iff ``i - W < j <= i``, the query's own position and the
+    ``W - 1`` before it.
+
     :meth:`hidden` is the rule element by element (arrays of positions: the
     kernels' straddling tiles and the dense reference); :meth:`tile_kinds`
     is the same rule over whole tiles, from their corners."""
@@ -90,7 +94,7 @@ class MaskStructure:
     block: int = 1
 
     def __post_init__(self):
-        if self.kind not in ("causal", "block_diffusion"):
+        if self.kind not in ("causal", "block_diffusion", "window"):
             raise ValueError(f"unknown mask structure {self.kind!r}")
         if self.block < 1:
             raise ValueError(f"block must be >= 1, got {self.block}")
@@ -100,6 +104,8 @@ class MaskStructure:
         broadcast against each other: numpy or traced)."""
         if self.kind == "causal":
             return kpos > qpos
+        if self.kind == "window":
+            return (kpos > qpos) | (qpos - kpos >= self.block)
         half = sq // 2
         q_noised, k_noised = qpos < half, kpos < half
         # positions are never negative, so // and % are shifts and masks
@@ -116,7 +122,7 @@ class MaskStructure:
         must lie within one quadrant of (noised | clean) x (noised | clean)."""
         if sq != sk:
             return False
-        if self.kind == "causal":
+        if self.kind in ("causal", "window"):
             return True
         half = sq // 2
         return sq % 2 == 0 and half % block_q == 0 and half % block_k == 0
@@ -130,6 +136,12 @@ class MaskStructure:
         k0, k1 = j * block_k, j * block_k + block_k - 1
         if self.kind == "causal":
             return k0 <= q1, k1 <= q0
+        if self.kind == "window":
+            # q - k over the tile runs from q0 - k1 to q1 - k0; a pair is
+            # seen where it lies in [0, W - 1]
+            w = self.block
+            return ((q1 - k0 >= 0) & (q0 - k1 <= w - 1),
+                    (q0 - k1 >= 0) & (q1 - k0 <= w - 1))
         half = sq // 2
         qn, kn = q0 < half, k0 < half
         qb0, qb1 = (q0 % half) // self.block, (q1 % half) // self.block
@@ -150,6 +162,12 @@ def block_diffusion_mask(block: int) -> MaskStructure:
     """The block-diffusion training mask over ``[noised copy ; clean copy]``
     (:class:`MaskStructure`)."""
     return MaskStructure("block_diffusion", int(block))
+
+
+def sliding_window_mask(window: int) -> MaskStructure:
+    """Causal attention over the last ``window`` positions, the query's own
+    among them (:class:`MaskStructure`)."""
+    return MaskStructure("window", int(window))
 
 
 def _as_structure(causal) -> Optional[MaskStructure]:
@@ -1193,8 +1211,9 @@ def _fa_fwd_listed_kernel(tiles_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     @pl.when(flags & 2 != 0)
     def _finish():
-        # a row's first live tile shows every query a key (its own block, or
-        # the diagonal), so l >= 1 here: no guard as in the streamed _finish
+        # a row's live tiles show every query a key, so l >= 1 here: no guard
+        # as in the streamed _finish (a query that saw none in an earlier
+        # tile, at a window's edge, is zeroed by the first tile that shows one)
         o_ref[0] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
         # lse leaves lane-dense, a row of block_q (the scratch holds it across
         # its 128 lanes, so its transpose holds it in every row): a column

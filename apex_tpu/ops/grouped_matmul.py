@@ -22,8 +22,8 @@ the kernels use:
   the group's row tiles accumulated in float32 in VMEM and written once a
   group; a group with no rows gets one visit that writes zeros.
 
-Every block holds its operands' widths whole (up to ``MAX_WIDTH``), so a
-width needs no tile that divides it: 1,408 is 128 x 11, 768 is 128 x 6.
+Every block holds its operands' widths whole (up to ``MAX_WIDTH``, 3,072),
+so a width needs no tile that divides it: 1,408 is 128 x 11, 768 is 128 x 6.
 
 bfloat16 operands, float32 accumulation and a result in the operands' type,
 as ``ragged_dot`` gives. On a compiled backend where the shapes tile the
@@ -61,8 +61,10 @@ ROW_TILE = 1024
 # The widest operand the kernels take whole (both widths of every block): at
 # the cells' widths the blocks take up to about 30 MiB of VMEM (grouped_dw's
 # float32 block of 2,048 x 1,408 beside its double-buffered output and row
-# tiles), past XLA's 16 MiB default scoped limit; a v5e core has 128.
-MAX_WIDTH = 2048
+# tiles), past XLA's 16 MiB default scoped limit; a v5e core has 128. At
+# 3,072 x 1,024 (Laguna's hidden and expert widths) grouped_dw's float32 block
+# is 12 MiB and a 1,024-row tile's blocks about 40 MiB in all.
+MAX_WIDTH = 3072
 _VMEM_LIMIT_BYTES = 96 * 1024 * 1024
 
 

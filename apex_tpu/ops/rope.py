@@ -21,7 +21,13 @@ with ``exp(i angle)``. Two things a model's published config decides:
   ``rope_scaling``; :class:`RopeScaling` (YaRN, as ``rope_scaling`` of type
   ``yarn`` publishes it) blends each with ``inv_freq_i / factor`` by a ramp
   over the pair's index and multiplies cos and sin by a ratio of two
-  ``mscale`` terms (:func:`rotary_inv_freq`).
+  ``mscale`` terms, or by ``attention_factor`` where the config gives one
+  (:func:`rotary_inv_freq`).
+* **how much of a head turns.** ``rotary_dim`` (``d x
+  partial_rotary_factor``): the first ``rotary_dim`` elements are rotated as
+  a head of that width (pairs, frequencies and YaRN's ramp over it), the
+  rest pass through. A config whose ``rope_parameters`` differ by layer type
+  gives each type's entry (:func:`rope_for_layer_type`).
 
 Angles, sines and cosines are float32 whatever ``x`` is; the result is
 ``x``'s type. The rotation is linear in ``x`` and orthogonal (times the
@@ -36,7 +42,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Mapping, Optional
+from typing import Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -55,11 +61,13 @@ class RopeScaling:
     beta_slow: float = 1.0
     mscale: float = 1.0
     mscale_all_dim: float = 0.0
+    # what cos and sin are multiplied by, where the config states it
+    attention_factor: Optional[float] = None
 
     @classmethod
     def from_config(cls, rope_scaling: Optional[Mapping]) -> Optional["RopeScaling"]:
         """``None`` for no ``rope_scaling``; another type than ``yarn`` is
-        refused."""
+        refused. The type is ``type`` or ``rope_type``."""
         if rope_scaling is None:
             return None
         kind = rope_scaling.get("type", rope_scaling.get("rope_type"))
@@ -82,6 +90,8 @@ class RopeScaling:
     @property
     def cos_sin_scale(self) -> float:
         """What cos and sin are multiplied by."""
+        if self.attention_factor is not None:
+            return self.attention_factor
         return (_yarn_mscale(self.factor, self.mscale)
                 / _yarn_mscale(self.factor, self.mscale_all_dim))
 
@@ -90,6 +100,19 @@ class RopeScaling:
         """``m``: a model whose config has ``mscale_all_dim`` multiplies its
         softmax scale ``d^-1/2`` by ``m * m``."""
         return _yarn_mscale(self.factor, self.mscale_all_dim)
+
+
+def rope_for_layer_type(rope_parameters: Mapping, layer_type: str
+                        ) -> Tuple[float, Optional[RopeScaling], float]:
+    """``(theta, scaling, partial_rotary_factor)`` of one layer type's entry
+    of a config's ``rope_parameters`` (``{"full_attention": {...},
+    "sliding_attention": {...}}``): ``rope_type`` ``default`` is no
+    scaling, ``yarn`` a :class:`RopeScaling`."""
+    entry = rope_parameters[layer_type]
+    kind = entry.get("rope_type", entry.get("type", "default"))
+    scaling = None if kind == "default" else RopeScaling.from_config(entry)
+    return (float(entry["rope_theta"]), scaling,
+            float(entry.get("partial_rotary_factor", 1.0)))
 
 
 def rotary_inv_freq(dim: int, theta: float = 10000.0,
@@ -139,7 +162,11 @@ def rotate_pairs(x):
     return jnp.where(even, -jnp.roll(x, -1, axis=-1), jnp.roll(x, 1, axis=-1))
 
 
-def _rotate(x, positions, theta, scaling, interleaved, sign):
+def _rotate(x, positions, theta, scaling, interleaved, sign, rotary_dim=None):
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        turned = _rotate(x[..., :rotary_dim], positions, theta, scaling,
+                         interleaved, sign)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     cos, sin = rotary_angles(positions, x.shape[-1], theta, scaling,
                              interleaved)
     x32 = x.astype(jnp.float32)
@@ -147,21 +174,24 @@ def _rotate(x, positions, theta, scaling, interleaved, sign):
     return (x32 * cos + turn(x32) * (sign * sin)).astype(x.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
 def apply_rotary(x, positions, theta: float = 10000.0,
                  scaling: Optional[RopeScaling] = None,
-                 interleaved: bool = False):
+                 interleaved: bool = False, rotary_dim: Optional[int] = None):
     """``x`` (..., seq, d) rotated by ``positions`` (seq,), or any shape that
-    broadcasts against ``x.shape[:-1]``."""
-    return _rotate(x, positions, theta, scaling, interleaved, 1.0)
+    broadcasts against ``x.shape[:-1]``; only its first ``rotary_dim``
+    elements where given, the rest passed through."""
+    return _rotate(x, positions, theta, scaling, interleaved, 1.0, rotary_dim)
 
 
-def _apply_rotary_fwd(x, positions, theta, scaling, interleaved):
-    return _rotate(x, positions, theta, scaling, interleaved, 1.0), positions
+def _apply_rotary_fwd(x, positions, theta, scaling, interleaved, rotary_dim):
+    return (_rotate(x, positions, theta, scaling, interleaved, 1.0, rotary_dim),
+            positions)
 
 
-def _apply_rotary_bwd(theta, scaling, interleaved, positions, dy):
-    return _rotate(dy, positions, theta, scaling, interleaved, -1.0), None
+def _apply_rotary_bwd(theta, scaling, interleaved, rotary_dim, positions, dy):
+    return (_rotate(dy, positions, theta, scaling, interleaved, -1.0,
+                    rotary_dim), None)
 
 
 apply_rotary.defvjp(_apply_rotary_fwd, _apply_rotary_bwd)

@@ -55,7 +55,14 @@ from apex_tpu.ops.attention import flash_attention
 from apex_tpu.ops.layer_norm import rms_norm
 from apex_tpu.ops.lm_head_loss import lm_head_loss
 from apex_tpu.ops.rope import RopeScaling, apply_rotary
-from apex_tpu.transformer.hybrid import gated_ffn, refuse_tp, run_stack
+from apex_tpu.transformer.hybrid import (
+    ATTN_KEPT,
+    append_row,
+    gated_ffn,
+    refuse_tp,
+    run_stack,
+    sublayer_remat,
+)
 from apex_tpu.transformer.moe import (
     RoutedExpertsConfig,
     routed_expert_shapes,
@@ -270,36 +277,26 @@ def _experts_sublayer(p, x, cfg: DeepSeekConfig):
         return x + routed + shared, {**counted, "aux_loss": aux[None]}
 
 
-def _wrap(cfg: DeepSeekConfig, keep=()):
-    """What a sublayer runs under: with ``remat`` "sublayer" a checkpoint of
-    its own that keeps its input and the arrays named ``keep``."""
-    if cfg.remat != "sublayer":
-        return lambda f: f
-    policy = jax.checkpoint_policies.save_only_these_names(*keep)
-    return lambda f: jax.checkpoint(f, policy=policy)
-
-
-# the flash kernel's output and row statistics (``ops/attention.py`` names
-# them): kept, the attention sublayer's replay runs its projections and the
-# rotation again and not the kernel. 192 MiB a layer at the benchmark's cell
-# (o 64, lse 128: a float32 column a row is padded to 128 lanes)
-ATTN_KEPT = ("attn_out", "attn_lse")
+# ``ATTN_KEPT`` (the flash kernel's output and row statistics) is 192 MiB a
+# layer at the benchmark's cell (o 64, lse 128: a float32 column a row is
+# padded to 128 lanes)
 
 
 def _dense_layer(p, x, cfg: DeepSeekConfig):
-    x = _wrap(cfg, ATTN_KEPT)(lambda p, x: _attention_sublayer(p, x, cfg))(p, x)
-    return _wrap(cfg)(lambda p, x: _dense_sublayer(p, x, cfg))(p, x)
+    x = sublayer_remat(cfg.remat, ATTN_KEPT)(
+        lambda p, x: _attention_sublayer(p, x, cfg))(p, x)
+    return sublayer_remat(cfg.remat)(lambda p, x: _dense_sublayer(p, x, cfg))(p, x)
 
 
 def _expert_layer(p, carry, cfg: DeepSeekConfig):
-    """One expert layer over the stack's carry ``(x, counted)``: each array
-    of ``counted`` (expert layers, ...) takes this layer's row at its end and
-    drops its first (``sdar.py``'s carry)."""
+    """One expert layer over the stack's carry ``(x, counted)``
+    (:func:`~apex_tpu.transformer.hybrid.append_row`)."""
     x, counted = carry
-    x = _wrap(cfg, ATTN_KEPT)(lambda p, x: _attention_sublayer(p, x, cfg))(p, x)
-    x, here = _wrap(cfg)(lambda p, x: _experts_sublayer(p, x, cfg))(p, x)
-    return x, jax.tree.map(
-        lambda all_, row: jnp.concatenate([all_[1:], row[None]]), counted, here)
+    x = sublayer_remat(cfg.remat, ATTN_KEPT)(
+        lambda p, x: _attention_sublayer(p, x, cfg))(p, x)
+    x, here = sublayer_remat(cfg.remat)(
+        lambda p, x: _experts_sublayer(p, x, cfg))(p, x)
+    return x, append_row(counted, here)
 
 
 def deepseek_hidden(params, tokens, cfg: DeepSeekConfig):

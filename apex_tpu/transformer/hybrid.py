@@ -92,10 +92,7 @@ class HybridConfig:
     @property
     def period(self) -> Tuple[str, ...]:
         """The shortest pattern that ``layer_types`` repeats."""
-        types = self.layer_types
-        for n in range(1, len(types) + 1):
-            if len(types) % n == 0 and types == types[:n] * (len(types) // n):
-                return types[:n]
+        return shortest_period(self.layer_types)
 
     @property
     def num_periods(self) -> int:
@@ -266,6 +263,13 @@ def _layer(p, x, cfg: HybridConfig, kind: str):
         return x + m
 
 
+def shortest_period(types: Tuple[str, ...]) -> Tuple[str, ...]:
+    """The shortest pattern that ``types`` repeats whole."""
+    for n in range(1, len(types) + 1):
+        if len(types) % n == 0 and types == types[:n] * (len(types) // n):
+            return types[:n]
+
+
 def _refuse_tp():
     refuse_tp("the hybrid model's mixers are",
               "the delta rule's heads, its convolutions and the QK-norm over "
@@ -295,6 +299,29 @@ def run_stack(x, periods, period: Tuple[str, ...], layer, remat: bool):
 
     x, _ = lax.scan(body, x, periods)
     return x
+
+
+# the flash kernel's output and row statistics (``ops/attention.py`` names
+# them): kept, an attention sublayer's replay runs its projections and the
+# rotation again and not the kernel
+ATTN_KEPT = ("attn_out", "attn_lse")
+
+
+def sublayer_remat(remat: str, keep=()):
+    """What a sublayer runs under: with ``remat`` "sublayer" a checkpoint of
+    its own that keeps its input and the arrays named ``keep``."""
+    if remat != "sublayer":
+        return lambda f: f
+    policy = jax.checkpoint_policies.save_only_these_names(*keep)
+    return lambda f: jax.checkpoint(f, policy=policy)
+
+
+def append_row(counted, row):
+    """The stack's counters over a layer: each array of ``counted`` (layers,
+    ...) takes this layer's ``row`` at its end and drops its first, so that
+    after the last layer its rows are the layers' in order."""
+    return jax.tree.map(lambda all_, r: jnp.concatenate([all_[1:], r[None]]),
+                        counted, row)
 
 
 def refuse_tp(what: str, why: str):

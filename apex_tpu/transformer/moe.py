@@ -30,7 +30,8 @@ That is :func:`moe_mlp` (top-2, GELU experts, a capacity
 (``MoEConfig.capacity``) and drops in ``_route``), which
 ``standalone_gpt.py`` runs. Beside it :func:`routed_experts_mlp` is the layer
 of the softmax-routed models with many small experts
-(``transformer/sdar.py``, ``transformer/deepseek.py``): **no capacity and no
+(``transformer/sdar.py``, ``transformer/deepseek.py``,
+``transformer/laguna.py``): **no capacity and no
 drops**, SiLU-gated experts, and the layer told which of the router's experts
 it holds (``experts_held``): one chip's share of an expert-parallel
 deployment, computed without the exchange. Two fields of
@@ -304,6 +305,14 @@ BUFFER_OVER_MEAN = 1.5
 # chunk reads 5% fewer rows and takes four times the loop's turns): any of
 # them (my chip runs, PR 34).
 CHUNK_ROWS = 2048
+# The passes over the buffer written out one after another; any past them
+# run as turns of one loop behind one conditional, so the step compiles the
+# pass four times and not once a pass: a layer that holds 8 experts of 256
+# with top-10 has 18 passes in its worst case, and written out they took
+# 351 s of the step's cold compile on a TPU v5e, where the layer's forward and
+# backward take 22.1 ms either way. Every pass after the first is a
+# conditional that a router near uniform never enters.
+PASSES_WRITTEN_OUT = 3
 
 
 @dataclasses.dataclass(frozen=True)
@@ -568,7 +577,8 @@ def routed_experts_mlp(p, x, cfg: RoutedExpertsConfig,
     work follows the pairs held (:class:`RoutedExpertsConfig`): the first
     pass runs outright; each pass after it is a conditional (the pass under
     ``jax.checkpoint``, so that it keeps nothing) that runs only while pairs
-    are left.
+    are left, those past ``PASSES_WRITTEN_OUT`` as turns of one loop inside
+    one more conditional.
 
     Returns ``(y, counted, routed)``. ``counted`` is what the layout itself
     counted, int32 (:func:`routing_facts` reads a step's counters from
@@ -635,10 +645,33 @@ def routed_experts_mlp(p, x, cfg: RoutedExpertsConfig,
 
     y = one_pass(jnp.int32(0))
     later = jax.checkpoint(one_pass)
-    for c in range(1, cfg.passes(t, count)):
+    passes = cfg.passes(t, count)
+    for c in range(1, min(passes, PASSES_WRITTEN_OUT)):
         with span("moe/combine"):
             y = y + lax.cond(c * n < total, later,
                              lambda c: jnp.zeros_like(y), jnp.int32(c))
+
+    if passes > PASSES_WRITTEN_OUT:
+        def turn(c, y):
+            # the checkpoint around the conditional: a turn keeps its index
+            # alone (inside it, the pass's inputs would leave the conditional
+            # as copies that the loop stacks a turn: 16.7 of 15.75 GiB at
+            # hidden 3,072 and 15 turns)
+            return y + jax.checkpoint(lambda c: lax.cond(
+                c * n < total, one_pass, lambda c: jnp.zeros_like(y), c))(c)
+
+        def the_rest(y):
+            # bounds known when traced: a scan, so the backward runs it too
+            return lax.fori_loop(PASSES_WRITTEN_OUT, passes, turn, y)
+
+        # one conditional round the loop: a layer with no pairs past the
+        # passes written out skips it whole, in the backward too (a turn's
+        # gradient adds the zeros of every leaf of the pass: 85 ms a step
+        # at the window cell when every turn ran it)
+        with span("moe/combine"):
+            y = y + jax.checkpoint(lambda y: lax.cond(
+                PASSES_WRITTEN_OUT * n < total, the_rest, jnp.zeros_like, y))(
+                    jnp.zeros_like(y))
     return (y.reshape(*lead, h),
             {"expert_loads": sizes, "held_places": held_places},
             {"scores": scores, "idx": idx})

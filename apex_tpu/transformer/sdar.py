@@ -53,7 +53,7 @@ from apex_tpu.ops.attention import block_diffusion_mask, flash_attention
 from apex_tpu.ops.layer_norm import rms_norm
 from apex_tpu.ops.lm_head_loss import lm_head_loss
 from apex_tpu.ops.rope import apply_rotary
-from apex_tpu.transformer.hybrid import refuse_tp, run_stack
+from apex_tpu.transformer.hybrid import append_row, refuse_tp, run_stack
 from apex_tpu.transformer.moe import (
     RoutedExpertsConfig,
     routed_expert_shapes,
@@ -217,15 +217,13 @@ def _experts_sublayer(p, x, cfg: SDARConfig):
 
 
 def _layer(p, carry, cfg: SDARConfig):
-    """One layer over the stack's carry ``(x, counted)``: each array of
-    ``counted`` (layers, ...) takes this layer's row at its end and drops its
-    first, so that after the last layer its rows are the layers' in order."""
+    """One layer over the stack's carry ``(x, counted)``, this layer's
+    counters appended to the stack's (:func:`hybrid.append_row`)."""
     x, counted = carry
     wrap = jax.checkpoint if cfg.remat == "sublayer" else (lambda f: f)
     x = wrap(lambda p, x: _attention_sublayer(p, x, cfg))(p, x)
     x, here = wrap(lambda p, x: _experts_sublayer(p, x, cfg))(p, x)
-    return x, jax.tree.map(lambda all_, row: jnp.concatenate([all_[1:], row[None]]),
-                           counted, here)
+    return x, append_row(counted, here)
 
 
 def sdar_hidden(params, seq2, cfg: SDARConfig):

@@ -1,0 +1,43 @@
+"""What the Laguna cell's readers share: whether a run is of that family (by
+the configuration's ``model_type``, which no other family states: its
+``layer_types`` are windowed and full attention, which the hybrid decoder's
+key also names), and its counter ``pairs_held``, read as the other routed
+cells' readers read it. The routed layer's device time is
+``scopes_sdar.moe_seconds``'s: the layer is the same code. The shared expert
+runs under ``layer/shared/*`` and is no part of it."""
+
+from __future__ import annotations
+
+from typing import Dict, Optional
+
+import scopes
+import scopes_sdar
+from scopes_sdar import pairs_held  # noqa: F401  (the kind's counter, read the same way)
+
+MODEL_TYPE = "laguna"
+SWA = "layer/swa"       # the sliding layers' attention sublayer
+ATTN = "layer/attn"     # the full layers' (and layer 0's)
+FLASH = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
+
+def is_laguna(facts: Dict) -> bool:
+    return facts.get("kind") == "train" and facts.get("model", {}).get("model_type") == MODEL_TYPE
+
+
+def moe_seconds(facts: Dict, trace) -> Optional[Dict[str, float]]:
+    return scopes_sdar.moe_seconds(facts, trace) if is_laguna(facts) else None
+
+
+def flash_seconds(facts: Dict, trace, sublayer: str) -> Optional[float]:
+    """Device seconds a step of the flash kernels under ``<sublayer>/core``
+    (every phase: a forward replayed costs time too); ``None`` where there
+    is no scope table or no such kernel."""
+    if trace is None or not is_laguna(facts):
+        return None
+    got = scopes.train_step_scopes(facts, trace)
+    if got is None:
+        return None
+    core = sublayer + "/core/"
+    seconds = sum(s for (_, scope, _), s in got["rows"].items()
+                  if scope.startswith(core) and scope[len(core):] in FLASH)
+    return seconds or None

@@ -2,8 +2,9 @@
 kernels' listed schedule, and grouped heads: the tile kinds against the dense
 mask, the kernels (Pallas interpret mode) against the dense reference forward
 and backward at a length where tiles are skipped, whole and straddled, K/V
-with fewer heads against K/V repeated, and ``causal`` through the structure
-bit for bit what ``causal=True`` gives."""
+with fewer heads against K/V repeated, the sliding window at Laguna's groups
+of 9 and 6, and ``causal`` through the structure bit for bit what
+``causal=True`` gives."""
 
 import numpy as np
 import pytest
@@ -18,6 +19,7 @@ from apex_tpu.ops.attention import (
     attention_reference,
     block_diffusion_mask,
     flash_attention,
+    sliding_window_mask,
 )
 
 
@@ -56,7 +58,17 @@ def test_block_diffusion_rule_is_the_three_predicates(s, block):
                                   _by_hand(s, block))
 
 
+@pytest.mark.parametrize("s,window", [(16, 1), (32, 5), (48, 8), (40, 64)])
+def test_the_window_rule_is_the_last_window_positions(s, window):
+    i, j = np.arange(s)[:, None], np.arange(s)[None, :]
+    np.testing.assert_array_equal(~_dense_hidden(sliding_window_mask(window), s),
+                                  (i - window < j) & (j <= i))
+
+
 @pytest.mark.parametrize("structure,s,bq,bk", [
+    (sliding_window_mask(64), 256, 64, 64), (sliding_window_mask(64), 256, 32, 32),
+    (sliding_window_mask(48), 256, 32, 64), (sliding_window_mask(100), 512, 64, 32),
+    (sliding_window_mask(512), 2048, 256, 256), (sliding_window_mask(1), 128, 16, 16),
     (CAUSAL, 256, 64, 64), (CAUSAL, 256, 32, 128),
     (block_diffusion_mask(4), 256, 32, 32), (block_diffusion_mask(4), 512, 64, 128),
     (block_diffusion_mask(16), 256, 8, 8), (block_diffusion_mask(64), 512, 32, 64),
@@ -85,6 +97,18 @@ def test_the_cells_plan_visits_288_of_1024_tiles_and_masks_48():
     assert (int(((q < 16) & (k < 16)).sum()), int(((q < 16) & (k >= 16)).sum()),
             int(((q >= 16) & (k >= 16)).sum()), int(((q >= 16) & (k < 16)).sum())
             ) == (16, 136, 136, 0)
+
+
+@pytest.mark.parametrize("block,visited,masked", [(512, 31, 31), (256, 93, 62)])
+def test_the_window_plan_at_8192_lists_the_live_tiles_alone(block, visited, masked):
+    """Laguna's sliding layers (s 8,192, window 512, 72 query heads over 8):
+    a q tile's live K/V tiles are its own and the one or two before it."""
+    plan = A._tile_plan(8192, 8192, 128, jnp.bfloat16, sliding_window_mask(512),
+                        block_q=block, block_k=block, group=9)
+    n = 8192 // block
+    assert plan == A.TilePlan("listed", block, block, visited, masked, n * n)
+    by_row, _ = A._listed_tiles(sliding_window_mask(512), 8192, 8192, block, block)
+    assert np.all(by_row[1] <= by_row[0]) and np.all(by_row[0] - by_row[1] <= 512 // block)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -122,6 +146,26 @@ def test_listed_kernels_match_the_dense_mask_forward_and_backward(structure, gro
     np.testing.assert_allclose(o, o_ref, atol=2e-5)
     for got, want in zip(grads, grads_ref):
         assert got.shape == want.shape          # dk, dv in K/V's own head count
+        np.testing.assert_allclose(got, want, atol=5e-5)
+
+
+@pytest.mark.parametrize("window,heads,kv", [(100, 9, 1), (64, 18, 2), (100, 6, 1), (64, 12, 2)],
+                         ids=["w100-group9", "w64-group9", "w100-group6", "w64-group6"])
+def test_listed_kernels_match_the_window_forward_and_backward(window, heads, kv):
+    """Laguna's groups (72 over 8 on sliding layers, 48 over 8 on full ones)
+    at s 512 on 64 x 64 tiles: a window of 64 lies on the tiles' edges, one
+    of 100 straddles them; the output and dq, dk, dv against the dense
+    reference."""
+    structure = sliding_window_mask(window)
+    q, k, v = _qkv(jax.random.PRNGKey(window + heads), 1, heads, kv, 512, 32)
+    flash = lambda q, k, v: flash_attention(q, k, v, structure=structure, block_q=64,
+                                            block_k=64, use_pallas=True)
+    dense = lambda q, k, v: attention_reference(q, k, v, structure=structure)
+    o, grads = _value_and_grads(flash, q, k, v)
+    o_ref, grads_ref = _value_and_grads(dense, q, k, v)
+    np.testing.assert_allclose(o, o_ref, atol=2e-5)
+    for got, want in zip(grads, grads_ref):
+        assert got.shape == want.shape
         np.testing.assert_allclose(got, want, atol=5e-5)
 
 
@@ -208,4 +252,6 @@ def test_what_is_refused():
         flash_attention(q, k[:, :1].repeat(4, 1), v[:, :1].repeat(4, 1),
                         mask=jnp.zeros((128, 128), bool), use_pallas=True)
     with pytest.raises(ValueError, match="unknown mask structure"):
-        MaskStructure("window")
+        MaskStructure("dilated")
+    with pytest.raises(ValueError, match="block must be"):
+        sliding_window_mask(0)

@@ -26,6 +26,9 @@ CASES = {
     "latent-attention-down": (64, 1408, 2048, 16, [32, 16], F32),
     "block-diffusion-gate": (96, 2048, 768, 32, [32, 0, 32], F32),
     "block-diffusion-down": (96, 768, 2048, 32, [64, 32], F32),
+    # Laguna's hidden width, past what the kernels took before it
+    "hidden-3072-gate": (64, 3072, 1024, 16, [16, 0, 32], F32),
+    "hidden-3072-down": (64, 1024, 3072, 16, [32, 16], F32),
     "an-empty-group": (128, 256, 128, 16, [32, 0, 48, 16], F32),
     "one-group-holds-every-row": (128, 128, 256, 16, [0, 128, 0], F32),
     "groups-fill-the-buffer": (128, 256, 128, 16, [32, 64, 32], F32),
@@ -79,6 +82,12 @@ def test_the_kernels_take_only_the_layouts_they_tile():
     assert kernel_row_tile(xs, w, 8) is None                          # under a bfloat16 tile
     assert kernel_row_tile(xs, w.astype(F32), 512) is None            # mixed types
     assert kernel_row_tile(xs[:, :100], w[:, :100], 512) is None      # width no multiple of 128
+    wide = jnp.zeros((8, 3072, 1024), jnp.bfloat16)                    # Laguna's widths
+    assert kernel_row_tile(jnp.zeros((7680, 3072), jnp.bfloat16), wide, 256) == 256
+    assert kernel_row_tile(jnp.zeros((7680, 1024), jnp.bfloat16),
+                           jnp.swapaxes(wide, 1, 2), 256) == 256
+    assert kernel_row_tile(jnp.zeros((4096, 4096), jnp.bfloat16),     # past MAX_WIDTH
+                           jnp.zeros((8, 4096, 1024), jnp.bfloat16), 512) is None
     with pytest.raises(ValueError, match="grouped kernels need"):
         grouped_matmul(xs, w, jnp.zeros((16,), jnp.int32), 8, use_pallas=True)
 

@@ -171,3 +171,54 @@ def test_backward_of_the_scaled_interleaved_rotation_is_the_opposite_one(dtype):
     assert got.dtype == x.dtype
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
                                atol=1e-6 if dtype == jnp.float32 else 2e-2)
+
+
+# ---------------------------------------------------------------------------
+# rope_parameters by layer type, partial rotation and attention_factor:
+# Laguna-S-2.1's published keys
+
+from apex_tpu.ops.rope import rope_for_layer_type
+
+LAGUNA = {"full_attention": {"rope_theta": 500000, "rope_type": "yarn", "factor": 128,
+                             "original_max_position_embeddings": 8192, "beta_slow": 1,
+                             "beta_fast": 32, "attention_factor": 1.4852030263919618,
+                             "partial_rotary_factor": 0.5},
+          "sliding_attention": {"rope_type": "default", "rope_theta": 10000,
+                                "partial_rotary_factor": 1}}
+
+
+def test_each_layer_types_entry_of_rope_parameters():
+    theta, scaling, fraction = rope_for_layer_type(LAGUNA, "full_attention")
+    assert (theta, fraction) == (500000.0, 0.5)
+    assert scaling.factor == 128 and scaling.attention_factor == pytest.approx(1.48520303)
+    assert scaling.cos_sin_scale == scaling.attention_factor       # the ratio of mscales is not used
+    assert scaling.ramp_bounds(64, theta) == (9, 18)                # over the 64 rotated widths
+    assert rope_for_layer_type(LAGUNA, "sliding_attention") == (10000.0, None, 1.0)
+    with pytest.raises(NotImplementedError, match="dynamic"):
+        rope_for_layer_type({"full_attention": {"rope_type": "dynamic", "rope_theta": 1}},
+                            "full_attention")
+
+
+@pytest.mark.parametrize("interleaved", [False, True])
+def test_a_partial_rotation_turns_the_first_widths_and_passes_the_rest(interleaved):
+    """``rotary_dim`` 64 of 128: the first 64 rotated as a head of 64 (YaRN's
+    ramp over them), the other 64 as they were; forward and backward."""
+    _, scaling, _ = rope_for_layer_type(LAGUNA, "full_attention")
+    x = jax.random.normal(jax.random.PRNGKey(12), (2, 3, 40, 128), jnp.float32)
+    positions = jnp.arange(40) * 300           # up to 12 k: past the original 8,192
+    got = apply_rotary(x, positions, 5e5, scaling, interleaved, 64)
+    np.testing.assert_array_equal(got[..., 64:], x[..., 64:])
+    np.testing.assert_allclose(got[..., :64],
+                               apply_rotary(x[..., :64], positions, 5e5, scaling, interleaved),
+                               atol=1e-6)
+    cos, _ = rotary_angles(positions, 64, 5e5, scaling)
+    assert float(jnp.abs(cos).max()) == pytest.approx(1.4852030, rel=1e-6)
+    # the backward: the opposite rotation on the first 64, the identity after
+    loss = lambda f: lambda x: jnp.sum(jnp.sin(f(x)))
+    g = jax.grad(loss(lambda x: apply_rotary(x, positions, 5e5, scaling, interleaved, 64)))(x)
+    want = jax.grad(loss(lambda x: jnp.concatenate(
+        [apply_rotary(x[..., :64], positions, 5e5, scaling, interleaved), x[..., 64:]], -1)))(x)
+    np.testing.assert_allclose(g, want, atol=1e-5)
+    # the whole width given is the whole rotation, bit for bit
+    np.testing.assert_array_equal(apply_rotary(x, positions, 5e5, scaling, interleaved, 128),
+                                  apply_rotary(x, positions, 5e5, scaling, interleaved))

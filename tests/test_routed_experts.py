@@ -9,6 +9,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from apex_tpu.transformer import moe
 from apex_tpu.transformer.moe import (
     RoutedExpertsConfig,
     route_softmax_top_k,
@@ -82,6 +83,40 @@ def test_gradients_equal_the_dense_loops_at_any_imbalance(bias, passes):
     with jax.default_matmul_precision("highest"):
         got = jax.grad(loss(lambda p, x: _layer(p, x, (first, count))), argnums=(0, 1))(p, x)
         want = jax.grad(loss(lambda p, x: _dense(p, x, first, count)), argnums=(0, 1))(p, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, atol=5e-5)
+
+
+def test_the_passes_past_those_written_out_loop_and_equal_the_dense_loops():
+    """32 experts, one held: a pass holds 16 rows and the worst case takes 7
+    passes, the last four as turns of one loop. A router biased to the held
+    expert sends it all 96 positions, six passes' worth; the layer and its
+    gradients are the dense loop's."""
+    cfg, e, first = RoutedExpertsConfig(num_experts=32, top_k=K), 32, 2
+    assert (cfg.passes(T, 1), cfg.rows_per_pass(T, 1)) == (7, 16)
+    assert cfg.passes(T, 1) > moe.PASSES_WRITTEN_OUT
+    ks = jax.random.split(jax.random.PRNGKey(9), 4)
+    shapes = routed_expert_shapes(H, F, e, 1)
+    p = {name: 0.3 * jax.random.normal(k, shapes[name], jnp.float32)
+         for name, k in zip(("router", "w_gate", "w_up", "w_down"), ks)}
+    p["router"] = p["router"].at[0, first].add(30.0)
+    x = _x(4).at[:, 0].set(1.0)
+
+    def dense(p, x):
+        s = jax.nn.softmax(jnp.dot(x, p["router"], precision="highest"), axis=-1)
+        chosen, idx = jax.lax.top_k(s, K)
+        w = jnp.sum(jnp.where(idx == first, chosen / chosen.sum(-1, keepdims=True), 0.0), -1)
+        out = (jax.nn.silu(x @ p["w_gate"][0]) * (x @ p["w_up"][0])) @ p["w_down"][0]
+        return w[:, None] * out
+
+    layer = lambda p, x: routed_experts_mlp(p, x, cfg, (first, 1))[0]
+    loss = lambda f: lambda p, x: jnp.sum(jnp.sin(f(p, x)))
+    with jax.default_matmul_precision("highest"):
+        _, counted, _ = routed_experts_mlp(p, x, cfg, (first, 1))
+        np.testing.assert_allclose(layer(p, x), dense(p, x), atol=2e-5)
+        got = jax.grad(loss(layer), argnums=(0, 1))(p, x)
+        want = jax.grad(loss(dense), argnums=(0, 1))(p, x)
+    assert routing_facts(counted["expert_loads"], T, cfg)["passes_run"] == 6
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, atol=5e-5)
 

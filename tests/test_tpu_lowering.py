@@ -162,6 +162,38 @@ def test_flash_attention_listed_schedule_compiles_at_the_block_diffusion_cells_s
     assert compiled.memory_analysis().temp_size_in_bytes < 400 * 2**20
 
 
+@pytest.mark.parametrize("kind,heads,visited", [("sliding", 72, None), ("full", 48, 136)])
+def test_flash_attention_listed_schedule_compiles_at_the_window_cells_shape(kind, heads, visited):
+    """Laguna's attention at the window cell's shape: 72 (sliding) or 48
+    (full) query heads over 8 key/value heads of 128, 8,192 positions, bf16.
+    The sliding layers' grid lists only the window's live tiles (a q tile's
+    own and the one or two before it: 31 at 512 x 512, 93 at 256 x 256), the
+    full layers' the 136 on or under the diagonal; the three kernels compile
+    for a described v5e, once each."""
+    from apex_tpu.ops._pallas_util import compile_for_tpu, mosaic_calls
+    from apex_tpu.ops.attention import CAUSAL, _tile_plan, flash_attention, sliding_window_mask
+    from apex_tpu.transformer.laguna import WINDOW_BLOCK
+
+    block = WINDOW_BLOCK if kind == "sliding" else 512
+    mask = sliding_window_mask(512) if kind == "sliding" else CAUSAL
+    plan = _tile_plan(8192, 8192, 128, jnp.bfloat16, mask, block, block, group=heads // 8)
+    n = 8192 // block
+    want = {512: 31, 256: 93}[block] if visited is None else visited
+    assert (plan.schedule, plan.block_q, plan.visited, plan.rectangle) == ("listed", block, want, n * n)
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, structure=mask, block_q=block, block_k=block,
+                            scale=128 ** -0.5)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    q = jax.ShapeDtypeStruct((1, heads, 8192, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 8, 8192, 128), jnp.bfloat16)
+    _, compiled = compile_for_tpu(jax.jit(jax.grad(loss, argnums=(0, 1, 2))), q, kv, kv)
+    calls = mosaic_calls(compiled.as_text())
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert sum(c for call, c in calls.items() if name in call) == 1, (name, calls)
+
+
 @pytest.mark.parametrize("heads,seq,d,dv,schedule", [
     (16, 16384, 192, 128, "streamed"), (4, 1024, 24, 16, "resident")])
 def test_flash_attention_compiles_with_a_value_width_of_its_own(heads, seq, d, dv, schedule):
@@ -937,7 +969,15 @@ def _dsv2():
                           num_experts=16, experts_held=(0, 4), shared_hidden=1408), 1, 2048
 
 
-@pytest.mark.parametrize("model", [_sdar, _dsv2], ids=["block-diffusion", "latent-attention"])
+def _laguna():
+    from apex_tpu.transformer.laguna import FULL, SLIDING, LagunaConfig
+    return LagunaConfig(vocab_held=1024, layer_types=(FULL, SLIDING, FULL), full_heads=8,
+                        sliding_heads=16, dense_hidden=1024, num_experts=64,
+                        experts_held=(0, 4)), 1, 2048
+
+
+@pytest.mark.parametrize("model", [_sdar, _dsv2, _laguna],
+                         ids=["block-diffusion", "latent-attention", "window-hidden-3072"])
 def test_the_routed_layers_grouped_products_are_the_programs_kernels(model):
     """At the cells' expert widths (depth, hidden and experts cut): the
     compiled step holds no XLA ``ragged-dot`` and runs the experts' products
