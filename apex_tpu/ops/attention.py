@@ -20,7 +20,9 @@ Who loops over the tiles is the *tile schedule*, chosen per shape by
 
 * **streamed** — q tiles and K/V tiles both on the grid, the statistics in
   VMEM scratch across the innermost grid dim, dead causal steps skipped by
-  predicate. Any length, and the additive bias (its tile rides the grid).
+  predicate. What the other schedules do not carry: the additive bias (its
+  tile rides the grid), attention dropout, and ring attention's partial
+  blocks (a row that sees no key, lse as a column to merge).
 * **resident** — one head a grid step, its Q, K, V (and dO, lse, delta)
   whole in VMEM, the loops over q tiles and K/V tiles unrolled inside the
   kernel with static indices. A causal call computes only the tiles on or
@@ -28,15 +30,16 @@ Who loops over the tiles is the *tile schedule*, chosen per shape by
   m, l and the accumulators are values, stored once a row (or K/V) block.
   Every unrolled tile body is compiled, lowered and hashed at each set-up,
   so a kernel holds at most ``_RESIDENT_MAX_BODIES`` of them (a 2 x 2
-  rectangle): a call over that, or over the VMEM budget, streams.
+  rectangle): a call over that, or over the VMEM budget, is listed.
 * **listed** — the grid walks a *list* of the live tiles (scalar-prefetched
   tables of q tile, K/V tile and flags), so a tile the mask hides costs no
   grid step and no DMA, a whole tile pays no mask, and a tile that straddles
-  an edge builds its mask from positions. What a :class:`MaskStructure`
-  other than ``causal`` takes (the block-diffusion mask), and what grouped
-  heads take (K/V with fewer heads than Q: the K/V block's index map reads
-  head ``i // group``, dK and dV are summed over the group inside the
-  kernel, nothing is repeated in HBM).
+  an edge builds its mask from positions; lse and delta are lane-dense
+  rows. Every call the other two do not take: causal or unmasked, any
+  :class:`MaskStructure` (the block-diffusion mask, the window), and grouped
+  heads (K/V with fewer heads than Q: the K/V block's index map reads head
+  ``i // group``, dK and dV are summed over the group inside the kernel,
+  nothing is repeated in HBM).
 
 Layout: ``flash_attention`` takes (batch, heads, seq, head_dim); at head_dim 64
 (half a lane tile) ``flash_attention_packed`` takes the QKV product: file's end.
@@ -408,20 +411,30 @@ def _listed_tiles(structure: Optional[MaskStructure], sq, sk, bq, bk):
     return table(False), table(True)
 
 
+def _listed_plan(structure, sq, sk, bq, bk) -> TilePlan:
+    """``visited``: the live tiles, each kernel's grid steps a head."""
+    by_row, _ = _listed_tiles(structure, sq, sk, bq, bk)
+    return TilePlan("listed", bq, bk, by_row.shape[1],
+                    int(np.sum(by_row[2] & 4 != 0)), (sq // bq) * (sk // bk))
+
+
 def _tile_plan(sq, sk, d, dtype, causal, block_q=512, block_k=512,
-               has_bias=False, group=1) -> TilePlan:
+               has_bias=False, group=1, dropout=False,
+               whole_rows=False) -> TilePlan:
     """The one place the schedule and the tile are chosen, from what the call
     can observe. ``causal`` is the mask's structure: a bool as ever, or a
     :class:`MaskStructure` (``True`` is ``CAUSAL``). ``group``: query heads a
-    K/V head. ``block_q`` / ``block_k`` bound the tile from above; the
-    widest divisor under them is taken on every schedule."""
+    K/V head. ``dropout``: the call drops attention weights.
+    ``whole_rows``: the caller owns each row whole (``flash_attention``);
+    left False, the call may be a block of ring attention's, whose rows may
+    see no key and whose lse is merged as a column, as ``_fa_fwd`` and
+    ``_fa_bwd`` serve. ``block_q`` / ``block_k`` bound the tile from above;
+    the widest divisor under them is taken on every schedule."""
     structure = _as_structure(causal)
     bq, bk = _pick_block(sq, block_q), _pick_block(sk, block_k)
     nq, nk = sq // bq, sk // bk
     if group > 1 or structure not in (None, CAUSAL):
-        by_row, _ = _listed_tiles(structure, sq, sk, bq, bk)
-        return TilePlan("listed", bq, bk, by_row.shape[1],
-                        int(np.sum(by_row[2] & 4 != 0)), nq * nk)
+        return _listed_plan(structure, sq, sk, bq, bk)
     causal = structure is not None
     kinds = [_tile_kind(causal, i, j, bq, bk)
              for i in range(nq) for j in range(nk)]
@@ -442,6 +455,16 @@ def _tile_plan(sq, sk, d, dtype, causal, block_q=512, block_k=512,
     if resident:
         return TilePlan("resident", bq, bk, visited, visited - interior,
                         nq * nk)
+    streamed = (
+        # what only the streamed kernels carry: the bias tile, the dropout
+        # mask, the l == 0 guard and lse as a column for the ring's merge,
+        # and a causal rectangle's own bounds (no caller makes one)
+        has_bias or dropout or not whole_rows or (causal and sq != sk)
+        # the listed kernels keep lse and delta as rows of the q tile: a
+        # block of whole 128 lanes, or the whole sequence
+        or (bq % 128 and bq != sq))
+    if not streamed:
+        return _listed_plan(structure, sq, sk, bq, bk)
     # the streamed kernels build the causal mask on every tile they compute
     return TilePlan("streamed", bq, bk, visited, visited if causal else 0,
                     nq * nk)
@@ -654,9 +677,10 @@ def _head_spec(rows, width):
 
 def _fa_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret,
             dropout_rate=0.0, seed=None, bias=None):
-    """(o, lse (bh, sq, 1)) of one attention call on (bh, seq, d) arrays.
-    ``block_q`` / ``block_k`` bound the tile; ``_tile_plan`` picks it and
-    the schedule."""
+    """(o, lse (bh, sq, 1)) of one attention call on (bh, seq, d) arrays,
+    resident or streamed: the kernels of a bias, dropout and ring
+    attention's partial rows. ``block_q`` / ``block_k`` bound the tile;
+    ``_tile_plan`` picks it and the schedule."""
     bh, sq, d = q3.shape
     sk, d_v = k3.shape[1], v3.shape[2]
     seed = _seed3(seed)
@@ -1585,10 +1609,10 @@ def flash_attention(
     Pallas flash kernels on aligned shapes (ref capability: ``fmhalib`` +
     ``fast_multihead_attn``, without their seqlen ≤ 512 limit) for no mask
     and for a mask given as a **structure** (:class:`MaskStructure`):
-    ``causal=True`` is ``structure=CAUSAL`` and runs the kernels it always
-    ran; :func:`block_diffusion_mask` (or any other structure) runs the
-    listed schedule, which skips the tiles the structure hides and builds a
-    mask only on the tiles that straddle one of its edges. An arbitrary
+    ``causal=True`` is ``structure=CAUSAL``. A head the resident schedule
+    does not hold runs the listed one, which skips the tiles the structure
+    hides and builds a mask only on the tiles that straddle one of its
+    edges; a bias or dropout keeps such a call streamed. An arbitrary
     dense ``mask`` (True = masked out) or an odd shape takes the XLA
     reference path, which materialises the scores.
 
@@ -1631,7 +1655,7 @@ def flash_attention(
         raise ValueError(f"K/V heads ({hk}, {v.shape[1]}) must be equal and "
                          f"divide the query heads ({h})")
     if structure == CAUSAL:
-        structure, causal = None, True      # the kernels causal always ran
+        structure, causal = None, True      # one spelling: the plan decides
     if structure is not None or hk != h:
         return _flash_listed(q, k, v, mask, CAUSAL if causal else structure,
                              scale, block_q, block_k, use_pallas,
@@ -1671,10 +1695,18 @@ def flash_attention(
         return attention_reference(q, k, v, mask=mask, scale=scale,
                                    causal=causal, dropout_rate=dropout_rate,
                                    dropout_keep=keep, bias=bias)
-    bq = _pick_block(sq, block_q)
-    bk = _pick_block(sk, block_k)
+    plan = _tile_plan(sq, sk, d, q.dtype, causal, block_q, block_k,
+                      has_bias=bias is not None, dropout=dropout_rate > 0.0,
+                      whole_rows=True)
+    bq, bk = plan.block_q, plan.block_k
     if interpret is None:
         interpret = not _compiled_backend()
+    if plan.schedule == "listed":
+        o3 = _flash3_listed(
+            q.reshape(b * h, sq, d), k.reshape(b * h, sk, d),
+            v.reshape(b * h, sk, dv), scale, CAUSAL if causal else None, bq,
+            bk, interpret)
+        return o3.reshape(b, h, sq, dv)
     seed = (jnp.zeros((1,), jnp.int32) if dropout_seed is None
             else jnp.asarray(dropout_seed, jnp.int32).reshape((1,)))
     if bias is not None:

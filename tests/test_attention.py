@@ -186,34 +186,38 @@ def test_flash_bwd_kernels_respect_global_offsets():
 
 # ---------------------------------------------------------------------------
 # The tile schedule (``_tile_plan``): resident (one head whole in VMEM, the
-# tile loops unrolled inside the kernel) against streamed (tiles on the grid).
+# tile loops unrolled inside the kernel) against listed (the live tiles on the
+# grid) and streamed (every tile on the grid: a bias, dropout, the ring).
 
 def _force_schedule(monkeypatch, schedule):
     """Steer ``_tile_plan`` from the test through the cap on unrolled tile
-    bodies: none allowed means every call streams, no cap means every call
-    whose head fits VMEM is resident."""
+    bodies: none allowed means no call is resident (it is listed, or
+    streamed where it carries dropout, a bias or the ring's partial rows),
+    no cap means every call whose head fits VMEM is resident."""
     from apex_tpu.ops import attention
 
     monkeypatch.setattr(attention, "_RESIDENT_MAX_BODIES",
-                        0 if schedule == "streamed" else 10 ** 6)
+                        10 ** 6 if schedule == "resident" else 0)
 
 
-@pytest.mark.parametrize("rate", [0.0, 0.3])
-@pytest.mark.parametrize("block_q,block_k", [(32, 32), (32, 16), (16, 32)])
+@pytest.mark.parametrize("block_q,block_k", [(128, 128), (128, 64), (128, 256)])
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("schedule", ["resident", "streamed"])
+@pytest.mark.parametrize("schedule,rate", [
+    ("resident", 0.0), ("resident", 0.3), ("listed", 0.0), ("streamed", 0.3)])
 def test_schedule_matches_reference(monkeypatch, schedule, causal, block_q,
                                     block_k, rate):
-    """Forward and all three gradients on either schedule, interior and
+    """Forward and all three gradients on each schedule, interior and
     diagonal tiles, equal and unequal tiles (several diagonal tiles a q
     tile, or one K/V tile across several q tiles), with and without the
-    in-kernel dropout."""
+    in-kernel dropout: off the resident schedule a call with dropout
+    streams, one without is listed."""
     from apex_tpu.ops.attention import _tile_plan, attention_dropout_mask
 
     _force_schedule(monkeypatch, schedule)
-    b, h, s, d = 1, 2, 64, 32
+    b, h, s, d = 1, 2, 256, 32
     q, k, v = _qkv(jax.random.PRNGKey(12), b, h, s, s, d)
-    plan = _tile_plan(s, s, d, q.dtype, causal, block_q, block_k)
+    plan = _tile_plan(s, s, d, q.dtype, causal, block_q, block_k,
+                      dropout=rate > 0, whole_rows=True)
     assert plan[:3] == (schedule, block_q, block_k)
     seed = jnp.int32(41)
     keep = None
@@ -348,28 +352,42 @@ def test_scale_folded_into_operand_matches_scaled_scores(monkeypatch, d,
                                        err_msg=name)
 
 
-# (sq, sk, causal, bound, has_bias) -> the plan; d 64, bf16 throughout
+# (sq, sk, causal, bound, has_bias, dropout, whole_rows) -> the plan; d 64, bf16 throughout
 _PLANS = {
-    "cells": ((1024, 1024, True, 512, False),
+    "cells": ((1024, 1024, True, 512, False, False, True),
               ("resident", 512, 512, 3, 2, 4, 3)),
-    "s2048": ((2048, 2048, True, 512, False),
-              ("streamed", 512, 512, 10, 10, 16, 1)),
-    "s8192": ((8192, 8192, True, 512, False),
-              ("streamed", 512, 512, 136, 136, 256, 1)),
-    "biased": ((1024, 1024, True, 512, True),
+    "cells_dropout": ((1024, 1024, True, 512, False, True, True),
+                      ("resident", 512, 512, 3, 2, 4, 3)),
+    "s2048": ((2048, 2048, True, 512, False, False, True),
+              ("listed", 512, 512, 10, 4, 16, 1)),
+    "s8192": ((8192, 8192, True, 512, False, False, True),
+              ("listed", 512, 512, 136, 16, 256, 1)),
+    "s16384": ((16384, 16384, True, 512, False, False, True),
+               ("listed", 512, 512, 528, 32, 1024, 1)),
+    "s8192_ring": ((8192, 8192, True, 512, False, False, False),
+                   ("streamed", 512, 512, 136, 136, 256, 1)),
+    "s8192_dropout": ((8192, 8192, True, 512, False, True, True),
+                      ("streamed", 512, 512, 136, 136, 256, 1)),
+    "biased": ((1024, 1024, True, 512, True, False, True),
                ("streamed", 512, 512, 3, 3, 4, 1)),
-    "noncausal": ((1024, 1024, False, 512, False),
+    "noncausal": ((1024, 1024, False, 512, False, False, True),
                   ("resident", 512, 512, 4, 0, 4, 4)),
-    "noncausal_s1536": ((1536, 1536, False, 512, False),
-                        ("streamed", 512, 512, 9, 0, 9, 1)),
-    "causal_s1536": ((1536, 1536, True, 512, False),
-                     ("streamed", 512, 512, 6, 6, 9, 1)),
-    "one_tile": ((512, 512, True, 512, False),
+    "noncausal_s1536": ((1536, 1536, False, 512, False, False, True),
+                        ("listed", 512, 512, 9, 0, 9, 1)),
+    "noncausal_s1536_biased": ((1536, 1536, False, 512, True, False, True),
+                               ("streamed", 512, 512, 9, 0, 9, 1)),
+    "noncausal_s1536_dropout": ((1536, 1536, False, 512, False, True, True),
+                                ("streamed", 512, 512, 9, 0, 9, 1)),
+    "causal_s1536": ((1536, 1536, True, 512, False, False, True),
+                     ("listed", 512, 512, 6, 3, 9, 1)),
+    "one_tile": ((512, 512, True, 512, False, False, True),
                  ("resident", 512, 512, 1, 1, 1, 1)),
-    "bound_256": ((1024, 1024, True, 256, False),
-                  ("streamed", 256, 256, 10, 10, 16, 1)),
-    "causal_rectangle": ((512, 1024, True, 512, False),
+    "bound_256": ((1024, 1024, True, 256, False, False, True),
+                  ("listed", 256, 256, 10, 4, 16, 1)),
+    "causal_rectangle": ((512, 1024, True, 512, False, False, True),
                          ("streamed", 512, 512, 1, 1, 2, 1)),
+    "tile_off_the_lanes": ((320, 320, True, 512, False, False, True),
+                           ("streamed", 64, 64, 15, 15, 25, 1)),
 }
 
 
@@ -378,49 +396,55 @@ def test_tile_plan_counts(case):
     """The plan is the mechanism's counter: schedule, tile, tiles visited /
     masked / in the rectangle per head, and unrolled bodies a kernel —
     static per shape. The benchmark cells' call is resident at three bodies
-    and a non-causal 2 x 2 at four; longer heads, a bias, a call over the cap
-    and a causal rectangle stream."""
+    and a non-causal 2 x 2 at four, with or without dropout; a longer head
+    is listed (masks on the diagonal alone); a bias, dropout, a ring
+    block's partial rows, a causal rectangle and a tile narrower than the
+    128 lanes the listed kernels' statistics rows take stream."""
     from apex_tpu.ops.attention import _tile_plan
 
-    (sq, sk, causal, bound, has_bias), want = _PLANS[case]
+    (sq, sk, causal, bound, has_bias, dropout, whole), want = _PLANS[case]
     plan = _tile_plan(sq, sk, 64, jnp.bfloat16, causal, bound, bound,
-                      has_bias)
+                      has_bias, dropout=dropout, whole_rows=whole)
     assert (*plan, plan.bodies) == want
 
 
 @pytest.mark.parametrize("cap,cells,noncausal", [
-    (2, "streamed", "streamed"), (3, "resident", "streamed"),
+    (2, "listed", "listed"), (3, "resident", "listed"),
     (4, "resident", "resident")])
 def test_tile_plan_holds_the_cap_on_unrolled_bodies(monkeypatch, cap, cells,
                                                     noncausal):
     """``_RESIDENT_MAX_BODIES`` is the program-size budget: a kernel that
-    would unroll more tile bodies streams, whatever its kernel time."""
+    would unroll more tile bodies is listed, whatever its kernel time."""
     from apex_tpu.ops import attention
 
     assert attention._RESIDENT_MAX_BODIES == 4
     monkeypatch.setattr(attention, "_RESIDENT_MAX_BODIES", cap)
-    plan = attention._tile_plan(1024, 1024, 64, jnp.bfloat16, True)
+    plan = attention._tile_plan(1024, 1024, 64, jnp.bfloat16, True,
+                                whole_rows=True)
     assert plan.schedule == cells and plan.bodies <= cap
-    plan = attention._tile_plan(1024, 1024, 64, jnp.bfloat16, False)
+    plan = attention._tile_plan(1024, 1024, 64, jnp.bfloat16, False,
+                                whole_rows=True)
     assert plan.schedule == noncausal and plan.bodies <= cap
 
 
 @pytest.mark.parametrize("d,dtype,block,want", [
     (64, jnp.bfloat16, 512, "resident"),
     (128, jnp.float32, 512, "resident"),
-    (256, jnp.float32, 512, "streamed"),    # the head outgrows VMEM
-    (64, jnp.bfloat16, 1024, "streamed"),   # the score tile does
+    (256, jnp.float32, 512, "listed"),      # the head outgrows VMEM
+    (64, jnp.bfloat16, 1024, "listed"),     # the score tile does
     (64, jnp.bfloat16, 8, "streamed"),      # off bf16's 16-row tiling
 ])
 def test_tile_plan_vmem_budget_and_tiling(monkeypatch, d, dtype, block, want):
     """With the cap out of the way: a head, or one tile's scores, that does
-    not fit the VMEM budget streams, and so does a tile that does not sit on
-    the dtype's sublane tiling (the kernel slices whole operands by it)."""
+    not fit the VMEM budget is listed; a tile that does not sit on the
+    dtype's sublane tiling (the resident kernel slices whole operands by
+    it) streams, being narrower than the listed statistics' 128 lanes."""
     from apex_tpu.ops.attention import _tile_plan
 
     _force_schedule(monkeypatch, "resident")
     s = 1024 if block >= 512 else 16
-    assert _tile_plan(s, s, d, dtype, True, block, block).schedule == want
+    assert _tile_plan(s, s, d, dtype, True, block, block,
+                      whole_rows=True).schedule == want
 
 
 # ---------------------------------------------------------------------------
@@ -435,22 +459,24 @@ def _qkv_widths(key, b, h, s, d, dv, dtype=jnp.float32):
 
 
 @pytest.mark.parametrize("d,dv", [(192, 128), (24, 16), (16, 24)])
-@pytest.mark.parametrize("schedule", ["resident", "streamed"])
+@pytest.mark.parametrize("schedule", ["resident", "listed"])
 def test_value_width_of_its_own_matches_reference(monkeypatch, schedule, d, dv):
     """Causal, forward and all three gradients against the reference: the
     score's products run over q's width, ``p @ v`` and ``do @ v.T`` over
-    v's, on either schedule; the output and dv have v's width."""
+    v's, on either schedule; the output and dv have v's width. (The
+    streamed kernels at these widths: ``test_listed_matches_streamed``.)"""
     from apex_tpu.ops.attention import _tile_plan
 
     _force_schedule(monkeypatch, schedule)
-    b, h, s = 1, 2, 128
+    b, h, s = 1, 2, 256
     q, k, v = _qkv_widths(jax.random.PRNGKey(d + dv), b, h, s, d, dv)
-    assert _tile_plan(s, s, d, q.dtype, True, 64, 64).schedule == schedule
+    assert _tile_plan(s, s, d, q.dtype, True, 128, 128,
+                      whole_rows=True).schedule == schedule
     scale = 0.114721 if d == 192 else None        # the cell's: 192^-1/2 m^2
 
     def loss_flash(q, k, v):
         o = flash_attention(q, k, v, causal=True, use_pallas=True, scale=scale,
-                            block_q=64, block_k=64)
+                            block_q=128, block_k=128)
         return jnp.sum(jnp.sin(o)), o
 
     def loss_ref(q, k, v):
@@ -465,6 +491,90 @@ def test_value_width_of_its_own_matches_reference(monkeypatch, schedule, d, dv):
     for a, e, name in zip(g1, g2, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(e), atol=2e-4,
                                    err_msg=name)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("d,dv", [(32, 32), (48, 32)], ids=["d32", "d48-dv32"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "unmasked"])
+def test_listed_matches_streamed(monkeypatch, causal, d, dv, dtype):
+    """A head the resident schedule does not hold runs listed where it
+    streamed: the same tiles in the same order with the same arithmetic, 2
+    x 2 tiles a head here, keys wider than values as latent attention's.
+    On the CPU's interpreter the unmasked call's output and gradients are
+    the streamed kernels' bit for bit; the causal call's agree within four
+    of the dtype's rounding steps of the largest value, since a whole tile
+    skips the mask's select and its arithmetic fuses otherwise (a product
+    and a difference may contract into one rounding)."""
+    from apex_tpu.ops import attention
+
+    _force_schedule(monkeypatch, "listed")
+    b, h, s, block = 1, 2, 256, 128
+    q, k, v = _qkv_widths(jax.random.PRNGKey(d + causal), b, h, s, d, dv, dtype)
+    assert attention._tile_plan(s, s, d, dtype, causal, block, block,
+                                whole_rows=True).schedule == "listed"
+    scale = d ** -0.5
+
+    def listed(q, k, v):
+        return flash_attention(q, k, v, causal=causal, use_pallas=True,
+                               block_q=block, block_k=block)
+
+    def streamed(q, k, v):
+        o3 = attention._flash3(
+            q.reshape(b * h, s, d), k.reshape(b * h, s, d),
+            v.reshape(b * h, s, dv), jnp.zeros((1,), jnp.int32), scale,
+            causal, block, block, True, 0.0)
+        return o3.reshape(b, h, s, dv)
+
+    def value_and_grads(f):
+        w = jnp.cos(jnp.arange(s * dv, dtype=jnp.float32)).reshape(s, dv)
+        loss = lambda q, k, v: (jnp.sum(f(q, k, v).astype(jnp.float32) * w),
+                                f(q, k, v))
+        return jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+
+    g1, o1 = value_and_grads(listed)
+    g2, o2 = value_and_grads(streamed)
+    f32 = lambda x: np.asarray(x, np.float32)
+    eps = float(jnp.finfo(dtype).eps)
+    for a, e, name in zip((o1, *g1), (o2, *g2), ("o", "dq", "dk", "dv")):
+        assert a.shape == e.shape and a.dtype == e.dtype
+        if causal:
+            np.testing.assert_allclose(f32(a), f32(e), rtol=0,
+                                       atol=4 * eps * np.abs(f32(e)).max(),
+                                       err_msg=name)
+        else:
+            np.testing.assert_array_equal(f32(a), f32(e), err_msg=name)
+
+
+@pytest.mark.parametrize("seq,d,dv,visited", [(16384, 192, 128, 528), (8192, 128, 128, 136)],
+                         ids=["latent-attention", "hybrid"])
+def test_the_cells_causal_call_steps_its_live_tiles_alone(seq, d, dv, visited):
+    """The two cells that streamed (latent attention's 16 heads of 192 over
+    128 at 16,384 positions, the hybrid's full layer at 8,192) are listed:
+    ``visited`` is each kernel's grid steps a head, and every step is a tile
+    on or under the diagonal. (Traced, not run.)"""
+    from apex_tpu.ops.attention import CAUSAL, _listed_tiles, _tile_plan
+
+    plan = _tile_plan(seq, seq, d, jnp.bfloat16, True, whole_rows=True)
+    assert (plan.schedule, plan.block_q, plan.visited) == ("listed", 512, visited)
+    by_row, by_col = _listed_tiles(CAUSAL, seq, seq, 512, 512)
+    for table in (by_row, by_col):
+        assert table.shape == (3, visited) and np.all(table[1] <= table[0])
+    q = jax.ShapeDtypeStruct((1, 2, seq, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 2, seq, dv), jnp.bfloat16)
+    loss = lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True, use_pallas=True, interpret=True).astype(jnp.float32))
+    grids = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids[eqn.params["name"]] = tuple(eqn.params["grid_mapping"].grid)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, v).jaxpr)
+    assert grids == {"flash_fwd": (2, visited), "flash_bwd_dq": (2, visited),
+                     "flash_bwd_dkv": (2, visited, 1)}
 
 
 def test_value_width_of_its_own_in_bfloat16_and_on_the_listed_schedule():

@@ -113,11 +113,19 @@ def test_the_window_plan_at_8192_lists_the_live_tiles_alone(block, visited, mask
 
 @pytest.mark.parametrize("causal", [False, True])
 def test_causal_and_no_mask_keep_the_schedules_they_had(causal):
-    for shape in ((1024, 64), (8192, 128)):
-        as_bool = A._tile_plan(shape[0], shape[0], shape[1], jnp.bfloat16, causal)
-        as_structure = A._tile_plan(shape[0], shape[0], shape[1], jnp.bfloat16,
-                                    CAUSAL if causal else None)
-        assert as_bool == as_structure and as_bool.schedule != "listed"
+    """Either spelling gives one plan: resident where a head fits, listed
+    where it does not (as any structure's) and the caller owns whole rows,
+    streamed with a bias or as a ring block."""
+    for shape, schedule in (((1024, 64), "resident"), ((8192, 128), "listed")):
+        s, d = shape
+        as_bool = A._tile_plan(s, s, d, jnp.bfloat16, causal, whole_rows=True)
+        as_structure = A._tile_plan(s, s, d, jnp.bfloat16,
+                                    CAUSAL if causal else None, whole_rows=True)
+        assert as_bool == as_structure and as_bool.schedule == schedule
+        assert A._tile_plan(s, s, d, jnp.bfloat16, causal, has_bias=True,
+                            whole_rows=True).schedule == "streamed"
+        ring = A._tile_plan(s, s, d, jnp.bfloat16, causal).schedule
+        assert ring == ("resident" if schedule == "resident" else "streamed")
 
 
 def _value_and_grads(fn, q, k, v):
@@ -214,13 +222,15 @@ def test_causal_through_the_structure_is_bit_for_bit_todays(s, d):
 
 
 def test_the_listed_schedule_runs_causal_as_the_streamed_one_does():
-    """The same tiles in the same order with the same arithmetic: grouped
-    heads put a causal call on the listed kernels."""
+    """The same tiles in the same order with the same arithmetic: a causal
+    call's listed kernels against the streamed ones, which a bias, dropout
+    and the ring's partial rows keep."""
     q, k, v = _qkv(jax.random.PRNGKey(6), 1, 2, 2, 512, 16)
-    old = flash_attention(q, k, v, causal=True, block_q=64, block_k=64, use_pallas=True)
-    listed = A._flash3_listed(q.reshape(2, 512, 16), k.reshape(2, 512, 16),
-                              v.reshape(2, 512, 16), 0.25, CAUSAL, 64, 64, True)
-    np.testing.assert_allclose(listed.reshape(old.shape), old, atol=1e-6)
+    q3, k3, v3 = (a.reshape(2, 512, 16) for a in (q, k, v))
+    streamed = A._flash3(q3, k3, v3, jnp.zeros((1,), jnp.int32), 0.25, True, 64, 64,
+                         True, 0.0)
+    listed = A._flash3_listed(q3, k3, v3, 0.25, CAUSAL, 64, 64, True)
+    np.testing.assert_allclose(listed, streamed, atol=1e-6)
 
 
 def test_reference_takes_the_structure_as_its_dense_mask():

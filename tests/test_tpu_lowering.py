@@ -104,12 +104,12 @@ def test_flash_attention_unequal_blocks(qkv):
 
 
 @pytest.mark.parametrize("seq,schedule", [(S, "resident"),
-                                          (8192, "streamed")])
+                                          (8192, "listed")])
 def test_flash_attention_compiles_on_both_schedules(seq, schedule):
     """The rows above only *lower* the benchmark cells' shape (s 1024, d 64,
     bf16), which the tile plan runs resident: one whole head a grid step in
     VMEM, three tile bodies unrolled inside each kernel. Here that shape and
-    one too long to hold (streamed: q and K/V tiles on the grid) go through
+    one too long to hold (listed: the live tiles on the grid) go through
     Mosaic's own compiler for a described v5e, where a VMEM overflow or an
     unaligned slice of a resident operand would surface, and all three
     kernels must be in the compiled program once, under the names the trace
@@ -117,7 +117,8 @@ def test_flash_attention_compiles_on_both_schedules(seq, schedule):
     from apex_tpu.ops._pallas_util import compile_for_tpu, mosaic_calls
     from apex_tpu.ops.attention import _tile_plan
 
-    assert _tile_plan(seq, seq, D, jnp.bfloat16, True).schedule == schedule
+    assert _tile_plan(seq, seq, D, jnp.bfloat16, True,
+                      whole_rows=True).schedule == schedule
     q = jax.ShapeDtypeStruct((2, H, seq, D), jnp.bfloat16)
     _, compiled = compile_for_tpu(
         jax.jit(jax.grad(_flash_sq_loss, argnums=(0, 1, 2))), q, q, q)
@@ -195,19 +196,23 @@ def test_flash_attention_listed_schedule_compiles_at_the_window_cells_shape(kind
 
 
 @pytest.mark.parametrize("heads,seq,d,dv,schedule", [
-    (16, 16384, 192, 128, "streamed"), (4, 1024, 24, 16, "resident")])
+    (16, 16384, 192, 128, "listed"), (4, 1024, 24, 16, "resident")])
 def test_flash_attention_compiles_with_a_value_width_of_its_own(heads, seq, d, dv, schedule):
     """Keys of 192 over values of 128 at the latent-attention cell's shape
-    (16 heads, 16,384 positions, causal, bf16: the streamed schedule), and a
+    (16 heads, 16,384 positions, causal, bf16: the listed schedule), and a
     small pair on the resident one, through Mosaic's own compiler for a
     described v5e: 192 is not a multiple of the 128 lanes, and nothing is
     padded in HBM to make it one (q, k and their gradients keep 192, v, o and
     their gradients 128). All three kernels are in the compiled program once,
-    under the names the trace and ``mla_attn_roofline`` read."""
+    under the names the trace and ``mla_attn_roofline`` read. At the cell's
+    shape lse and delta are lane-dense rows: as (rows, 1) float32 columns
+    each would be padded to 128 lanes, 128 MiB (the streamed schedule's
+    program planned 640 MiB of temporaries, this one 384)."""
     from apex_tpu.ops._pallas_util import compile_for_tpu, mosaic_calls
     from apex_tpu.ops.attention import _tile_plan, flash_attention
 
-    assert _tile_plan(seq, seq, d, jnp.bfloat16, True).schedule == schedule
+    assert _tile_plan(seq, seq, d, jnp.bfloat16, True,
+                      whole_rows=True).schedule == schedule
 
     def loss(q, k, v):
         o = flash_attention(q, k, v, causal=True, scale=0.114721)
@@ -222,6 +227,7 @@ def test_flash_attention_compiles_with_a_value_width_of_its_own(heads, seq, d, d
         assert sum(n for name, n in calls.items() if kernel in name) == 1, (kernel, calls)
     dq, dk, dvv = jax.eval_shape(grad, q, q, v)
     assert dq.shape == dk.shape == q.shape and dvv.shape == v.shape
+    assert compiled.memory_analysis().temp_size_in_bytes < 448 * 2**20
 
 
 # Characters of the lowered text (StableHLO with the three kernels' Mosaic
