@@ -167,6 +167,30 @@ written out before the scanned expert layers; the same step, so ``opt`` and
     final_norm                  the RMSNorm after the stack
     lm_head_loss                untied head + cross entropy, fused
 
+Device scopes of SmallThinker's train step (``transformer/smallthinker.py``:
+full layers with no positional encoding among windowed rotary ones, every
+layer routed, the router reading the attention's normed input; the same
+step, so ``opt`` and ``scan_carry`` as above)::
+
+    embed                       token embedding (rows of the vocabulary held)
+    layer                       one layer (full or window), and in it
+    layer/pre_norm              the RMSNorm before each sublayer
+    layer/moe/route             the router, in the attention sublayer ahead
+                                of its projections: softmax, top-k and the
+                                weights from the attention's normed input
+    layer/swa/qkv  layer/swa/rope  layer/swa/core  layer/swa/out
+                                a window layer's attention, as above (no
+                                QK-norm, no gate)
+    layer/attn/qkv  layer/attn/core  layer/attn/out
+                                the full layer's (no rotation)
+    layer/moe/dispatch  layer/moe/experts  layer/moe/combine
+                                the routed ReLU-gated experts, as above
+                                (``layer/moe/route`` there holds only the
+                                barrier the routing given enters by)
+    layer/residual              the residual adds
+    final_norm                  the RMSNorm after the stack
+    lm_head_loss                untied head + cross entropy, fused
+
 **Counters of a routed layer** (``ROUTING_COUNTERS``; a step's, from the
 held experts' loads that the step itself returns, ``train_step_fn``'s fourth
 result, through ``transformer.moe.routing_facts``): ``pairs_held``

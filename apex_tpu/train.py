@@ -11,11 +11,12 @@ A model is any object with three methods:
   split over ``dp``).
 
 ``transformer.testing.GPTConfig``, ``transformer.hybrid.HybridConfig``,
-``transformer.sdar.SDARConfig`` and ``transformer.deepseek.DeepSeekConfig``
-are. A model that counts what its step did (a routed layer's loads, its
-balance loss) has a fourth, ``loss_and_counters(params, tokens, targets)``:
-the loss and a pytree of arrays whose leading axis stacks over ``dp`` (the
-block-diffusion and the latent-attention decoders have). Its step returns
+``transformer.sdar.SDARConfig``, ``transformer.deepseek.DeepSeekConfig``,
+``transformer.laguna.LagunaConfig`` and
+``transformer.smallthinker.SmallThinkerConfig`` are. A model that counts
+what its step did (a routed layer's loads, its balance loss) has a fourth,
+``loss_and_counters(params, tokens, targets)``: the loss and a pytree of
+arrays whose leading axis stacks over ``dp`` (every routed decoder has). Its step returns
 them fourth, from the step itself and at no pass of their own.
 
 Each call of the step is a host span ``train_step`` carrying its call index

@@ -38,7 +38,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +56,7 @@ from apex_tpu.ops.delta_rule import (
 )
 from apex_tpu.ops.layer_norm import rms_norm
 from apex_tpu.ops.lm_head_loss import lm_head_loss
+from apex_tpu.ops.rope import apply_rotary
 from apex_tpu.parallel.mesh import TP_AXIS
 
 Pytree = Any
@@ -243,6 +244,59 @@ def gated_ffn(x, w_gate, w_up, w_down, scope: str = "mlp"):
         y = (jax.nn.silu(gate.astype(F32)) * up.astype(F32)).astype(x.dtype)
     with span(scope + "/down"):
         return y @ w_down
+
+
+def grouped_attention(p, a, *, heads: int, kv_heads: int, head_dim: int,
+                      scope: str, structure, block: int = 512, rope=None,
+                      qk_norm_eps: Optional[float] = None, gate: bool = False):
+    """Softmax attention over ``a`` (rows, seq, hidden), a sublayer's normed
+    input: ``q = a W_q`` (``heads`` of ``head_dim``), ``k = a W_k``, ``v = a
+    W_v`` (``kv_heads``); with ``qk_norm_eps`` q and k RMS-normalised over
+    each head (``q_norm``, ``k_norm``); with ``rope = (theta, scaling,
+    rotary_dim)`` both rotated in ``rotate_half`` form (``ops/rope.py``;
+    ``rotary_dim`` None for the whole head), without it no positional
+    encoding; ``o = softmax(q k^T head_dim^-1/2 + mask) v`` on the flash
+    kernels, ``structure`` the mask (``ops.attention.CAUSAL``,
+    ``sliding_window_mask``) on tiles of ``block``, query head ``i`` reading
+    K/V head ``i // group`` (never repeated); with ``gate`` each head's
+    ``o`` times ``sigmoid(a W_g)``; then ``W_o``. Under the scopes
+    ``<scope>/{qkv, qk_norm, rope, core, gate, out}`` that run."""
+    b, s, _ = a.shape
+    n, kv, d = heads, kv_heads, head_dim
+    by_head = lambda t, m: t.reshape(b, s, m, d)
+    with span(scope + "/qkv"):
+        q, k, v = a @ p["wq"], a @ p["wk"], a @ p["wv"]
+        if qk_norm_eps is None:
+            q, k = by_head(q, n), by_head(k, kv)
+    if qk_norm_eps is not None:
+        with span(scope + "/qk_norm"):
+            # a head's width at a time: XLA's fusion, not the row kernel
+            q = rms_norm(by_head(q, n), p["q_norm"], qk_norm_eps,
+                         use_pallas=False)
+            k = rms_norm(by_head(k, kv), p["k_norm"], qk_norm_eps,
+                         use_pallas=False)
+    if rope is not None:
+        with span(scope + "/rope"):
+            theta, scaling, rotary_dim = rope
+            positions = jnp.arange(s, dtype=jnp.int32)
+            turn = lambda t: apply_rotary(t.transpose(0, 2, 1, 3), positions,
+                                          theta, scaling, False, rotary_dim)
+            q, k = turn(q), turn(k)
+    with span(scope + "/core"):
+        if rope is None:
+            q, k = q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)
+        v = by_head(v, kv).transpose(0, 2, 1, 3)
+        ctx = flash_attention(q, k, v, scale=d ** -0.5, block_q=block,
+                              block_k=block, structure=structure)
+    if gate:
+        with span(scope + "/gate"):
+            g = jax.nn.sigmoid((a @ p["wg"]).astype(F32))
+            ctx = (ctx.transpose(0, 2, 1, 3).astype(F32) * g[..., None]
+                   ).astype(a.dtype)
+    else:
+        ctx = ctx.transpose(0, 2, 1, 3)
+    with span(scope + "/out"):
+        return ctx.reshape(b, s, n * d) @ p["wo"]
 
 
 def _mlp(p, x):

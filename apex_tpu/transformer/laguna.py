@@ -52,14 +52,15 @@ from jax.sharding import PartitionSpec as P
 
 from apex_tpu.monitor.trace import span
 from apex_tpu.ops._pallas_util import pvary_like
-from apex_tpu.ops.attention import flash_attention, sliding_window_mask
+from apex_tpu.ops.attention import CAUSAL, sliding_window_mask
 from apex_tpu.ops.layer_norm import rms_norm
 from apex_tpu.ops.lm_head_loss import lm_head_loss
-from apex_tpu.ops.rope import RopeScaling, apply_rotary
+from apex_tpu.ops.rope import RopeScaling
 from apex_tpu.transformer.hybrid import (
     ATTN_KEPT,
     append_row,
     gated_ffn,
+    grouped_attention,
     refuse_tp,
     run_stack,
     shortest_period,
@@ -237,39 +238,17 @@ def init_laguna_params(rng, cfg: LagunaConfig) -> Pytree:
 # forward (local shards, inside shard_map)
 
 def _attention(p, a, cfg: LagunaConfig, kind: str):
-    b, s, _ = a.shape
-    n, kv, d = cfg.heads(kind), cfg.num_kv_heads, cfg.head_dim
     theta, scaling, fraction = cfg.rope(kind)
-    rotary_dim = int(d * fraction)
-    scope = "swa" if kind == SLIDING else "attn"
-    heads = lambda t, m: t.reshape(b, s, m, d)
-    with span(scope + "/qkv"):
-        q, k, v = a @ p["wq"], a @ p["wk"], a @ p["wv"]
-    with span(scope + "/qk_norm"):
-        # a head's 128 at a time: XLA's fusion, not the row kernel (sdar.py)
-        q = rms_norm(heads(q, n), p["q_norm"], cfg.norm_eps, use_pallas=False)
-        k = rms_norm(heads(k, kv), p["k_norm"], cfg.norm_eps, use_pallas=False)
-    with span(scope + "/rope"):
-        positions = jnp.arange(s, dtype=jnp.int32)
-        turn = lambda t: apply_rotary(
-            t.transpose(0, 2, 1, 3), positions, theta, scaling, False,
-            None if rotary_dim == d else rotary_dim)
-        q, k = turn(q), turn(k)
-    with span(scope + "/core"):
-        v = heads(v, kv).transpose(0, 2, 1, 3)
-        if kind == SLIDING:
-            ctx = flash_attention(
-                q, k, v, scale=d ** -0.5, block_q=WINDOW_BLOCK,
-                block_k=WINDOW_BLOCK,
-                structure=sliding_window_mask(cfg.sliding_window))
-        else:
-            ctx = flash_attention(q, k, v, causal=True, scale=d ** -0.5)
-    with span(scope + "/gate"):
-        g = jax.nn.sigmoid((a @ p["wg"]).astype(F32))
-        ctx = (ctx.transpose(0, 2, 1, 3).astype(F32) * g[..., None]
-               ).astype(a.dtype)
-    with span(scope + "/out"):
-        return ctx.reshape(b, s, n * d) @ p["wo"]
+    rotary_dim = int(cfg.head_dim * fraction)
+    window = kind == SLIDING
+    return grouped_attention(
+        p, a, heads=cfg.heads(kind), kv_heads=cfg.num_kv_heads,
+        head_dim=cfg.head_dim, scope="swa" if window else "attn",
+        structure=sliding_window_mask(cfg.sliding_window) if window else CAUSAL,
+        block=WINDOW_BLOCK if window else 512,
+        rope=(theta, scaling,
+              None if rotary_dim == cfg.head_dim else rotary_dim),
+        qk_norm_eps=cfg.norm_eps, gate=True)
 
 
 def _attention_sublayer(p, x, cfg: LagunaConfig, kind: str):

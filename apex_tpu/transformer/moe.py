@@ -31,12 +31,14 @@ That is :func:`moe_mlp` (top-2, GELU experts, a capacity
 ``standalone_gpt.py`` runs. Beside it :func:`routed_experts_mlp` is the layer
 of the softmax-routed models with many small experts
 (``transformer/sdar.py``, ``transformer/deepseek.py``,
-``transformer/laguna.py``): **no capacity and no
-drops**, SiLU-gated experts, and the layer told which of the router's experts
-it holds (``experts_held``): one chip's share of an expert-parallel
-deployment, computed without the exchange. Two fields of
-:class:`RoutedExpertsConfig` say what a chosen expert's weight is, each what
-the published key of its name says: ``norm_topk_prob`` (true: the chosen
+``transformer/laguna.py``, ``transformer/smallthinker.py``): **no capacity
+and no drops**, gated experts (SiLU, or ReLU where the caller says so), and
+the layer told which of the router's experts it holds (``experts_held``):
+one chip's share of an expert-parallel deployment, computed without the
+exchange. The routing is the layer's own, from its input, or the caller's,
+from another tensor (SmallThinker routes the attention's normed input). Two
+fields of :class:`RoutedExpertsConfig` say what a chosen expert's weight is,
+each what the published key of its name says: ``norm_topk_prob`` (true: the chosen
 scores divided by their sum, the block-diffusion decoder's Qwen3-style
 router; false: the scores as they are, DeepSeek-V2) and
 ``routed_scaling_factor`` (the weights times it; DeepSeek-V2-Lite publishes
@@ -560,9 +562,17 @@ def _layout(key, count: int, tile: int):
 
 
 def routed_experts_mlp(p, x, cfg: RoutedExpertsConfig,
-                       experts_held: Tuple[int, int]):
+                       experts_held: Tuple[int, int], routing=None,
+                       activation=jax.nn.silu):
     """``sum over the chosen experts held here of w_i E_i(x)`` over ``x``
-    (..., hidden), ``E(x) = (SiLU(x W_gate) * (x W_up)) W_down``.
+    (..., hidden), ``E(x) = (act(x W_gate) * (x W_up)) W_down``, ``act``
+    the ``activation`` (SiLU; ``jax.nn.relu`` for ReGLU experts).
+
+    ``routing``: ``None`` routes ``x`` itself (:func:`route_softmax_top_k`
+    over ``p["router"]``); else ``(idx, weight)`` (..., top_k) as that
+    function hands them out for the same positions from another tensor,
+    decided by the caller (a router that reads the layer's input before
+    attention). The weights' cotangent then leaves through ``routing``.
 
     ``p``: :func:`routed_expert_shapes`' leaves. ``experts_held = (first,
     count)``: the experts ``first .. first + count - 1`` of the router's
@@ -587,8 +597,9 @@ def routed_experts_mlp(p, x, cfg: RoutedExpertsConfig,
     hold exactly 0 .. top_k pairs with a row in the first pass: what the
     gathers of :func:`_sum_rows` follow. ``routed`` is the router's own
     result over the flattened positions: ``routed["scores"]`` (tokens,
-    num_experts) float32 and ``routed["idx"]`` (tokens, top_k), for a
-    balance loss (:func:`sequence_balance_loss`)."""
+    num_experts) float32 (``None`` where ``routing`` was given) and
+    ``routed["idx"]`` (tokens, top_k), for a balance loss
+    (:func:`sequence_balance_loss`)."""
     lead, h = x.shape[:-1], x.shape[-1]
     xf = x.reshape(-1, h)
     t, k = xf.shape[0], cfg.top_k
@@ -602,11 +613,19 @@ def routed_experts_mlp(p, x, cfg: RoutedExpertsConfig,
         # follows in the backward and holds the buffer's rows, their
         # cotangents and the later passes' results (1.9 GB at the benchmark's
         # cell) across it, then rematerialises that sublayer to fit
-        p, xf = lax.optimization_barrier((
-            {name: p[name] for name in ("router", "w_gate", "w_up", "w_down")},
-            xf))
-        idx, weight, scores = route_softmax_top_k(
-            xf, p["router"], k, cfg.norm_topk_prob, cfg.routed_scaling_factor)
+        if routing is None:
+            p, xf = lax.optimization_barrier((
+                {name: p[name] for name in ("router", "w_gate", "w_up",
+                                            "w_down")}, xf))
+            idx, weight, scores = route_softmax_top_k(
+                xf, p["router"], k, cfg.norm_topk_prob,
+                cfg.routed_scaling_factor)
+        else:
+            idx, weight = (r.reshape(t, k) for r in routing)
+            p, xf, weight = lax.optimization_barrier((
+                {name: p[name] for name in ("w_gate", "w_up", "w_down")}, xf,
+                weight))
+            scores = None
     with span("moe/dispatch"):
         local = idx - first
         key = jnp.where((local >= 0) & (local < count), local, count
@@ -637,7 +656,7 @@ def routed_experts_mlp(p, x, cfg: RoutedExpertsConfig,
         with span("moe/experts"):
             gate = grouped_matmul(xs, p["w_gate"], rows, tile)
             up = grouped_matmul(xs, p["w_up"], rows, tile)
-            act = (jax.nn.silu(gate.astype(F32)) * up.astype(F32)
+            act = (activation(gate.astype(F32)) * up.astype(F32)
                    ).astype(x.dtype)
             ys = grouped_matmul(act, p["w_down"], rows, tile)
         with span("moe/combine"):

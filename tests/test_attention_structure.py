@@ -157,13 +157,16 @@ def test_listed_kernels_match_the_dense_mask_forward_and_backward(structure, gro
         np.testing.assert_allclose(got, want, atol=5e-5)
 
 
-@pytest.mark.parametrize("window,heads,kv", [(100, 9, 1), (64, 18, 2), (100, 6, 1), (64, 12, 2)],
-                         ids=["w100-group9", "w64-group9", "w100-group6", "w64-group6"])
+@pytest.mark.parametrize("window,heads,kv", [(100, 9, 1), (64, 18, 2), (100, 6, 1), (64, 12, 2),
+                                             (256, 7, 1)],
+                         ids=["w100-group9", "w64-group9", "w100-group6", "w64-group6",
+                              "w256-group7"])
 def test_listed_kernels_match_the_window_forward_and_backward(window, heads, kv):
     """Laguna's groups (72 over 8 on sliding layers, 48 over 8 on full ones)
     at s 512 on 64 x 64 tiles: a window of 64 lies on the tiles' edges, one
-    of 100 straddles them; the output and dq, dk, dv against the dense
-    reference."""
+    of 100 straddles them; SmallThinker's group of 7 with the window four
+    tiles wide (4,096 on its 1,024 x 1,024 tiles); the output and dq, dk, dv
+    against the dense reference."""
     structure = sliding_window_mask(window)
     q, k, v = _qkv(jax.random.PRNGKey(window + heads), 1, heads, kv, 512, 32)
     flash = lambda q, k, v: flash_attention(q, k, v, structure=structure, block_q=64,

@@ -536,3 +536,49 @@ def test_the_balance_loss_by_hand_and_its_gradient_goes_through_p_only():
     np.testing.assert_allclose(np.asarray(g).reshape(rows, length, E),
                                np.broadcast_to(alpha * f[:, None, :] / (length * rows),
                                                (rows, length, E)), rtol=1e-5)
+
+
+@pytest.mark.parametrize("first,count,activation", [(0, 8, "silu"), (2, 3, "relu")])
+def test_routing_given_from_outside_equals_the_layers_own(first, count, activation):
+    """The routing handed in as ``(idx, weight)`` from the same tensor the
+    layer would route: the layer's output and the gradients of its leaves
+    and of its input equal the layer routing itself; the router's gradient
+    leaves through the weights handed in."""
+    p, x = _params(count, key=5), _x(6)
+    act = getattr(jax.nn, activation)
+
+    def own(p, x):
+        return jnp.sum(jnp.sin(routed_experts_mlp(p, x, _cfg(), (first, count),
+                                                  activation=act)[0]))
+
+    def given(p, x):
+        idx, weight, _ = route_softmax_top_k(x, p["router"], K)
+        y, _, routed = routed_experts_mlp(p, x, _cfg(), (first, count), (idx, weight), act)
+        assert routed["scores"] is None
+        return jnp.sum(jnp.sin(y))
+
+    with jax.default_matmul_precision("highest"):
+        a, ga = jax.value_and_grad(own, argnums=(0, 1))(p, x)
+        b, gb = jax.value_and_grad(given, argnums=(0, 1))(p, x)
+    np.testing.assert_allclose(a, b, rtol=1e-6)
+    for u, v in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
+        np.testing.assert_allclose(u, v, atol=1e-6 * max(float(jnp.abs(u).max()), 1.0))
+    assert float(jnp.abs(gb[0]["router"]).max()) > 0
+
+
+@pytest.mark.parametrize("first,count", [(0, 8), (2, 3)])
+def test_the_relu_activation_against_a_plain_product(first, count):
+    """``activation=jax.nn.relu``: ReGLU experts, ``(ReLU(x W_gate) * x
+    W_up) W_down``, against the dense loop written with ``max(., 0)``; the
+    routing taken from another tensor than the experts' input."""
+    p, x, r = _params(count, key=7), _x(8), _x(9)
+    with jax.default_matmul_precision("highest"):
+        idx, weight, _ = route_softmax_top_k(r, p["router"], K)
+        got = routed_experts_mlp(p, x, _cfg(), (first, count), (idx, weight), jax.nn.relu)[0]
+        want = jnp.zeros_like(x)
+        for e in range(count):
+            we = jnp.sum(jnp.where(idx == first + e, weight, 0.0), axis=-1)
+            out = (jnp.maximum(x @ p["w_gate"][e], 0.0) * (x @ p["w_up"][e])) @ p["w_down"][e]
+            want = want + we[:, None] * out
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    assert float(jnp.abs(want).max()) > 0.1

@@ -195,6 +195,34 @@ def test_flash_attention_listed_schedule_compiles_at_the_window_cells_shape(kind
         assert sum(c for call, c in calls.items() if name in call) == 1, (name, calls)
 
 
+def test_flash_attention_listed_schedule_compiles_at_smallthinkers_window():
+    """SmallThinker's window layers at their cell's shape: 28 query heads
+    over 4 key/value heads of 128, 16,384 positions, a window of 4,096, bf16,
+    on the program's tile (``smallthinker.WINDOW_BLOCK``): 252 live tiles a
+    head at 512 x 512 of the 1,024, 56 of them masked; the three kernels
+    compile for a described v5e, once each."""
+    from apex_tpu.ops._pallas_util import compile_for_tpu, mosaic_calls
+    from apex_tpu.ops.attention import _tile_plan, flash_attention, sliding_window_mask
+    from apex_tpu.transformer.smallthinker import WINDOW_BLOCK
+
+    block, mask = WINDOW_BLOCK, sliding_window_mask(4096)
+    plan = _tile_plan(16384, 16384, 128, jnp.bfloat16, mask, block, block, group=7)
+    want = {512: (252, 56), 1024: (70, 28)}[block]
+    assert (plan.schedule, (plan.visited, plan.masked)) == ("listed", want)
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, structure=mask, block_q=block, block_k=block,
+                            scale=128 ** -0.5)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    q = jax.ShapeDtypeStruct((1, 28, 16384, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 4, 16384, 128), jnp.bfloat16)
+    _, compiled = compile_for_tpu(jax.jit(jax.grad(loss, argnums=(0, 1, 2))), q, kv, kv)
+    calls = mosaic_calls(compiled.as_text())
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert sum(c for call, c in calls.items() if name in call) == 1, (name, calls)
+
+
 @pytest.mark.parametrize("heads,seq,d,dv,schedule", [
     (16, 16384, 192, 128, "listed"), (4, 1024, 24, 16, "resident")])
 def test_flash_attention_compiles_with_a_value_width_of_its_own(heads, seq, d, dv, schedule):
