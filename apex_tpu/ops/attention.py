@@ -353,6 +353,23 @@ _RESIDENT_MAX_BODIES = 4
 # scoped limit is 16 MiB.
 _RESIDENT_VMEM_BYTES = 14 * 1024 * 1024
 
+# The listed kernels' time a head, forward and backward, as two terms: a grid
+# step of the three kernels, and a pair of positions a tile computes at keys
+# and values of 128 (scaled by (d + d_v) / 256). Fitted on the v5e to a
+# forward and backward timed alone at each tile below and 256 x 256, at the
+# window (4,096), causal, block-diffusion and latent-attention cells' shapes
+# (``benchmarks/flash_window_tpu.py``, 18 timings, each within 21%; PERF.md
+# section 6). Of those tiles the least cost is the fastest timed in each call.
+_STEP_US = 1.977
+_PAIR_US = 1.453e-5
+
+# The tiles a listed call weighs when its caller leaves the tile to the plan.
+_LISTED_TILES = ((512, 512), (1024, 512), (512, 1024), (1024, 1024))
+
+# VMEM the listed kernels may plan for: XLA's default scoped limit, which
+# ``_listed_params`` leaves in place.
+_LISTED_VMEM_BYTES = 16 * 1024 * 1024
+
 
 class TilePlan(NamedTuple):
     """What a call's kernels run, static per shape. ``visited`` / ``masked``
@@ -418,9 +435,47 @@ def _listed_plan(structure, sq, sk, bq, bk) -> TilePlan:
                     int(np.sum(by_row[2] & 4 != 0)), (sq // bq) * (sk // bk))
 
 
+def _listed_vmem(bq, bk, d, dv, itemsize) -> int:
+    """VMEM the widest listed kernel (dK/dV) plans for, an upper bound: its
+    q, dO, K and V blocks and dK, dV results at 128-lane widths, its fp32
+    accumulators, and two fp32 score-sized temporaries of one tile.
+    Compiled for a described v5e the three kernels need, in MiB (this count
+    in brackets): 3.2 (3.25) at 512 x 512 and widths of 128, 9.9 (10.5) at
+    1,024 x 1,024, 9.6 (11.75) there at keys of 192 over 128, 15.5 (19.25)
+    at 576 over 512, 18.0 (19) at 2,048 x 1,024 and 128."""
+    width = -(-d // 128) * 128 + -(-dv // 128) * 128
+    return ((bq + 2 * bk) * width * itemsize + bk * width * 4
+            + 2 * bq * bk * 4)
+
+
+def _listed_tile(structure, sq, sk, d, dv, dtype, block_q, block_k):
+    """The listed schedule's tile. A number bounds it from above (the widest
+    divisor under it); ``None`` for both leaves it to the fitted cost: of
+    ``_LISTED_TILES``, those that divide the sequence, that the structure
+    can carry and whose kernels fit ``_LISTED_VMEM_BYTES``, the one of least
+    grid steps x ``_STEP_US`` + pairs computed x ``_PAIR_US``; 512 x 512's
+    divisors where none qualifies."""
+    if block_q is not None or block_k is not None:
+        return (_pick_block(sq, 512 if block_q is None else block_q),
+                _pick_block(sk, 512 if block_k is None else block_k))
+    itemsize = jnp.dtype(dtype).itemsize
+    pair = _PAIR_US * (d + dv) / 256
+    costs = []
+    for bq, bk in _LISTED_TILES:
+        if (sq % bq or sk % bk
+                or (structure is not None and not structure.tiles(sq, sk, bq, bk))
+                or _listed_vmem(bq, bk, d, dv, itemsize) > _LISTED_VMEM_BYTES):
+            continue
+        steps = _listed_tiles(structure, sq, sk, bq, bk)[0].shape[1]
+        costs.append((steps * (_STEP_US + bq * bk * pair), bq, bk))
+    if not costs:
+        return _pick_block(sq, 512), _pick_block(sk, 512)
+    return min(costs)[1:]
+
+
 def _tile_plan(sq, sk, d, dtype, causal, block_q=512, block_k=512,
                has_bias=False, group=1, dropout=False,
-               whole_rows=False) -> TilePlan:
+               whole_rows=False, dv=None) -> TilePlan:
     """The one place the schedule and the tile are chosen, from what the call
     can observe. ``causal`` is the mask's structure: a bool as ever, or a
     :class:`MaskStructure` (``True`` is ``CAUSAL``). ``group``: query heads a
@@ -428,13 +483,20 @@ def _tile_plan(sq, sk, d, dtype, causal, block_q=512, block_k=512,
     ``whole_rows``: the caller owns each row whole (``flash_attention``);
     left False, the call may be a block of ring attention's, whose rows may
     see no key and whose lse is merged as a column, as ``_fa_fwd`` and
-    ``_fa_bwd`` serve. ``block_q`` / ``block_k`` bound the tile from above;
-    the widest divisor under them is taken on every schedule."""
+    ``_fa_bwd`` serve. ``dv``: the value width (``d`` when None).
+    ``block_q`` / ``block_k`` bound the tile from above; the widest divisor
+    under them is taken on every schedule. ``None`` leaves the tile to the
+    plan: 512 on the resident and streamed schedules, the listed one's
+    fitted cost on the listed (``_listed_tile``)."""
     structure = _as_structure(causal)
-    bq, bk = _pick_block(sq, block_q), _pick_block(sk, block_k)
+    dv = d if dv is None else dv
+    bq = _pick_block(sq, 512 if block_q is None else block_q)
+    bk = _pick_block(sk, 512 if block_k is None else block_k)
     nq, nk = sq // bq, sk // bk
+    listed = lambda: _listed_plan(structure, sq, sk, *_listed_tile(
+        structure, sq, sk, d, dv, dtype, block_q, block_k))
     if group > 1 or structure not in (None, CAUSAL):
-        return _listed_plan(structure, sq, sk, bq, bk)
+        return listed()
     causal = structure is not None
     kinds = [_tile_kind(causal, i, j, bq, bk)
              for i in range(nq) for j in range(nk)]
@@ -464,7 +526,7 @@ def _tile_plan(sq, sk, d, dtype, causal, block_q=512, block_k=512,
         # block of whole 128 lanes, or the whole sequence
         or (bq % 128 and bq != sq))
     if not streamed:
-        return _listed_plan(structure, sq, sk, bq, bk)
+        return listed()
     # the streamed kernels build the causal mask on every tile they compute
     return TilePlan("streamed", bq, bk, visited, visited if causal else 0,
                     nq * nk)
@@ -1538,12 +1600,15 @@ def _flash_listed(q, k, v, mask, structure, scale, block_q, block_k,
             "attention dropout and the additive bias are not written for "
             "the listed schedule (a MaskStructure other than causal, or "
             "K/V with fewer heads than Q)")
-    bq, bk = _pick_block(sq, block_q), _pick_block(sk, block_k)
     dv = v.shape[3]
+    bq = bk = None
     tiles = (mask is None and _pick_block(sq, 128) is not None
              and _pick_block(sk, 128) is not None and d % 8 == 0
-             and dv % 8 == 0
-             and (structure is None or structure.tiles(sq, sk, bq, bk)))
+             and dv % 8 == 0)
+    if tiles:
+        bq, bk = _listed_tile(structure, sq, sk, d, dv, q.dtype, block_q,
+                              block_k)
+        tiles = structure is None or structure.tiles(sq, sk, bq, bk)
     if use_pallas is None:
         use_pallas = tiles and _compiled_backend()
     elif use_pallas and not tiles:
@@ -1589,8 +1654,8 @@ def flash_attention(
     mask=None,
     causal: bool = False,
     scale: Optional[float] = None,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     use_pallas: Optional[bool] = None,
     dropout_rate: float = 0.0,
     dropout_seed=None,
@@ -1615,6 +1680,10 @@ def flash_attention(
     edges; a bias or dropout keeps such a call streamed. An arbitrary
     dense ``mask`` (True = masked out) or an odd shape takes the XLA
     reference path, which materialises the scores.
+
+    ``block_q`` / ``block_k``: numbers bound the tile from above; ``None``
+    leaves it to the plan (``_tile_plan``): 512 on the resident and
+    streamed schedules, on the listed one the tile of least fitted cost.
 
     ``k`` and ``v`` may have fewer heads than ``q`` (a divisor of its head
     count): query head ``i`` reads K/V head ``i // group``. On the kernels
@@ -1697,7 +1766,7 @@ def flash_attention(
                                    dropout_keep=keep, bias=bias)
     plan = _tile_plan(sq, sk, d, q.dtype, causal, block_q, block_k,
                       has_bias=bias is not None, dropout=dropout_rate > 0.0,
-                      whole_rows=True)
+                      whole_rows=True, dv=dv)
     bq, bk = plan.block_q, plan.block_k
     if interpret is None:
         interpret = not _compiled_backend()

@@ -545,18 +545,20 @@ def test_listed_matches_streamed(monkeypatch, causal, d, dv, dtype):
             np.testing.assert_array_equal(f32(a), f32(e), err_msg=name)
 
 
-@pytest.mark.parametrize("seq,d,dv,visited", [(16384, 192, 128, 528), (8192, 128, 128, 136)],
+@pytest.mark.parametrize("seq,d,dv,visited", [(16384, 192, 128, 136), (8192, 128, 128, 36)],
                          ids=["latent-attention", "hybrid"])
 def test_the_cells_causal_call_steps_its_live_tiles_alone(seq, d, dv, visited):
     """The two cells that streamed (latent attention's 16 heads of 192 over
-    128 at 16,384 positions, the hybrid's full layer at 8,192) are listed:
-    ``visited`` is each kernel's grid steps a head, and every step is a tile
-    on or under the diagonal. (Traced, not run.)"""
+    128 at 16,384 positions, the hybrid's full layer at 8,192) are listed,
+    on the 1,024 x 1,024 tiles the plan picks when the call leaves the tile
+    to it: ``visited`` is each kernel's grid steps a head, and every step is
+    a tile on or under the diagonal. (Traced, not run.)"""
     from apex_tpu.ops.attention import CAUSAL, _listed_tiles, _tile_plan
 
-    plan = _tile_plan(seq, seq, d, jnp.bfloat16, True, whole_rows=True)
-    assert (plan.schedule, plan.block_q, plan.visited) == ("listed", 512, visited)
-    by_row, by_col = _listed_tiles(CAUSAL, seq, seq, 512, 512)
+    plan = _tile_plan(seq, seq, d, jnp.bfloat16, True, None, None, whole_rows=True, dv=dv)
+    assert (plan.schedule, plan.block_q, plan.block_k, plan.visited) == (
+        "listed", 1024, 1024, visited)
+    by_row, by_col = _listed_tiles(CAUSAL, seq, seq, 1024, 1024)
     for table in (by_row, by_col):
         assert table.shape == (3, visited) and np.all(table[1] <= table[0])
     q = jax.ShapeDtypeStruct((1, 2, seq, d), jnp.bfloat16)

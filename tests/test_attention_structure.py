@@ -128,6 +128,75 @@ def test_causal_and_no_mask_keep_the_schedules_they_had(causal):
         assert ring == ("resident" if schedule == "resident" else "streamed")
 
 
+@pytest.mark.parametrize("seq,d,dv,structure,kw,want", [
+    (16384, 128, 128, block_diffusion_mask(4), dict(group=8), ("listed", 1024, 1024, 80)),
+    (16384, 192, 128, CAUSAL, dict(whole_rows=True), ("listed", 1024, 1024, 136)),
+    (16384, 576, 512, CAUSAL, dict(whole_rows=True), ("listed", 512, 1024, 272)),
+    (8192, 128, 128, CAUSAL, dict(whole_rows=True), ("listed", 1024, 1024, 36)),
+    (8192, 128, 128, sliding_window_mask(512), dict(group=9), ("listed", 512, 512, 31)),
+    (16384, 128, 128, sliding_window_mask(4096), dict(group=7), ("listed", 1024, 1024, 70)),
+    (1024, 64, 64, CAUSAL, dict(whole_rows=True), ("resident", 512, 512, 3)),
+    (8192, 128, 128, CAUSAL, dict(), ("streamed", 512, 512, 136)),
+    (8192, 128, 128, CAUSAL, dict(whole_rows=True, has_bias=True), ("streamed", 512, 512, 136)),
+    (8192, 128, 128, CAUSAL, dict(whole_rows=True, dropout=True), ("streamed", 512, 512, 136)),
+], ids=["blockdiff-16k-group8", "causal-16k-keys192", "causal-16k-keys576-vmem", "causal-8k",
+        "window512-8k", "window4096-16k", "gpt2-resident", "ring-block", "bias", "dropout"])
+def test_the_plan_picks_the_tile_a_call_leaves_to_it(seq, d, dv, structure, kw, want):
+    """``block_q = block_k = None``: on the listed schedule the candidate of
+    least fitted cost (steps x ``_STEP_US`` + pairs x ``_PAIR_US``) whose
+    kernels ``_listed_vmem`` counts inside the scoped VMEM (at keys of 576
+    over 512 it counts the square over: a rectangle); 512 where Laguna's window of 512 would compute twice
+    the pairs at 1,024 (PERF.md section 5); 512 as ever on the resident and
+    streamed schedules (GPT-2's call, a ring block, a bias, dropout).
+    (Counted, not run.)"""
+    plan = A._tile_plan(seq, seq, d, jnp.bfloat16, structure, None, None, dv=dv, **kw)
+    assert (plan.schedule, plan.block_q, plan.block_k, plan.visited) == want
+    assert A._listed_vmem(plan.block_q, plan.block_k, d, dv, 2) <= A._LISTED_VMEM_BYTES
+
+
+def _grids(fn, *args):
+    """Each pallas_call's grid in ``fn``'s jaxpr, by kernel name."""
+    grids = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids[eqn.params["name"]] = tuple(eqn.params["grid_mapping"].grid)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return grids
+
+
+@pytest.mark.parametrize("structure,heads,kv,d,dv,steps", [
+    (CAUSAL, 2, 2, 32, 16, 3), (block_diffusion_mask(4), 4, 2, 32, 32, 3)],
+    ids=["causal-value-width", "blockdiff-group2"])
+def test_the_plans_1024_tiles_match_the_dense_reference(structure, heads, kv, d, dv, steps):
+    """s 2,048, so a 1,024 tile is two a side: causal with keys wider than
+    values, and block diffusion with grouped heads, on the tile the plan
+    picks when the call leaves it; the output and dq, dk, dv against the
+    dense reference within the 512-tile tests' tolerances."""
+    s = 2048
+    plan = A._tile_plan(s, s, d, jnp.float32, structure, None, None, group=heads // kv,
+                        whole_rows=True, dv=dv)
+    assert (plan.schedule, plan.block_q, plan.block_k, plan.visited) == (
+        "listed", 1024, 1024, steps)
+    q, k, _ = _qkv(jax.random.PRNGKey(heads + d), 1, heads, kv, s, d)
+    v = jax.random.normal(jax.random.PRNGKey(dv), (1, kv, s, dv), jnp.float32)
+    flash = lambda q, k, v: flash_attention(q, k, v, structure=structure, use_pallas=True)
+    dense = lambda q, k, v: attention_reference(q, k, v, structure=structure)
+    grids = _grids(jax.grad(lambda *a: jnp.sum(flash(*a)), argnums=(0, 1, 2)), q, k, v)
+    assert {name: grid[1] for name, grid in grids.items()} == dict.fromkeys(
+        ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"), steps)
+    o, grads = _value_and_grads(flash, q, k, v)
+    o_ref, grads_ref = _value_and_grads(dense, q, k, v)
+    np.testing.assert_allclose(o, o_ref, atol=2e-5)
+    for got, want in zip(grads, grads_ref):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(got, want, atol=5e-5)
+
+
 def _value_and_grads(fn, q, k, v):
     def loss(q, k, v):
         o = fn(q, k, v)

@@ -134,11 +134,14 @@ def test_flash_attention_listed_schedule_compiles_at_the_block_diffusion_cells_s
         structure, visited):
     """The listed schedule (a ``MaskStructure`` other than causal, or grouped
     heads) at the block-diffusion cell's attention shape: 32 query heads over
-    4 key/value heads of 128, 16,384 positions, bf16. The three kernels go
-    through Mosaic's own compiler for a described v5e (scalar-prefetched tile
-    tables, the lane-dense lse and its in-kernel transposes, dK and dV summed
-    over a group) under the names the trace and ``blockdiff_attn_roofline``
-    read; lse and delta cost 32 MiB, not the GiB a column layout pads to."""
+    4 key/value heads of 128, 16,384 positions, bf16, on the 1,024 x 1,024
+    tiles the plan picks when the call leaves the tile to it (80 and 136 live
+    tiles a head, where 512 x 512 has ``visited``). The three
+    kernels go through Mosaic's own compiler for a described v5e
+    (scalar-prefetched tile tables, the lane-dense lse and its in-kernel
+    transposes, dK and dV summed over a group) under the names the trace and
+    ``blockdiff_attn_roofline`` read; lse and delta cost 32 MiB, not the GiB
+    a column layout pads to."""
     from apex_tpu.ops._pallas_util import compile_for_tpu, mosaic_calls
     from apex_tpu.ops.attention import (CAUSAL, _tile_plan, block_diffusion_mask,
                                         flash_attention)
@@ -146,6 +149,9 @@ def test_flash_attention_listed_schedule_compiles_at_the_block_diffusion_cells_s
     mask = block_diffusion_mask(4) if structure == "blockdiff" else CAUSAL
     plan = _tile_plan(16384, 16384, 128, jnp.bfloat16, mask, group=8)
     assert (plan.schedule, plan.visited) == ("listed", visited)
+    plan = _tile_plan(16384, 16384, 128, jnp.bfloat16, mask, None, None, group=8)
+    assert (plan.schedule, plan.block_q, plan.block_k, plan.visited) == (
+        "listed", 1024, 1024, {288: 80, 528: 136}[visited])
 
     def loss(q, k, v):
         return jnp.sum(flash_attention(q, k, v, structure=mask).astype(jnp.float32) ** 2)
@@ -227,8 +233,10 @@ def test_flash_attention_listed_schedule_compiles_at_smallthinkers_window():
     (16, 16384, 192, 128, "listed"), (4, 1024, 24, 16, "resident")])
 def test_flash_attention_compiles_with_a_value_width_of_its_own(heads, seq, d, dv, schedule):
     """Keys of 192 over values of 128 at the latent-attention cell's shape
-    (16 heads, 16,384 positions, causal, bf16: the listed schedule), and a
-    small pair on the resident one, through Mosaic's own compiler for a
+    (16 heads, 16,384 positions, causal, bf16: the listed schedule, on the
+    1,024 x 1,024 tiles the plan picks when the call leaves the tile to it,
+    whose kernels it counts inside the scoped VMEM), and a small pair on the
+    resident one (512), through Mosaic's own compiler for a
     described v5e: 192 is not a multiple of the 128 lanes, and nothing is
     padded in HBM to make it one (q, k and their gradients keep 192, v, o and
     their gradients 128). All three kernels are in the compiled program once,
@@ -239,8 +247,9 @@ def test_flash_attention_compiles_with_a_value_width_of_its_own(heads, seq, d, d
     from apex_tpu.ops._pallas_util import compile_for_tpu, mosaic_calls
     from apex_tpu.ops.attention import _tile_plan, flash_attention
 
-    assert _tile_plan(seq, seq, d, jnp.bfloat16, True,
-                      whole_rows=True).schedule == schedule
+    tile = {"listed": 1024, "resident": 512}[schedule]
+    plan = _tile_plan(seq, seq, d, jnp.bfloat16, True, None, None, whole_rows=True, dv=dv)
+    assert (plan.schedule, plan.block_q, plan.block_k) == (schedule, tile, tile)
 
     def loss(q, k, v):
         o = flash_attention(q, k, v, causal=True, scale=0.114721)
